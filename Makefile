@@ -70,12 +70,12 @@ chaos:
 # from the run so a real linter FAILURE fails the target (an
 # `a && b || c` chain would swallow it).
 lint:
-	$(PY) -m compileall -q minbft_tpu tests bench.py __graft_entry__.py
+	$(PY) -m compileall -q minbft_tpu tests bench.py chip_smoke.py __graft_entry__.py
 	$(PY) -m tools.analyze
 	@if $(PY) -c "import ruff" 2>/dev/null; then \
-	    $(PY) -m ruff check minbft_tpu tests bench.py __graft_entry__.py; \
+	    $(PY) -m ruff check minbft_tpu tests bench.py chip_smoke.py __graft_entry__.py; \
 	elif $(PY) -c "import pyflakes" 2>/dev/null; then \
-	    $(PY) -m pyflakes minbft_tpu tests bench.py __graft_entry__.py; \
+	    $(PY) -m pyflakes minbft_tpu tests bench.py chip_smoke.py __graft_entry__.py; \
 	else \
 	    echo "ruff/pyflakes not installed; tools/analyze dead-code pass is the floor"; \
 	fi

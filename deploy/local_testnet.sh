@@ -17,6 +17,10 @@ trap cleanup EXIT
 
 # Each replica runs from its least-privilege keystore copy (only its own
 # private material); the full keys.yaml stays client/operator-side.
+# --no-batch: host crypto — these replicas never initialize a JAX backend,
+# so none of them needs (or takes) a chip.  A chip belongs to one process:
+# to put one replica on it, drop ITS --no-batch and keep JAX_PLATFORMS=cpu
+# and --no-batch on the others (chip_smoke.py's deployment phase does).
 for i in $(seq 0 $((N - 1))); do
     python -m minbft_tpu.sample.peer \
         --keys "$DIR/keys.replica$i.yaml" --config "$DIR/consensus.yaml" \

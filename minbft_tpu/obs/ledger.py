@@ -21,8 +21,9 @@ The headline is the multiplicative headroom identity
     effective_rate = ceiling × busy_fraction × fill_efficiency × useful_fraction
 
 where ``ceiling`` is the CALIBRATED full-batch lane rate for the
-backend (one-shot probe on CPU; the committed ``last_tpu`` block on the
-chip — bench.py supplies it), and the three factors are defined so the
+backend (a one-shot probe on the warm queue of the run's own device,
+stamped ``probe:<platform>`` — bench.py supplies it), and the three
+factors are defined so the
 product is EXACT, not approximate:
 
 - ``busy_fraction  = busy_s / wall_s``              (idle loses the rest)
@@ -159,10 +160,10 @@ class DeviceLedger:
     def set_ceiling(self, queue: str, lanes_per_sec: float,
                     source: str) -> None:
         """Record the calibrated full-batch lane rate for ``queue``.
-        ``source`` says where the number came from (``cpu-probe`` /
-        ``last_tpu:BENCH_rNN.json``) — a ceiling without provenance is
-        how CPU and chip numbers get confused (the standing VERDICT
-        caution)."""
+        ``source`` says where the number came from (``probe:cpu`` /
+        ``probe:tpu`` — the probe below, stamped with the platform it
+        ran on) — a ceiling without provenance is how CPU and chip
+        numbers get confused."""
         if lanes_per_sec <= 0:
             raise ValueError("ceiling must be positive")
         self._ceilings[queue] = (float(lanes_per_sec), source)

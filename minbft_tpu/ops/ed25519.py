@@ -484,9 +484,11 @@ def _rb_comb_one(r: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
 _rb_comb_batch = None
 
 
-def ed25519_rb_kernel(r_arr) -> jnp.ndarray:
-    """Batched r*B — [B, 16] limb rows in (uploaded u16), [B, 3, 16] u16
-    out.  Table closed over as a jit constant (never a per-call upload)."""
+def rb_comb_kernel():
+    """The jitted fixed-base comb kernel itself: [B, 16] uint16 nonce
+    limbs -> [B, 3, 16] uint16.  Built on first use, table closed over
+    as a jit constant (never a per-call upload).  Traceable — see
+    :func:`minbft_tpu.ops.p256.kg_comb_kernel`."""
     global _rb_comb_batch
     if _rb_comb_batch is None:
         table = jnp.asarray(_comb_table_np())
@@ -496,10 +498,14 @@ def ed25519_rb_kernel(r_arr) -> jnp.ndarray:
                 r16.astype(jnp.uint32), table
             )
 
-        from .lowering import per_mode_jit as _pmj
+        _rb_comb_batch = per_mode_jit(widen)
+    return _rb_comb_batch
 
-        _rb_comb_batch = _pmj(widen)
-    return _rb_comb_batch(jnp.asarray(np.asarray(r_arr).astype(np.uint16)))
+
+def ed25519_rb_kernel(r_arr) -> jnp.ndarray:
+    """Batched r*B — [B, 16] limb rows in (uploaded u16), [B, 3, 16] u16
+    out."""
+    return rb_comb_kernel()(jnp.asarray(np.asarray(r_arr).astype(np.uint16)))
 
 
 _batch_inv = limbs.batch_inv_host

@@ -109,15 +109,18 @@ def sharded_ecdsa_kernel(mesh: Mesh):
     return sharded_verifier(p256._verify_one_packed, mesh, 1)
 
 
+def hmac_row_verify(row):
+    """Scalar-shaped HMAC-SHA256 verify of one packed [24] u32 row
+    (key | msg | mac) — the body the sharded kernel vmaps."""
+    from ..ops import hmac_sha256 as hs
+
+    return hs.hmac32_verify(row[0:8], row[8:16], row[16:24])
+
+
 def sharded_hmac_kernel(mesh: Mesh):
     """Batched HMAC-SHA256 verify sharded across ``mesh`` (packed
     [B, 24] u32 rows)."""
-    from ..ops import hmac_sha256 as hs
-
-    def one(row):
-        return hs.hmac32_verify(row[0:8], row[8:16], row[16:24])
-
-    return sharded_verifier(one, mesh, 1)
+    return sharded_verifier(hmac_row_verify, mesh, 1)
 
 
 def sharded_ed25519_kernel(mesh: Mesh):

@@ -513,8 +513,9 @@ async def _run_replica(args) -> int:
     from ...sample.config import load_config
     from ...utils import jaxcache
 
-    # Tree-keyed persistent compile cache: a restarted replica loads its
-    # kernels instead of recompiling them (set before any jax use).
+    # Persistent compile cache (JAX_COMPILATION_CACHE_DIR, else
+    # <checkout>/.jax_cache): a restarted replica loads its kernels
+    # instead of recompiling them (set before any jax use).
     jaxcache.enable_compilation_cache()
     if args.transport == "tcp":
         from ...sample.conn.tcp import (
@@ -531,43 +532,34 @@ async def _run_replica(args) -> int:
     if args.id not in addrs:
         raise SystemExit(f"peer: replica {args.id} not in {args.config} peers[]")
 
-    # Eager tasks (3.12+): most protocol tasks complete without suspending
-    # (memo hits, buffered sends) — running them synchronously at spawn
-    # cuts event-loop scheduling overhead (same setting as the in-process
+    # Eager tasks: most protocol tasks complete without suspending (memo
+    # hits, buffered sends) — running them synchronously at spawn cuts
+    # event-loop scheduling overhead (same setting as the in-process
     # bench cluster).
-    if hasattr(asyncio, "eager_task_factory"):
-        asyncio.get_running_loop().set_task_factory(asyncio.eager_task_factory)
+    asyncio.get_running_loop().set_task_factory(asyncio.eager_task_factory)
 
-    engine = None
-    batch_signatures = False
-    if not args.no_batch:
-        import jax
+    # Device engine or host crypto: one rule (placement.py), and one
+    # line saying which it chose and why — a replica that was meant to
+    # own the chip must never carry on with host crypto in silence.
+    from .placement import (
+        device_schemes,
+        replica_authenticator,
+        replica_engine,
+        warm_engines,
+    )
 
-        # The batch engine only pays off where the limb kernels beat host
-        # OpenSSL — i.e. on a real accelerator.  On the CPU backend a
-        # single COMMIT would pad to a full unrolled-P256 batch (plus the
-        # kernel's large XLA CPU compile), so fall back to serial host
-        # crypto there exactly as --no-batch does.
-        if jax.default_backend() != "cpu":
-            from ...parallel import BatchVerifier
-
-            engine = BatchVerifier(max_batch=args.batch, buckets=(args.batch,))
-            batch_signatures = True
+    engine, placement = replica_engine(args.batch, args.no_batch)
+    batch_signatures = engine is not None
+    print(f"replica {args.id} crypto: {placement}", file=sys.stderr)
+    warm_schemes = device_schemes(store, mac=args.auth == "mac")
 
     def make_auth():
         # One call = one authenticator instance = one fresh USIG epoch
         # (the keystore restores the sealed key per call), so construct
         # exactly as many as the runtime needs: one ungrouped, or one
         # per group below — never a spare.
-        if args.auth == "mac":
-            # device_macs follows the signature-placement rule: the HMAC
-            # batch kernel only beats host HMAC where the chip isn't
-            # remote-attached.
-            return store.mac_replica_authenticator(
-                args.id, engine=engine, device_macs=batch_signatures
-            )
-        return store.replica_authenticator(
-            args.id, engine=engine, batch_signatures=batch_signatures
+        return replica_authenticator(
+            store, args.id, engine, batch_signatures, mac=args.auth == "mac"
         )
 
     if args.transport == "tcp":
@@ -631,6 +623,23 @@ async def _run_replica(args) -> int:
             chips=chips, max_batch=args.batch, buckets=(args.batch,)
         )
         engine = None
+    # Kernels first, listener second: a replica that traced, compiled or
+    # loaded its kernels inside its first request would look dead to its
+    # peers for tens of seconds (prepare timeout, view change).
+    to_warm = (
+        engine_pool.engines if engine_pool is not None
+        else [engine] if engine is not None else []
+    )
+    if to_warm:
+        import time as _time
+
+        t_warm = _time.monotonic()
+        await warm_engines(to_warm, warm_schemes)
+        print(
+            f"replica {args.id} engine warm ({', '.join(warm_schemes)}) in "
+            f"{_time.monotonic() - t_warm:.1f}s",
+            file=sys.stderr,
+        )
     if grouped:
         # Multi-group runtime (README §Sharding): G independent group
         # cores over this one listener + peer connection set, every
@@ -1061,8 +1070,7 @@ async def _run_bench_clients(args) -> int:
     cfg = load_config(args.config)
     addrs = {p.id: p.addr for p in cfg.peers}
 
-    if hasattr(asyncio, "eager_task_factory"):
-        asyncio.get_running_loop().set_task_factory(asyncio.eager_task_factory)
+    asyncio.get_running_loop().set_task_factory(asyncio.eager_task_factory)
 
     conn = connect_many_replicas(addrs, kind="client")
     clients = []
@@ -1236,23 +1244,22 @@ async def _run_load(args) -> int:
 
 async def _run_selftest(args) -> int:
     """In-process n=4/f=1 commit through generated keys + the dummy
-    connector — a deployment smoke test needing no files or sockets."""
+    connector — a deployment smoke test needing no files or sockets.
+    Crypto placement is ``peer run``'s (placement.py): where JAX finds a
+    chip every replica gets its own device engine, so the selftest
+    exercises the chip when there is one."""
     from ... import api
     from ...client import new_client
-    from ...core import new_replica
     from ...sample.authentication import generate_testnet_keys
     from ...sample.config import SimpleConfiger
-    from ...sample.conn.inprocess import (
-        InProcessClientConnector,
-        InProcessPeerConnector,
-        make_testnet_stubs,
-    )
-    from ...sample.requestconsumer import SimpleLedger
+    from ...sample.conn.inprocess import InProcessClientConnector
+    from ...utils import jaxcache
+    from .placement import start_local_cluster
 
+    jaxcache.enable_compilation_cache()
     n, f = 4, 1
     store = generate_testnet_keys(n, n_clients=1)
     cfg = SimpleConfiger(n=n, f=f, timeout_request=60.0, timeout_prepare=30.0)
-    stubs = make_testnet_stubs(n)
 
     # Chaos mode: the same smoke workload, but every link flows through a
     # seeded fault-injection network — the CLI face of tests/test_chaos.py
@@ -1280,21 +1287,11 @@ async def _run_selftest(args) -> int:
     def _wrap(conn, endpoint):
         return net.wrap(conn, endpoint) if net is not None else conn
 
-    ledgers = [SimpleLedger() for _ in range(n)]
-    replicas = []
-    for i in range(n):
-        r = new_replica(
-            i,
-            cfg,
-            store.replica_authenticator(i),
-            _wrap(InProcessPeerConnector(stubs), f"r{i}"),
-            ledgers[i],
-            opts=_log_opts(args),
-        )
-        stubs[i].assign_replica(r)
-        replicas.append(r)
-    for r in replicas:
-        await r.start()
+    cluster = await start_local_cluster(
+        store, cfg, wrap_conn=_wrap, opts=_log_opts(args)
+    )
+    replicas, ledgers, stubs = cluster.replicas, cluster.ledgers, cluster.stubs
+    print(f"selftest crypto: {cluster.placement}", file=sys.stderr)
     client = new_client(
         0,
         n,

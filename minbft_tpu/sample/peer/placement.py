@@ -1,0 +1,194 @@
+"""Where a replica's crypto runs — the one placement rule.
+
+``peer run``, ``peer selftest`` and ``chip_smoke.py`` all build their
+engines, authenticators and in-process clusters here, so that what the
+smoke test proves on the chip is what a deployed replica does.
+
+The rule: a replica owns one :class:`~minbft_tpu.parallel.BatchVerifier`
+(replicas are mutually distrusting machines — they never share an engine
+or its verdict memo), with one padded bucket of ``batch`` lanes, wherever
+JAX found an accelerator; USIG certificates, REQUEST/REPLY signature
+checks and REPLY signing then all ride it.  Host crypto is what is left
+when the operator said ``--no-batch`` or JAX runs on the CPU backend —
+and the choice is always reported, never silent.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import os
+from typing import List, Optional, Tuple
+
+
+def replica_engine(
+    batch: int = 512, no_batch: bool = False, on_cpu: bool = False
+) -> Tuple[Optional[object], str]:
+    """-> ``(engine or None, one line saying which was chosen and why)``.
+
+    ``on_cpu`` builds the engine on the CPU backend too, device sign lane
+    included — for tests that run the device path's code at a tiny bucket
+    where there is no chip; no entry point passes it."""
+    if no_batch:
+        return None, "host crypto (--no-batch)"
+    import jax
+
+    backend = jax.default_backend()
+    if backend == "cpu" and not on_cpu:
+        if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
+            why = "JAX_PLATFORMS=cpu"
+        else:
+            why = "JAX found no accelerator and runs on the cpu backend"
+        return None, f"host crypto ({why})"
+    from ...parallel import BatchVerifier
+
+    dev = jax.devices()[0]
+    engine = BatchVerifier(
+        max_batch=batch,
+        buckets=(batch,),
+        sign_on_device=True if backend == "cpu" else None,
+    )
+    return engine, (
+        f"device engine on {dev.platform} ({dev.device_kind}), one "
+        f"{batch}-lane bucket: UI, request and reply signatures batch on "
+        f"the device"
+    )
+
+
+def replica_authenticator(
+    store, replica_id: int, engine, device: bool, mac: bool = False
+):
+    """Replica ``replica_id``'s authenticator over ``engine``.  ``device``
+    says whether per-message signatures (or MACs) batch on the device; it
+    is separate from ``engine`` because a grouped runtime binds each
+    group's home-chip engine later (``engine=None, device=True``)."""
+    if mac:
+        return store.mac_replica_authenticator(
+            replica_id, engine=engine, device_macs=device
+        )
+    return store.replica_authenticator(
+        replica_id, engine=engine, batch_signatures=device
+    )
+
+
+def device_schemes(store, mac: bool = False) -> Tuple[str, ...]:
+    """The engine queues a replica built from ``store`` dispatches to the
+    device: its USIG's certificate scheme and its message scheme."""
+    usig = "hmac_sha256" if store.usig_spec == "HMAC_SHA256" else "ecdsa_p256"
+    msg = "hmac_sha256" if mac else store.scheme.replace("-", "_")
+    return tuple(sorted({usig, msg} & {"ecdsa_p256", "ed25519", "hmac_sha256"}))
+
+
+async def warm_engine(engine, schemes=("ecdsa_p256",)) -> None:
+    """One item signed and verified through each of ``engine``'s queues
+    in ``schemes``: the kernels trace, compile (or load from the
+    persistent cache) and run once at the engine's bucket BEFORE any
+    protocol timer runs.  Even with a warm cache the first call of the
+    ECDSA kernels costs tens of seconds on the TPU (tracing and loading
+    the executable) — inside a first request that is a prepare timeout
+    and a view change against a replica that is merely starting up.  The
+    first dispatch of each queue carries a cold compile inside the
+    liveness net's first-dispatch allowance (parallel/engine.py)."""
+    import hashlib
+    import hmac
+
+    from ...utils import hostcrypto as hc
+
+    msg = b"minbft-tpu engine warm-up"
+    digest = hashlib.sha256(msg).digest()
+    ok = True
+    if "ecdsa_p256" in schemes:
+        d, q = hc.keygen()
+        sig = await engine.sign_ecdsa_p256(d, digest)
+        ok &= await engine.verify_ecdsa_p256(q, digest, sig)
+    if "ed25519" in schemes:
+        seed, pub = hc.ed25519_keygen()
+        ok &= await engine.verify_ed25519(
+            pub, msg, await engine.sign_ed25519(seed, msg)
+        )
+    if "hmac_sha256" in schemes:
+        mac = hmac.new(digest, digest, hashlib.sha256).digest()
+        ok &= await engine.verify_hmac_sha256(digest, digest, mac)
+    if not ok:
+        raise RuntimeError("engine warm-up: the device rejected a valid item")
+
+
+async def warm_engines(engines, schemes=("ecdsa_p256",)) -> None:
+    """:func:`warm_engine` over several engines.  An executable belongs
+    to its device: engines that share one warm one after the other (the
+    first compiles, the rest reuse its executable), engines on distinct
+    devices side by side (each compiles its own — a four-chip pool warms
+    in the time of one chip, not four)."""
+    import asyncio
+
+    by_device: dict = {}
+    for engine in engines:
+        by_device.setdefault(engine.device, []).append(engine)
+
+    async def one_after_the_other(sharing):
+        for engine in sharing:
+            await warm_engine(engine, schemes)
+
+    await asyncio.gather(*[one_after_the_other(g) for g in by_device.values()])
+
+
+@dataclasses.dataclass
+class LocalCluster:
+    replicas: List[object]
+    ledgers: List[object]
+    engines: List[Optional[object]]  # one per replica; None = host crypto
+    stubs: List[object]
+    placement: str  # replica_engine's line (the same for every replica)
+
+    async def stop(self) -> None:
+        for r in self.replicas:
+            await r.stop()
+
+
+async def start_local_cluster(
+    store,
+    cfg,
+    batch: int = 512,
+    no_batch: bool = False,
+    on_cpu: bool = False,
+    wrap_conn=None,
+    opts=(),
+) -> LocalCluster:
+    """Start ``cfg.n`` in-process replicas over the in-process connector,
+    each with its own engine by :func:`replica_engine` and a fresh
+    ``SimpleLedger``, every engine warmed off the clock.
+    ``wrap_conn(connector, endpoint)`` wraps each peer connector (fault
+    injection).  Caller stops the cluster."""
+    from ...core import new_replica
+    from ...sample.conn.inprocess import (
+        InProcessPeerConnector,
+        make_testnet_stubs,
+    )
+    from ...sample.requestconsumer import SimpleLedger
+
+    n = cfg.n
+    stubs = make_testnet_stubs(n)
+    ledgers = [SimpleLedger() for _ in range(n)]
+    engines, replicas = [], []
+    placement = ""
+    for i in range(n):
+        engine, placement = replica_engine(batch, no_batch, on_cpu)
+        conn = InProcessPeerConnector(stubs)
+        if wrap_conn is not None:
+            conn = wrap_conn(conn, f"r{i}")
+        r = new_replica(
+            i,
+            cfg,
+            replica_authenticator(store, i, engine, engine is not None),
+            conn,
+            ledgers[i],
+            opts=list(opts),
+        )
+        stubs[i].assign_replica(r)
+        engines.append(engine)
+        replicas.append(r)
+    await warm_engines(
+        [e for e in engines if e is not None], device_schemes(store)
+    )
+    for r in replicas:
+        await r.start()
+    return LocalCluster(replicas, ledgers, engines, stubs, placement)

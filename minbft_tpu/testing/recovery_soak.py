@@ -157,6 +157,11 @@ def run_recovery_soak(
         CONSENSUS_CHECKPOINT_PERIOD=str(checkpoint_period),
         MINBFT_STATE_DIR=state_dir,
         MINBFT_RECOVERY_CHUNK_BYTES=str(chunk_bytes),
+        # One process per chip: the caller (a bench or a test that has
+        # touched JAX) may hold it, and these --no-batch children need
+        # none — pinned to the CPU platform they never load the TPU
+        # library at all.
+        JAX_PLATFORMS="cpu",
     )
     env.pop("MINBFT_CHAOS_SEED", None)
     env.pop("MINBFT_CHAOS_PLAN", None)

@@ -1,74 +1,65 @@
-"""Persistent JAX compilation cache, keyed to the kernel source tree.
+"""Persistent JAX compilation cache, placed from outside or at one fixed path.
 
-The crypto kernels are compile-dominated on cold processes (a block-mode
-ECDSA bucket is ~30-40s on TPU, minutes on CPU): every production entry
-point (bench.py, ``peer run`` / ``peer bench``) should load yesterday's
-executables instead of recompiling them.  JAX's cache is already keyed by
-HLO, so correctness never depends on the directory key — but keying the
-directory to a hash of the kernel sources (ops/ + parallel/) keeps one
-tree's artifacts from unboundedly accreting into another's directory and
-makes "did this run hit the cache?" a countable question: entry counts
-before/after a run (``entry_count``) show near-zero new compiles on a
-warm second run (the ``*_compile_s`` keys of BENCH_extras corroborate).
+The crypto kernels are compile-dominated on cold processes (tens of
+seconds per kernel shape on the TPU, minutes on the CPU): every entry
+point (``chip_smoke.py``, ``bench.py``, ``peer run``, the tests) loads
+the executables an earlier process compiled instead of compiling them
+again.
+
+Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+module sets no directory: whoever runs the program decides where the
+cache lives (a machine that is thrown away after each run can only keep
+one where its owner mounts it).  Where it is not set, the cache is
+``<checkout>/.jax_cache`` — one fixed path, because the path is part of
+what a hit depends on: a directory that moves with the source tree, the
+process or the working directory never hits.  JAX's own key covers the
+HLO, the compiler version and the device, so one directory serves every
+kernel edit and every backend.
 """
 
 from __future__ import annotations
 
-import hashlib
 import os
 
-# Source roots whose content defines the cache key: everything that can
-# change emitted HLO lives here (kernels, lowering modes, sharding).
-_KERNEL_ROOTS = ("ops", "parallel")
+# <checkout>/.jax_cache (this file is <checkout>/minbft_tpu/utils/jaxcache.py).
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
 
 
-def tree_key() -> str:
-    """Short content hash of the kernel source tree."""
-    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    h = hashlib.sha256()
-    for root in _KERNEL_ROOTS:
-        base = os.path.join(pkg, root)
-        for dirpath, _dirs, files in sorted(os.walk(base)):
-            for name in sorted(files):
-                if not name.endswith(".py"):
-                    continue
-                path = os.path.join(dirpath, name)
-                h.update(name.encode())
-                # noqa: AH102 - one-time startup hash of the kernel tree
-                with open(path, "rb") as fh:
-                    h.update(fh.read())
-    return h.hexdigest()[:16]
+def cache_dir() -> str:
+    """Where the cache is (the rule above; needs no JAX)."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
 
 
-def enable_compilation_cache(
-    base_dir: str | None = None, min_compile_secs: int = 5
-) -> str:
-    """Point ``jax_compilation_cache_dir`` at a tree-keyed directory and
-    return that directory.  Call before the first kernel compile (import
-    time is fine — this only sets config, it never initializes a
-    backend).  Override the root with MINBFT_JAX_CACHE_DIR; disable
-    entirely with MINBFT_JAX_CACHE=0."""
+def enable_compilation_cache(min_compile_secs: float = 1.0) -> str:
+    """Turn the persistent compilation cache on and return its directory.
+    Call before the first kernel compile (import time is fine — this only
+    sets config, it never initializes a backend).  Disable entirely with
+    MINBFT_JAX_CACHE=0 (returns "")."""
     if os.environ.get("MINBFT_JAX_CACHE", "1") == "0":
         return ""
     import jax
 
-    root = (
-        base_dir
-        or os.environ.get("MINBFT_JAX_CACHE_DIR")
-        or os.path.expanduser("~/.cache/minbft_jax_cache")
-    )
-    cache_dir = os.path.join(root, tree_key())
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs", min_compile_secs
     )
-    return cache_dir
+    return cache_dir()
 
 
 def entry_count(cache_dir: str) -> int:
     """Number of cached executables in ``cache_dir`` (0 when absent) —
-    recorded before/after a bench run so the artifact proves whether the
-    kernels compiled or loaded."""
+    recorded before/after a run to show whether the kernels compiled or
+    loaded."""
     if not cache_dir or not os.path.isdir(cache_dir):
         return 0
-    return sum(1 for name in os.listdir(cache_dir) if not name.startswith("."))
+    return sum(
+        1
+        for name in os.listdir(cache_dir)
+        if not name.startswith(".") and not name.endswith("-atime")
+    )

@@ -76,15 +76,12 @@ async def make_cluster(
     return replicas, c_auths, stubs, ledgers
 
 # Persistent compilation cache: the crypto kernels are compile-dominated on
-# the CPU backend (a cold ECDSA ladder compile is ~2 min), so warm CI runs
-# should pay zero compiles.  Keyed by HLO, so kernel changes re-compile
+# the CPU backend (a cold ECDSA ladder compile is ~2 min), so warm runs
+# should pay zero compiles.  Same placement rule as every entry point
+# (utils/jaxcache.py): JAX_COMPILATION_CACHE_DIR where it is set, else
+# <checkout>/.jax_cache.  Keyed by HLO, so kernel changes re-compile
 # automatically.  Opt out with MINBFT_TEST_CACHE=0.
 if os.environ.get("MINBFT_TEST_CACHE", "1") != "0":
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.environ.get(
-            "MINBFT_TEST_CACHE_DIR",
-            os.path.expanduser("~/.cache/minbft_jax_cache_tests"),
-        ),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
+    from minbft_tpu.utils import jaxcache  # noqa: E402
+
+    jaxcache.enable_compilation_cache(min_compile_secs=2)

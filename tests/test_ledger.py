@@ -175,14 +175,14 @@ def test_self_ceiling_fallback_reads_fill_one():
 def test_set_ceiling_is_used_and_stamped():
     eng = _mk(verify={"hmac_sha256": _Stats()})
     led = DeviceLedger(eng, now=0.0)
-    led.set_ceiling("hmac_sha256", 50_000.0, "last_tpu:BENCH_r05.json")
+    led.set_ceiling("hmac_sha256", 50_000.0, "probe:tpu")
     with pytest.raises(ValueError):
         led.set_ceiling("hmac_sha256", 0.0, "bad")
     st = eng.stats["hmac_sha256"]
     st.items, st.batches, st.device_time_s = 640, 10, 0.4
     keys = led.util_keys("e2e", "hmac_sha256", now=4.0)
     assert keys["e2e_util_ceiling_per_sec"] == 50_000.0
-    assert keys["e2e_util_ceiling_source"] == "last_tpu:BENCH_r05.json"
+    assert keys["e2e_util_ceiling_source"] == "probe:tpu"
 
 
 def test_util_keys_schema_and_absent_queue():

@@ -298,7 +298,6 @@ def test_peer_metrics_subcommand_scrapes(capsys):
 
 
 def _bench_cluster_keys(trace: bool):
-    os.environ.setdefault("MINBFT_BENCH_SKIP_PREFLIGHT", "1")
     import bench
 
     out = asyncio.run(

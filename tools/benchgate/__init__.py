@@ -30,7 +30,7 @@ Scope — deliberately narrow and honest:
   ``drop > max(sigmas * sqrt(base_std² + cand_std²),
   rel_floor * base_mean)`` — the stddev band covers measured run-to-run
   variance, the relative floor covers the 1-core bench host's
-  documented ±30% single-run swing (perf/PROFILE_r05.md) when runs=1
+  documented ±30% single-run swing (the round-5 profile, removed in PR 21) when runs=1
   makes the stddev lie at 0.
 - Backend honesty is a HARD refusal, not a threshold: a
   ``tpu_unavailable`` (CPU-fallback) artifact can gate only against a

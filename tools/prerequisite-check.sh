@@ -3,6 +3,14 @@
 # tools/prerequisite-check.sh probes SGX; this probes the TPU + native
 # toolchain story).  Informational: exits 0 unless Python-side
 # prerequisites are missing.
+#
+# It is for a person to read.  Its output and exit status are NOT a
+# permission for any program path to continue a chip run: the "no TPU"
+# branch below only says that the tests' CPU backend still works.  A
+# run that was meant for the chip and finds none fails — `peer run` says
+# on stderr which crypto it chose and why, `chip_smoke.py` and
+# `bench.py` exit non-zero (the latter unless JAX_PLATFORMS=cpu was
+# asked for).  Nothing in the repo reads this script or the probe.
 set -u
 cd "$(dirname "$0")/.."
 
@@ -30,7 +38,7 @@ if make -C tools/tpu-capability check-tpu-capability >/dev/null 2>&1; then
     tools/tpu-capability/check-tpu-capability
     case $? in
         0) echo "(accelerator path available)";;
-        1) echo "(CPU SIM mode; kernels still run on the jax CPU backend)";;
+        1) echo "(no TPU here: tests run on the jax CPU backend with JAX_PLATFORMS=cpu; a chip run would FAIL, not fall back)";;
         *) echo "(probe error)";;
     esac
 else

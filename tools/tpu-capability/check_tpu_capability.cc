@@ -12,9 +12,12 @@
 //   4. libcrypto (OpenSSL 3) loadable — required by the native USIG
 //      module (minbft_tpu/native).
 //
-// Exit status: 0 = TPU hardware reachable, 1 = no TPU (CPU "SIM mode"
-// still works), 2 = probe error.  Modeled on the reference tool's
-// tri-state exit so tools/prerequisite-check.sh can branch on it.
+// Exit status: 0 = TPU hardware reachable, 1 = no TPU (the tests' CPU
+// backend still works), 2 = probe error.  Modeled on the reference tool's
+// tri-state exit so tools/prerequisite-check.sh can branch on it — for a
+// person to read.  No program path may take status 1 as permission to
+// continue a chip run on the CPU: a run meant for the chip that finds
+// none fails (chip_smoke.py, bench.py, `peer run`'s placement line).
 
 #include <dirent.h>
 #include <dlfcn.h>
@@ -85,6 +88,6 @@ int main() {
   }
   const bool tpu = pci || accel > 0 || libtpu;
   std::printf("verdict: %s\n",
-              tpu ? "TPU reachable" : "no TPU (CPU SIM mode only)");
+              tpu ? "TPU reachable" : "no TPU (tests' CPU backend only; chip runs fail)");
   return tpu ? 0 : 1;
 }
