@@ -1,0 +1,161 @@
+"""BENCHMARK.json and the harness's data files: they load, they refer to
+each other, they keep to the contract's limits, and a later PR can add a
+cell, a configuration, a traffic mix or a per-layer metric as files and
+entries alone."""
+
+import json
+import os
+import re
+import shutil
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, REPO)
+
+from benchmark import manifest, observe, roofline  # noqa: E402
+from benchmark.generator import Mix, Payloads  # noqa: E402
+
+MANIFEST = manifest.load_manifest()
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+CELLS = [w["name"] for w in MANIFEST["workloads"]]
+
+
+def test_manifest_has_the_contracts_keys_and_limits():
+    assert set(MANIFEST) == {"command", "paths", "run_seconds", "configs",
+                             "workloads", "end_to_end", "per_layer"}
+    assert MANIFEST["command"] == ["python3", "-m", "benchmark.run"]
+    assert MANIFEST["paths"] == ["benchmark", "tests/benchmark"]
+    cells = len(MANIFEST["workloads"])
+    assert 1 <= MANIFEST["run_seconds"] <= 51
+    # a full check with the full 24 cells has to fit into 43200 s
+    assert (2 + 14 * 24) * (MANIFEST["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
+    assert 1 <= cells <= 24 and sum(w["chips"] == 4 for w in MANIFEST["workloads"]) <= max(1, cells // 2)
+    assert "setup_s" in {m["name"] for m in MANIFEST["end_to_end"]}
+    for m in MANIFEST["end_to_end"]:
+        assert set(m) <= {"name", "unit", "better", "bound", "source", "workloads"}
+        assert 0.01 <= m["bound"] <= 0.25 and m["source"] in ("host_clock", "device_trace")
+    for m in MANIFEST["per_layer"]:
+        assert set(m) <= {"name", "unit", "better", "source", "layer", "moves", "workloads"}
+        assert m["source"] in manifest.SOURCES
+        assert m["moves"] in {e["name"] for e in MANIFEST["end_to_end"]}
+    every = MANIFEST["configs"] + MANIFEST["workloads"] + MANIFEST["end_to_end"] + MANIFEST["per_layer"]
+    assert all(NAME.match(e["name"]) for e in every)
+    assert all(m["better"] in ("lower", "higher") and re.match(r"^[A-Za-z0-9_/%.\-]{1,16}$", m["unit"])
+               for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"])
+    assert all(1 <= len(e["why"]) <= 200 for e in MANIFEST["configs"] + MANIFEST["workloads"])
+    assert all(1 <= len(c["source"]) <= 200 for c in MANIFEST["configs"])
+    assert len(json.dumps(MANIFEST)) < 64 * 1024
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_loads_with_its_config_traffic_and_readers(name):
+    cell = manifest.load_cell(name, MANIFEST)
+    entry = next(c for c in MANIFEST["configs"] if c["name"] == cell.config_name)
+    assert entry["file"].startswith("benchmark/configs/")
+    assert cell.config["name"] == cell.config_name and cell.chips == cell.config["chips"] == 1
+    assert set(entry["reduced"]) == set(cell.config["reduced"])
+    assert {"reply_quorum", "durability", "agreement", "device_path"} <= set(cell.config["guarantees"])
+    mix = Mix.from_file(cell.traffic)
+    assert (mix.loop, mix.clients, mix.depth) == ("closed", 16, 8)
+    assert {m["name"] for m in cell.end_to_end} == {
+        "goodput_rps", "finality_mean_ms", "finality_p95_ms", "setup_s"}
+    assert len(cell.per_layer) == 9 and all(callable(m.read) for m in cell.per_layer)
+    assert set(manifest.load_kernels(cell)) == {"ecdsa_verify", "ecdsa_sign"}
+
+
+@pytest.mark.parametrize("entry", MANIFEST["per_layer"], ids=lambda m: m["name"])
+def test_per_layer_entry_matches_its_readers_declaration(entry):
+    module = manifest.load_module(
+        os.path.join(manifest.HERE, "layer_metrics", entry["name"] + ".py"))
+    declared = {k: entry[k] for k in ("unit", "better", "source", "layer", "moves")}
+    assert module.DECLARATION == declared
+    assert set(entry["workloads"]) <= set(CELLS)
+
+
+def test_every_file_under_paths_has_a_contract_name():
+    for path in MANIFEST["paths"]:
+        for base, _dirs, files in os.walk(os.path.join(REPO, path)):
+            if "__pycache__" in base:
+                continue
+            for f in files:
+                rel = os.path.relpath(os.path.join(base, f), REPO)
+                assert re.match(r"^[A-Za-z0-9_.\-/]+$", rel), rel
+
+
+def test_a_later_pr_adds_cell_config_mix_and_metric_as_files_alone(tmp_path, monkeypatch):
+    here = tmp_path / "benchmark"
+    shutil.copytree(manifest.HERE, here, ignore=shutil.ignore_patterns("__pycache__"))
+    config = json.loads((here / "configs" / "n3f1-ecdsa.json").read_text())
+    config.update(name="n4f1-ecdsa", n=4, f=1)
+    (here / "configs" / "n4f1-ecdsa.json").write_text(json.dumps(config))
+    mix = json.loads((here / "traffic" / "closed-16x8.json").read_text())
+    mix.update(loop="open", rate_rps=400.0)
+    (here / "traffic" / "open-400.json").write_text(json.dumps(mix))
+    (here / "layer_metrics" / "client.finality_p99_ms.py").write_text(
+        "from benchmark.observe import percentile\n"
+        "def read(obs):\n    return percentile(obs.latencies_ms, 99)\n")
+    later = json.loads(json.dumps(MANIFEST))
+    later["configs"].append({"name": "n4f1-ecdsa", "source": "x", "why": "y", "reduced": ["hosts"],
+                             "file": "benchmark/configs/n4f1-ecdsa.json"})
+    later["workloads"].append({"name": "n4f1-ecdsa.open-400", "config": "n4f1-ecdsa",
+                               "traffic": "open-400", "chips": 1, "why": "z"})
+    later["per_layer"].append({"name": "client.finality_p99_ms", "unit": "ms", "better": "lower",
+                               "source": "host_clock", "layer": "client", "moves": "finality_p95_ms",
+                               "workloads": ["n4f1-ecdsa.open-400"]})
+    monkeypatch.setattr(manifest, "ROOT", str(tmp_path))
+    monkeypatch.setattr(manifest, "HERE", str(here))
+    cell = manifest.load_cell("n4f1-ecdsa.open-400", later)
+    assert cell.config["n"] == 4 and Mix.from_file(cell.traffic).loop == "open"
+    assert [m.name for m in cell.per_layer] == ["client.finality_p99_ms"]
+    obs = observe.Observations(30.0, [1.0, 2.0, 3.0], 3, [], {}, "TPU v5 lite", "tpu", {}, {}, {}, 512, None)
+    assert cell.per_layer[0].read(obs) == pytest.approx(2.98)
+    old = manifest.load_cell(CELLS[0], later)
+    assert "client.finality_p99_ms" not in [m.name for m in old.per_layer]
+
+
+def test_unknown_names_are_errors_not_defaults():
+    with pytest.raises(manifest.BenchmarkError, match="no workload"):
+        manifest.load_cell("n9f4-ecdsa.closed-16x8", MANIFEST)
+    with pytest.raises(manifest.BenchmarkError, match="no device kind"):
+        manifest.load_peaks("TPU v9")
+    with pytest.raises(manifest.BenchmarkError, match="read_share"):
+        Mix.from_file({**manifest.load_cell(CELLS[0]).traffic, "read_share": 0.5})
+    with pytest.raises(manifest.BenchmarkError, match="rate_rps"):
+        Mix.from_file({**manifest.load_cell(CELLS[0]).traffic, "loop": "open"})
+
+
+def test_payloads_come_from_the_seed_and_never_repeat():
+    mix = Mix.from_file(manifest.load_cell(CELLS[0]).traffic)
+    a, b, c = Payloads(2**31 + 11, mix), Payloads(2**31 + 11, mix), Payloads(2**31 + 12, mix)
+    ops = [a.next(k % 16) for k in range(512)]
+    assert ops == [b.next(k % 16) for k in range(512)] != [c.next(k % 16) for k in range(512)]
+    assert len(set(ops)) == 512 and {len(op) for op in ops} == {mix.payload_bytes}
+    assert a.forged() == b.forged() and len(a.forged()) == mix.payload_bytes
+
+
+def test_percentiles_and_collector_pauses():
+    assert observe.percentile([1, 2, 3, 4, 5], 50) == 3
+    assert observe.percentile(list(range(101)), 95) == 95
+    buckets = [0] * 64
+    buckets[14] = 10  # 8.192 ms < d <= 16.384 ms
+    assert 0.008192 < observe.log2_bucket_percentile(buckets, 50) < 0.016384
+    assert observe.log2_bucket_percentile([0] * 64, 50) is None
+    timer = observe.GcTimer()
+    timer.passes = [(2, 9.8, 0.4), (2, 12.0, 0.5), (1, 12.0, 0.5), (2, 19.9, 0.4)]
+    assert timer.pause_s(2, 10.0, 20.0) == pytest.approx(0.2 + 0.5 + 0.1)
+
+
+def test_roofline_counts_textbook_work_and_stays_far_under_the_peak():
+    cell = manifest.load_cell(CELLS[0])
+    kernels = manifest.load_kernels(cell)
+    peaks = manifest.load_peaks("TPU v5 lite")
+    verify, sign = kernels["ecdsa_verify"].work(512), kernels["ecdsa_sign"].work(512)
+    assert verify["ops"] == 512 * 4930 * 4096 and sign["ops"] == 512 * 3456 * 4096
+    least, binds = roofline.least_time_s(verify, peaks)
+    assert binds == "compute" and least == pytest.approx(26.3e-6, rel=0.01)
+    obs = observe.Observations(30.0, [], 0, [], {}, "TPU v5 lite", "tpu", kernels,
+                               {"ecdsa_verify": 9.5e-3}, {}, 512, None)
+    assert 0.2 < roofline.share_percent(obs, "ecdsa_verify") < 0.4
+    assert roofline.share_percent(obs, "ecdsa_sign") is None  # nothing traced: no 0
