@@ -491,6 +491,9 @@ class Client:
                     operation=operation,
                     read_mode=mode,
                 )
+                # Always on (obs/trace.py timeline()): one row a request;
+                # a retransmission pushes nothing.
+                obs_trace.note_client_start(self.client_id, seq)
                 if tr is not None:
                     tr.note(obs_trace.C_START, self.client_id, seq)
                 # Awaitable batch-aware signing: concurrent pipelined
@@ -545,6 +548,7 @@ class Client:
             read_mode=1,
         )
         tr = self._trace
+        obs_trace.note_client_start(self.client_id, seq)
         if tr is not None:
             tr.note(obs_trace.C_START, self.client_id, seq)
         req.signature = await self._auth.generate_message_authen_tag_async(

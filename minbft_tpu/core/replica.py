@@ -131,11 +131,17 @@ class _Replica(api.Replica):
         # Event-loop lag sampler (obs/looplag.py): scheduled-vs-actual
         # wakeup delta into metrics.loop_lag — GIL/loop saturation as a
         # scrapeable histogram and a trace-dump extra.
-        from ..obs.looplag import maybe_sampler
+        from ..obs.looplag import install_idle_clock, maybe_sampler
+        from ..obs.trace import install_collector_clock
 
         self._lag_sampler = maybe_sampler(self.handlers.metrics.loop_lag)
         if self._lag_sampler is not None:
             self._lag_sampler.start()
+        # The process timeline's always-on parts that belong to a
+        # running replica: this loop's idle clock (once per loop) and
+        # the collector's clock (once per process).
+        install_idle_clock(loop)
+        install_collector_clock()
         # Crash forensics: a protocol task dying with an exception must
         # not take the flight-recorder trace with it — the dump fires on
         # the fatal error, not only on a clean stop() (a crashed soak

@@ -35,9 +35,13 @@ same locked-writes discipline as the engine's ``_stats_lock`` stats;
 
 from __future__ import annotations
 
+import gc
+import itertools
 import json
 import os
+import threading
 import time
+import weakref
 from array import array
 from typing import Dict, Iterable, List, Optional, Tuple
 
@@ -111,34 +115,55 @@ def tracing_enabled() -> bool:
 
 
 class StageRing:
-    """Preallocated single-writer ring of (a, b, stage, t_ns) events.
+    """Preallocated single-writer ring of fixed-width rows of integers
+    in one ``array('q')`` (the flight recorder's events are the
+    four-wide ``(a, b, stage, t_ns)``; the dispatch record is sixteen
+    wide).
 
-    Four parallel ``array('q')`` columns: a push is four C-level stores
-    plus two int updates — no allocation, no lock.  ONLY the owning
+    :meth:`push` (four-wide rings: the per-request hot path) is four
+    C-level stores plus three int updates — no allocation, no lock;
+    :meth:`push_row` (any width, once a dispatch / pass / event) is one
+    slice store.  ONLY the owning
     event loop may push; cross-thread producers use :class:`MTStageRing`.
+    :meth:`read` also counts the rows a wrapped ring has overwritten, so
+    a reader can tell a whole record from the tail of one.
     """
 
-    __slots__ = ("_a", "_b", "_c", "_t", "_cap", "_idx", "_n")
+    __slots__ = ("_buf", "_width", "_cap", "_idx", "_n", "_pushed")
 
-    def __init__(self, capacity: int = _DEFAULT_RING):
+    def __init__(self, capacity: int = _DEFAULT_RING, width: int = 4):
         cap = 1
         while cap < max(2, capacity):
             cap <<= 1
         self._cap = cap
-        self._a = array("q", bytes(8 * cap))
-        self._b = array("q", bytes(8 * cap))
-        self._c = array("q", bytes(8 * cap))
-        self._t = array("q", bytes(8 * cap))
+        self._width = width
+        self._buf = array("q", bytes(8 * cap * width))
         self._idx = 0  # next write slot
         self._n = 0  # valid entries (saturates at _cap)
+        self._pushed = 0  # rows ever pushed
 
     def push(self, a: int, b: int, c: int, t_ns: int) -> None:
+        if self._width != 4:
+            raise ValueError(f"push() on a ring of {self._width} columns")
         i = self._idx
-        self._a[i] = a
-        self._b[i] = b
-        self._c[i] = c
-        self._t[i] = t_ns
+        buf, j = self._buf, i << 2
+        buf[j] = a
+        buf[j + 1] = b
+        buf[j + 2] = c
+        buf[j + 3] = t_ns
         self._idx = (i + 1) & (self._cap - 1)
+        self._pushed += 1
+        if self._n < self._cap:
+            self._n += 1
+
+    def push_row(self, row: Tuple[int, ...]) -> None:
+        w = self._width
+        if len(row) != w:  # a slice store of another length would resize the buffer
+            raise ValueError(f"row of {len(row)} for a ring of {w} columns")
+        i = self._idx
+        self._buf[i * w:(i + 1) * w] = array("q", row)
+        self._idx = (i + 1) & (self._cap - 1)
+        self._pushed += 1
         if self._n < self._cap:
             self._n += 1
 
@@ -149,17 +174,26 @@ class StageRing:
     def capacity(self) -> int:
         return self._cap
 
-    def snapshot(self, limit: Optional[int] = None) -> List[Tuple[int, int, int, int]]:
-        """Events oldest→newest (optionally only the newest ``limit``)."""
+    def snapshot(self, limit: Optional[int] = None) -> List[Tuple[int, ...]]:
+        """Rows oldest→newest (optionally only the newest ``limit``)."""
         n = self._n
         if limit is not None:
             n = min(n, limit)
+        w = self._width
         start = (self._idx - n) & (self._cap - 1)
-        out = []
-        for k in range(n):
-            i = (start + k) & (self._cap - 1)
-            out.append((self._a[i], self._b[i], self._c[i], self._t[i]))
-        return out
+        head = min(n, self._cap - start)
+        # Two slices (the ring's tail, then its wrapped head), cut into rows.
+        flat = (
+            self._buf[start * w:(start + head) * w].tolist()
+            + self._buf[:(n - head) * w].tolist()
+        )
+        return [tuple(flat[k:k + w]) for k in range(0, n * w, w)]
+
+    def read(self) -> Tuple[List[Tuple[int, ...]], int]:
+        """``(snapshot(), rows overwritten since the ring was made)`` of
+        one instant."""
+        # The base snapshot by name: MTStageRing.read holds its lock here.
+        return StageRing.snapshot(self), self._pushed - self._n
 
 
 class MTStageRing(StageRing):
@@ -172,23 +206,29 @@ class MTStageRing(StageRing):
 
     __slots__ = ("_lock",)
 
-    def __init__(self, capacity: int = 4096):
-        import threading
-
-        super().__init__(capacity)
+    def __init__(self, capacity: int = 4096, width: int = 4):
+        super().__init__(capacity, width)
         self._lock = threading.Lock()
 
     def push(self, a: int, b: int, c: int, t_ns: int) -> None:
         with self._lock:
             super().push(a, b, c, t_ns)
 
+    def push_row(self, row: Tuple[int, ...]) -> None:
+        with self._lock:
+            super().push_row(row)
+
     def __len__(self) -> int:
         with self._lock:
             return super().__len__()
 
-    def snapshot(self, limit: Optional[int] = None) -> List[Tuple[int, int, int, int]]:
+    def snapshot(self, limit: Optional[int] = None) -> List[Tuple[int, ...]]:
         with self._lock:
             return super().snapshot(limit)
+
+    def read(self) -> Tuple[List[Tuple[int, ...]], int]:
+        with self._lock:
+            return super().read()
 
 
 class FlightRecorder:
@@ -294,6 +334,154 @@ class FlightRecorder:
         if self.group is not None:
             doc["group"] = self.group
         return doc
+
+
+# ---------------------------------------------------------------------------
+# The process timeline: what the host was doing, at dispatch /
+# collector-pass / JAX-event / request granularity, always recorded (no
+# switch: these recorders are of the kind the engine's queue_wait
+# histograms are), every instant ``time.monotonic_ns()``.  The event
+# loop's idle clock is the fifth part (obs/looplag.py).  One way in:
+# :func:`timeline`.
+
+# One row per counted batch of every engine queue (parallel/engine.py
+# _DispatchQueue._note_dispatch writes it, on the loop).
+DISPATCH_COLUMNS: Tuple[str, ...] = (
+    "dispatch_id",  # process-wide, from DISPATCH_IDS
+    "engine",  # register_engine's id
+    "queue",  # e.g. ecdsa_p256, sign_ecdsa_p256
+    "kind",  # DISPATCH_KINDS
+    "items",
+    "lanes",  # the bucket the batch was padded to (0: no device phases)
+    "reason",  # FLUSH_REASONS
+    "flags",  # FLAG_*
+    # The eight instants, never decreasing:
+    "t_first_enqueue",  # the batch's oldest item entered the queue
+    "t_flush",  # _run began: the batch left the queue
+    "t_worker_start",  # the dispatcher's first line, on its thread
+    "t_prep_end",  # packed buffer ready
+    "t_launch_end",  # the jitted call returned: the kernel is enqueued
+    "t_result",  # np.asarray returned: the result is on the host
+    "t_finish_end",  # after sign_finish (= t_result for verify)
+    "t_resolved",  # _run running again on the loop
+)
+DISPATCH_KINDS: Tuple[str, ...] = ("verify", "sign")
+FLUSH_REASONS: Tuple[str, ...] = ("direct", "full", "idle", "timer", "completion", "other")
+FLAG_FALLBACK = 1  # the host fallback computed the results
+FLAG_TIMEOUT = 2  # the device dispatch hung past dispatch_timeout
+FLAG_NO_DEVICE = 4  # no dispatcher stamped a launch: a host queue, or the fallback alone
+DISPATCH_IDS = itertools.count(1)  # next() is one C call: safe from any thread
+
+# JAX's own duration events (jax.monitoring), as JAX 0.9.0 names them
+# (tests/test_jaxcache.py pins the names against the installed JAX).
+JAX_EVENTS: Tuple[str, ...] = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+    "/jax/compilation_cache/cache_retrieval_time_sec",
+)
+_JAX_EVENT_IDS = {name: i for i, name in enumerate(JAX_EVENTS)}
+
+# Collector passes of generations 0 and 1 and JAX events are recorded from
+# 1 ms up: a kernel's trace nests tens of thousands of inner traces of
+# microseconds each, all inside the outer event that is recorded.
+_MIN_NS = 1_000_000
+
+_ENGINE_IDS = itertools.count()
+# Weak: the registry keeps no engine alive, and a dead one's rows go with it.
+_ENGINES: "weakref.WeakValueDictionary[int, object]" = weakref.WeakValueDictionary()
+# (client id, seq, C_START, t): one row a request, from every client of
+# the process (clients may live on several loops, so the locked ring).
+# 2**16 rows hold set-up and 100 s of windows at 600 requests/s.
+_CLIENT_ROWS = MTStageRing(1 << 16)
+# (index into JAX_EVENTS, t_end, duration_ns); tracing runs on worker threads.
+_JAX_ROWS = MTStageRing(1 << 12, width=3)
+# (generation, t_start, duration_ns).  One writer at a time without a lock:
+# the collector never runs two passes at once, and its callback must not
+# take a lock that the thread it interrupted may hold.
+_GC_ROWS = StageRing(1 << 12, width=3)
+_gc_start = [0]
+
+
+def register_engine(engine) -> int:
+    """Enter ``engine`` (anything with ``dispatch_rows()``) into the
+    process timeline -> its id in dispatch rows.  Also where the
+    collector's clock is installed: with the first engine."""
+    install_collector_clock()
+    engine_id = next(_ENGINE_IDS)
+    _ENGINES[engine_id] = engine
+    return engine_id
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        _gc_start[0] = time.monotonic_ns()
+        return
+    dur = time.monotonic_ns() - _gc_start[0]
+    if info["generation"] == 2 or dur >= _MIN_NS:
+        _GC_ROWS.push_row((info["generation"], _gc_start[0], dur))
+
+
+def install_collector_clock() -> None:
+    """Time the collector's passes from inside the program: one
+    ``gc.callbacks`` entry a process, installed where the first engine
+    or replica starts.  It changes nothing the collector does."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def note_jax_event(event: str, duration_secs: float, **_kwargs) -> None:
+    """The ``jax.monitoring`` duration listener (utils/jaxcache.py
+    registers it): jaxpr tracing, lowering, backend compile, cache
+    retrieval."""
+    i = _JAX_EVENT_IDS.get(event)
+    duration_ns = int(duration_secs * 1e9)
+    if i is not None and duration_ns >= _MIN_NS:
+        _JAX_ROWS.push_row((i, time.monotonic_ns(), duration_ns))
+
+
+def note_client_start(client_id: int, seq: int) -> None:
+    """A client issued request ``seq`` (its first transmission)."""
+    _CLIENT_ROWS.push(client_id, seq, C_START, time.monotonic_ns())
+
+
+def timeline() -> dict:
+    """Everything this process has on its timeline, as plain lists of
+    rows whose instants are ``time.monotonic_ns()``, each with its ring's
+    ``dropped`` count (rows overwritten):
+
+    - ``dispatch``: per live engine ``{"engine", "rows", "dropped"}``,
+      rows as :data:`DISPATCH_COLUMNS` with names decoded;
+    - ``gc``: rows ``(generation, t_start, duration_ns)``, every
+      generation-2 pass and any pass of 1 ms or more;
+    - ``jax``: rows ``(event, t_end, duration_ns)``, every event of
+      :data:`JAX_EVENTS` that took 1 ms or more;
+    - ``client``: rows ``(client_id, seq, "start", t)``, one a request;
+    - ``loops``: obs/looplag.py's idle clocks, one per live loop.
+    """
+    from . import looplag
+
+    dispatch = []
+    for engine_id, engine in sorted(dict(_ENGINES).items()):
+        rows, dropped = engine.dispatch_rows()
+        dispatch.append({"engine": engine_id, "rows": rows, "dropped": dropped})
+    gc_rows, gc_dropped = _GC_ROWS.read()
+    jax_rows, jax_dropped = _JAX_ROWS.read()
+    client_rows, client_dropped = _CLIENT_ROWS.read()
+    return {
+        "dispatch_columns": list(DISPATCH_COLUMNS),
+        "dispatch": dispatch,
+        "gc": {"rows": gc_rows, "dropped": gc_dropped},
+        "jax": {
+            "rows": [(JAX_EVENTS[i], t, d) for i, t, d in jax_rows],
+            "dropped": jax_dropped,
+        },
+        "client": {
+            "rows": [(c, s, CLIENT_STAGES[st], t) for c, s, st, t in client_rows],
+            "dropped": client_dropped,
+        },
+        "loops": looplag.idle_clocks(),
+    }
 
 
 # ---------------------------------------------------------------------------

@@ -38,6 +38,8 @@ serial per key, ref usig.c:66-69).
 from __future__ import annotations
 
 import asyncio
+import contextlib
+import contextvars
 import dataclasses
 import threading
 import time
@@ -46,6 +48,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs import trace as obs_trace
 from ..obs.hist import Log2Histogram
 
 
@@ -70,6 +73,88 @@ class _Resolved:
         return self.v
 
 
+class _Phase:
+    """One worker-side phase of a dispatch: a ``TraceAnnotation`` on the
+    profiler's clock (a flag test without a session) whose end is also
+    stamped into the span on ``CLOCK_MONOTONIC``."""
+
+    __slots__ = ("_t", "_index", "_annotation")
+
+    def __init__(self, t: List[int], index: int, annotation):
+        self._t, self._index, self._annotation = t, index, annotation
+
+    def __enter__(self) -> None:
+        self._annotation.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self._t[self._index] = time.monotonic_ns()
+        self._annotation.__exit__(*exc)
+
+
+class _DispatchSpan:
+    """One dispatch's timeline row in the making (obs/trace.py
+    DISPATCH_COLUMNS).  ``_run`` makes it on the loop and sets it in a
+    context variable; ``asyncio.to_thread`` copies the context, so the
+    dispatcher finds it on its worker thread and stamps its own
+    instants there, and ``_run`` writes the whole row once it resumes.
+    One writer at a time: the loop before and after the await, the one
+    worker in between — but for a dispatch that timed out, whose worker
+    runs on; ``_note_dispatch`` therefore reads a copy."""
+
+    __slots__ = ("names", "dispatch_id", "lanes", "flags", "t")
+
+    # Indices into ``t``: the ends of the worker's phases, by name.
+    WORKER_START, PREP, LAUNCH, WAIT, FINISH = range(5)
+
+    def __init__(self, names: Dict[str, str], dispatch_id: int):
+        self.names = names  # phase -> annotation name (:func:`_phase_names`)
+        self.dispatch_id = dispatch_id
+        self.lanes = 0
+        self.flags = 0
+        self.t = [0, 0, 0, 0, 0]
+
+    def phase(self, name: str, index: int) -> _Phase:
+        """``minbft/dispatch/<queue>/<name>`` around a worker phase.
+        Only device dispatchers call it: they import jax anyway."""
+        from jax.profiler import TraceAnnotation
+
+        return _Phase(
+            self.t,
+            index,
+            TraceAnnotation(self.names[name], dispatch_id=self.dispatch_id),
+        )
+
+
+def _phase_names(label: str) -> Dict[str, str]:
+    """The annotation names of one queue's dispatches, made once."""
+    return {
+        phase: f"minbft/dispatch/{label}/{phase}"
+        for phase in ("prep", "launch", "wait", "finish", "resolve")
+    }
+
+
+_SPAN: contextvars.ContextVar = contextvars.ContextVar(
+    "minbft_dispatch_span", default=None
+)
+
+
+def _worker_span() -> _DispatchSpan:
+    """The running dispatch's span, ``t_worker_start`` stamped: the first
+    line of every device dispatcher.  A dispatcher called outside
+    ``_run`` (a test, a probe) gets one that nothing reads."""
+    span = _SPAN.get()
+    if span is None:
+        span = _DispatchSpan(_DIRECT_NAMES, 0)
+    span.t[0] = time.monotonic_ns()
+    return span
+
+
+_DIRECT_NAMES = _phase_names("direct")
+# A flush reason's index in a dispatch row; one the table lacks reads "other".
+_FLUSH_REASON_IDS = {name: i for i, name in enumerate(obs_trace.FLUSH_REASONS)}
+_FLUSH_REASON_OTHER = _FLUSH_REASON_IDS["other"]
+
+
 @dataclasses.dataclass
 class VerifyStats:
     """Engine counters (the observability the reference lacks, SURVEY.md §5)."""
@@ -78,12 +163,17 @@ class VerifyStats:
     batches: int = 0
     max_batch_seen: int = 0
     padded_lanes: int = 0
+    # NOT device time, whatever the name (obs/prom.py exports it, so the
+    # name stays): the sum of every dispatch's whole ``await`` on the
+    # loop — thread hop, host prep, launch, the wait behind other
+    # engines' kernels, the kernel, the result's return and the loop's
+    # wake-up.  The dispatch rows (obs/trace.py) separate these.
     device_time_s: float = 0.0
     # Host share of the dispatch: time the worker thread spent preparing
     # and packing the batch (limb conversion, batch inversion, staging
-    # writes) BEFORE the kernel call — device_time_s covers the whole
-    # dispatch await, so host_prep_time_s / device_time_s is the prep
-    # share of the pipeline (bench.py reports it as *_prep_share).
+    # writes) BEFORE the kernel call — so host_prep_time_s /
+    # device_time_s is the prep share of the dispatch's await (bench.py
+    # reports it as *_prep_share).
     host_prep_time_s: float = 0.0
     memo_hits: int = 0
     dispatch_timeouts: int = 0  # hung device dispatches rescued on host
@@ -117,8 +207,9 @@ class SignStats:
 
     ``host_prep_time_s`` covers BOTH host halves of a dispatch (nonce
     derivation + limb packing before the kernel, batch inversion + scalar
-    finish after it); ``device_time_s`` is the whole dispatch await, so
-    the difference is the kernel + transfer share.
+    finish after it); ``device_time_s`` is the whole dispatch ``await``
+    (see :class:`VerifyStats`: not device time), so the difference is
+    thread hop, launch, device queue, kernel, transfer and loop wake-up.
     ``host_fallback_items`` counts items signed by the serial host
     fallback instead of the device — because the backend is CPU (sign
     device auto-disabled), the device was written off, or a dispatch hung
@@ -206,11 +297,17 @@ class _DispatchQueue:
     # Entry points warm their engines before serving anyway
     # (sample/peer/placement.py).
     _FIRST_TIMEOUT_FACTOR = 4
+    KIND = 0  # index into obs/trace.py DISPATCH_KINDS
+    LABEL_PREFIX = ""
 
     def __init__(self, engine: "BatchVerifier", name: str, dispatch):
         self.engine = engine
         self.name = name
         self.dispatch = dispatch  # List[item] -> per-lane results
+        # This queue in dispatch rows and trace annotations.
+        self.label = self.LABEL_PREFIX + name
+        self._obs_queue = engine._obs_queue_id(self.label)
+        self._phase_names = _phase_names(self.label)
         # (item, future, enqueue_monotonic_ns): the timestamp feeds the
         # per-item queue-wait histogram at dispatch time.
         self.pending: List[Tuple[object, asyncio.Future, int]] = []
@@ -274,6 +371,8 @@ class _DispatchQueue:
         moment a dispatch slot frees up)."""
         items = [it for it, _f, _t in batch]
         t0_ns = time.monotonic_ns()
+        span = _DispatchSpan(self._phase_names, next(obs_trace.DISPATCH_IDS))
+        _SPAN.set(span)  # this task's context: the worker thread gets a copy
         try:
             results, fell_back = await self._dispatch_with_fallback(items)
         except Exception as e:
@@ -286,7 +385,8 @@ class _DispatchQueue:
             self.inflight -= 1  # noqa: LD001
             if self.pending:
                 self._flush_now("completion")
-        dt_ns = time.monotonic_ns() - t0_ns
+        t_resolved = time.monotonic_ns()
+        dt_ns = t_resolved - t0_ns
         dt = dt_ns * 1e-9
         st = self.stats
         st.items += len(batch)
@@ -314,7 +414,53 @@ class _DispatchQueue:
         for _it, _f, t_enq in batch:
             wait_h.observe_ns(t0_ns - t_enq)
         st.queue_service.observe_ns(dt_ns, len(batch))
-        self._resolve(batch, results, fell_back)
+        resolve = contextlib.nullcontext()
+        if span.t[span.LAUNCH]:
+            # A device dispatcher ran, so jax is imported: the loop's
+            # side of the dispatch, beside the worker's four phases.
+            from jax.profiler import TraceAnnotation
+
+            resolve = TraceAnnotation(
+                span.names["resolve"], dispatch_id=span.dispatch_id
+            )
+        # The callers first: the row is about this dispatch, never in
+        # its way.
+        with resolve:
+            self._resolve(batch, results, fell_back)
+        self._note_dispatch(span, batch, reason, t0_ns, t_resolved, fell_back)
+
+    def _note_dispatch(self, span: _DispatchSpan, batch, reason: str,
+                       t_flush: int, t_resolved: int, fell_back: bool) -> None:
+        """This dispatch's row (obs/trace.py DISPATCH_COLUMNS), written
+        once, on the loop, with ``batches``: one row per counted batch.
+        An instant the dispatcher did not stamp (a host queue or the
+        host fallback has no device phases; verify has no finish) takes
+        the one before it, so the eight never decrease."""
+        flags = span.flags | (obs_trace.FLAG_FALLBACK if fell_back else 0)
+        # A copy: after a timeout the abandoned worker may still be
+        # stamping ``span.t`` on its thread.
+        t = list(span.t)
+        if not t[span.LAUNCH]:
+            flags |= obs_trace.FLAG_NO_DEVICE
+        prev = t_flush
+        for i, v in enumerate(t):
+            if v < prev:
+                t[i] = prev
+            prev = t[i]
+        self.engine._obs_ring.push_row((
+            span.dispatch_id,
+            self.engine.obs_id,
+            self._obs_queue,
+            self.KIND,
+            len(batch),
+            span.lanes,
+            _FLUSH_REASON_IDS.get(reason, _FLUSH_REASON_OTHER),
+            flags,
+            batch[0][2],  # the oldest: a queue appends in time order
+            t_flush,
+            *t,
+            t_resolved,
+        ))
 
     # -- flush scheduling ---------------------------------------------------
 
@@ -422,6 +568,9 @@ class _DispatchQueue:
                 lambda t: t.exception() if not t.cancelled() else None
             )
             self.stats.dispatch_timeouts += 1
+            span = _SPAN.get()
+            if span is not None:
+                span.flags |= obs_trace.FLAG_TIMEOUT
             self._consecutive_timeouts += 1
             if self._consecutive_timeouts >= self._WRITE_OFF_AFTER:
                 self._device_written_off = True
@@ -446,6 +595,9 @@ class _DispatchQueue:
         queue; failure re-arms the re-probe clock."""
         import logging
 
+        # This task's context is a copy of the live dispatch's: the
+        # probe's dispatcher must not stamp into that dispatch's row.
+        _SPAN.set(None)
         task = asyncio.ensure_future(asyncio.to_thread(self.dispatch, items))
         try:
             await asyncio.wait_for(
@@ -596,6 +748,9 @@ class _SignQueue(_DispatchQueue):
     signing never reaches this queue.
     """
 
+    KIND = 1
+    LABEL_PREFIX = "sign_"
+
     def __init__(self, engine: "BatchVerifier", name: str, dispatch):
         super().__init__(engine, name, dispatch)
         self.stats = SignStats()
@@ -743,23 +898,19 @@ class BatchVerifier:
         self._queues: Dict[str, _SchemeQueue] = {}
         self._sign_queues: Dict[str, _SignQueue] = {}
         self._staging = _StagingPool(cap=max_inflight)
-        # Flight-recorder hookup (obs/): dispatcher-side span events —
-        # (queue, padded lanes, host-prep ns) per dispatch — pushed by
-        # the WORKER threads into a multi-producer ring.  None until an
-        # operator enables it; the disabled cost is one attribute check
-        # per dispatch (not per item).  Queue-name ids are interned under
-        # _stats_lock (the same cross-thread discipline as the stats).
-        self._obs_ring = None
+        # The dispatch record (obs/trace.py): one row per counted batch
+        # of every queue, DISPATCH_COLUMNS wide, always recorded — of
+        # the kind queue_wait is, at one push a dispatch.  The loop
+        # writes; timeline() and the shutdown dump read, from any
+        # thread, so the ring is the locked one.  2**12 rows hold a
+        # 51 s window of one engine at the benchmark's rates.
+        self._obs_ring = obs_trace.MTStageRing(
+            1 << 12, width=len(obs_trace.DISPATCH_COLUMNS)
+        )
         self._obs_queue_ids: Dict[str, int] = {}
+        self.obs_id = obs_trace.register_engine(self)
 
     # -- flight-recorder surface -------------------------------------------
-
-    def enable_obs_ring(self, capacity: int = 4096) -> None:
-        """Start recording per-dispatch span events (see _note_prep)."""
-        from ..obs.trace import MTStageRing
-
-        if self._obs_ring is None:
-            self._obs_ring = MTStageRing(capacity)
 
     def _obs_queue_id(self, name: str) -> int:
         qid = self._obs_queue_ids.get(name)  # GIL-atomic fast path
@@ -772,18 +923,23 @@ class BatchVerifier:
         return qid
 
     def drain_obs_events(self) -> list:
-        """Decoded dispatcher span events, oldest→newest:
-        (queue_name, padded_lanes, host_prep_ns, t_monotonic_ns)."""
-        ring = self._obs_ring
-        if ring is None:
-            return []
-        # dict() is a C-level copy (GIL-atomic): worker threads may be
-        # interning new names while we decode.
+        """This engine's dispatch rows, oldest→newest, as obs/trace.py
+        DISPATCH_COLUMNS with queue, kind and flush reason by name."""
+        return self.dispatch_rows()[0]
+
+    def dispatch_rows(self) -> Tuple[list, int]:
+        """-> (:meth:`drain_obs_events`' rows, rows the ring has
+        overwritten)."""
+        rows, dropped = self._obs_ring.read()
+        # dict() is a C-level copy (GIL-atomic): the loop may be
+        # interning a new queue's name while another thread decodes.
         names = {v: k for k, v in dict(self._obs_queue_ids).items()}
+        kinds, reasons = obs_trace.DISPATCH_KINDS, obs_trace.FLUSH_REASONS
         return [
-            (names.get(qid, f"queue{qid}"), pad, prep_ns, t_ns)
-            for qid, pad, prep_ns, t_ns in ring.snapshot()
-        ]
+            (r[0], r[1], names.get(r[2], f"queue{r[2]}"), kinds[r[3]],
+             r[4], r[5], reasons[r[6]]) + r[7:]
+            for r in rows
+        ], dropped
 
     def queue_depths(self) -> Dict[str, int]:
         """Items pending per verify queue right now (scrape gauge).
@@ -830,8 +986,6 @@ class BatchVerifier:
         are thread-local, so concurrent engines pinned to different
         chips never fight over a global default."""
         if self.device is None:
-            import contextlib
-
             return contextlib.nullcontext()
         import jax
 
@@ -1061,16 +1215,6 @@ class BatchVerifier:
             st = self._queues[name].stats
             st.padded_lanes += pad
             st.host_prep_time_s += prep_s
-        ring = self._obs_ring
-        if ring is not None:
-            # Dispatcher span event from the worker thread: the ring's
-            # own lock serializes concurrent max_inflight producers.
-            ring.push(
-                self._obs_queue_id(name),
-                pad,
-                int(prep_s * 1e9),
-                time.monotonic_ns(),
-            )
 
     def _note_sign_prep(self, name: str, pad: int, prep_s: float) -> None:
         """Sign-queue sibling of :meth:`_note_prep` (worker thread):
@@ -1079,90 +1223,103 @@ class BatchVerifier:
             st = self._sign_queues[name].stats
             st.padded_lanes += pad
             st.host_prep_time_s += prep_s
-        ring = self._obs_ring
-        if ring is not None:
-            ring.push(
-                self._obs_queue_id("sign_" + name),
-                pad,
-                int(prep_s * 1e9),
-                time.monotonic_ns(),
-            )
 
     def _dispatch_ecdsa(self, items) -> np.ndarray:
+        span = _worker_span()
         import jax.numpy as jnp
 
         from ..ops import p256
 
         n = len(items)
-        b = _bucket_for(n, self.buckets)
+        b = span.lanes = _bucket_for(n, self.buckets)
         # Packed single-upload form: one host->device transfer per
         # dispatch instead of the 8-argument form's eight (per-dispatch
         # host<->device cost, to be measured on the chip).
         t0 = time.perf_counter()
         staging = self._staging.acquire((b, p256.PACKED_COLS), np.uint16)
         try:
-            packed = p256.prepare_packed(items, b, out=staging)
+            with span.phase("prep", span.PREP):
+                packed = p256.prepare_packed(items, b, out=staging)
             self._note_prep("ecdsa_p256", b - n, time.perf_counter() - t0)
             if self.mesh is not None:
                 from . import mesh as mesh_mod
 
                 kernel = self._sharded("ecdsa", mesh_mod.sharded_ecdsa_kernel)
-                return np.asarray(kernel(packed))[:n]
+            else:
+                kernel = p256.ecdsa_verify_kernel_packed
             with self._device_scope():
-                out = p256.ecdsa_verify_kernel_packed(jnp.asarray(packed))
-                return np.asarray(out)[:n]
+                with span.phase("launch", span.LAUNCH):
+                    out = kernel(
+                        packed if self.mesh is not None else jnp.asarray(packed)
+                    )
+                with span.phase("wait", span.WAIT):
+                    return np.asarray(out)[:n]
         finally:
             self._staging.release(staging)
 
     def _dispatch_hmac(self, items) -> np.ndarray:
+        span = _worker_span()
         import jax.numpy as jnp
 
         from ..ops.hmac_sha256 import hmac_verify_kernel_packed
 
         n = len(items)
-        b = _bucket_for(n, self.buckets)
+        b = span.lanes = _bucket_for(n, self.buckets)
         t0 = time.perf_counter()
         staging = self._staging.acquire((b, 24), np.uint32)
         try:
-            # One bulk big-endian word view of the concatenated batch
-            # instead of 3n per-item frombuffer calls.
-            staging[:n] = np.frombuffer(
-                b"".join([key + msg + mac for key, msg, mac in items]),
-                dtype=">u4",
-            ).reshape(n, 24)
-            staging[n:] = 0
+            with span.phase("prep", span.PREP):
+                # One bulk big-endian word view of the concatenated batch
+                # instead of 3n per-item frombuffer calls.
+                staging[:n] = np.frombuffer(
+                    b"".join([key + msg + mac for key, msg, mac in items]),
+                    dtype=">u4",
+                ).reshape(n, 24)
+                staging[n:] = 0
             self._note_prep("hmac_sha256", b - n, time.perf_counter() - t0)
             if self.mesh is not None:
                 from . import mesh as mesh_mod
 
                 kernel = self._sharded("hmac", mesh_mod.sharded_hmac_kernel)
-                return np.asarray(kernel(staging))[:n]
+            else:
+                kernel = hmac_verify_kernel_packed
             with self._device_scope():
-                out = hmac_verify_kernel_packed(jnp.asarray(staging))
-                return np.asarray(out)[:n]
+                with span.phase("launch", span.LAUNCH):
+                    out = kernel(
+                        staging if self.mesh is not None else jnp.asarray(staging)
+                    )
+                with span.phase("wait", span.WAIT):
+                    return np.asarray(out)[:n]
         finally:
             self._staging.release(staging)
 
     def _dispatch_ed25519(self, items) -> np.ndarray:
+        span = _worker_span()
         import jax.numpy as jnp
 
         from ..ops import ed25519 as ed
 
         n = len(items)
-        b = _bucket_for(n, self.buckets)
+        b = span.lanes = _bucket_for(n, self.buckets)
         t0 = time.perf_counter()
         staging = self._staging.acquire((b, ed.PACKED_COLS), np.uint16)
         try:
-            packed = ed.prepare_packed(items, b, out=staging)
+            with span.phase("prep", span.PREP):
+                packed = ed.prepare_packed(items, b, out=staging)
             self._note_prep("ed25519", b - n, time.perf_counter() - t0)
             if self.mesh is not None:
                 from . import mesh as mesh_mod
 
                 kernel = self._sharded("ed25519", mesh_mod.sharded_ed25519_kernel)
-                return np.asarray(kernel(packed))[:n]
+            else:
+                kernel = ed.ed25519_verify_kernel_packed
             with self._device_scope():
-                out = ed.ed25519_verify_kernel_packed(jnp.asarray(packed))
-                return np.asarray(out)[:n]
+                with span.phase("launch", span.LAUNCH):
+                    out = kernel(
+                        packed if self.mesh is not None else jnp.asarray(packed)
+                    )
+                with span.phase("wait", span.WAIT):
+                    return np.asarray(out)[:n]
         finally:
             self._staging.release(staging)
 
@@ -1173,14 +1330,16 @@ class BatchVerifier:
     # like the verify dispatchers.
 
     def _dispatch_sign_ecdsa(self, items) -> list:
+        span = _worker_span()
         from ..ops import p256
 
         n = len(items)
-        b = _bucket_for(n, self.buckets)
+        b = span.lanes = _bucket_for(n, self.buckets)
         t0 = time.perf_counter()
         staging = self._staging.acquire((b, p256.SIGN_COLS), np.uint16)
         try:
-            k_arr, meta = p256.sign_prepare(items, b, out=staging)
+            with span.phase("prep", span.PREP):
+                k_arr, meta = p256.sign_prepare(items, b, out=staging)
             prep = time.perf_counter() - t0
             if self.mesh is not None:
                 from . import mesh as mesh_mod
@@ -1191,9 +1350,13 @@ class BatchVerifier:
             else:
                 kernel = p256.ecdsa_kg_kernel
             with self._device_scope():
-                xz = np.asarray(kernel(k_arr))
+                with span.phase("launch", span.LAUNCH):
+                    out = kernel(k_arr)
+                with span.phase("wait", span.WAIT):
+                    xz = np.asarray(out)
             t1 = time.perf_counter()
-            sigs = p256.sign_finish(items, meta, xz)
+            with span.phase("finish", span.FINISH):
+                sigs = p256.sign_finish(items, meta, xz)
             prep += time.perf_counter() - t1
             self._note_sign_prep("ecdsa_p256", b - n, prep)
             return sigs
@@ -1201,14 +1364,16 @@ class BatchVerifier:
             self._staging.release(staging)
 
     def _dispatch_sign_ed25519(self, items) -> list:
+        span = _worker_span()
         from ..ops import ed25519 as ed
 
         n = len(items)
-        b = _bucket_for(n, self.buckets)
+        b = span.lanes = _bucket_for(n, self.buckets)
         t0 = time.perf_counter()
         staging = self._staging.acquire((b, ed.SIGN_COLS), np.uint16)
         try:
-            r_arr, meta = ed.sign_prepare(items, b, out=staging)
+            with span.phase("prep", span.PREP):
+                r_arr, meta = ed.sign_prepare(items, b, out=staging)
             prep = time.perf_counter() - t0
             if self.mesh is not None:
                 from . import mesh as mesh_mod
@@ -1219,9 +1384,13 @@ class BatchVerifier:
             else:
                 kernel = ed.ed25519_rb_kernel
             with self._device_scope():
-                xyz = np.asarray(kernel(r_arr))
+                with span.phase("launch", span.LAUNCH):
+                    out = kernel(r_arr)
+                with span.phase("wait", span.WAIT):
+                    xyz = np.asarray(out)
             t1 = time.perf_counter()
-            sigs = ed.sign_finish(meta, xyz)
+            with span.phase("finish", span.FINISH):
+                sigs = ed.sign_finish(meta, xyz)
             prep += time.perf_counter() - t1
             self._note_sign_prep("ed25519", b - n, prep)
             return sigs

@@ -696,14 +696,6 @@ async def _run_replica(args) -> int:
 
     from ...obs import trace as obs_trace
 
-    # Engine dispatcher spans are exported by the MINBFT_TRACE_DUMP
-    # shutdown dump, so recording is gated on exactly that knob —
-    # independent of --metrics-port (a dump-only run must not lose
-    # them), and never enabled without an export path (events must not
-    # be recorded only to be discarded).
-    if engine is not None and os.environ.get(obs_trace.TRACE_DUMP_ENV):
-        engine.enable_obs_ring()
-
     # Latency-SLO engine (obs/slo.py): the Handlers built their own
     # BudgetLedger when the policy is enabled (MINBFT_SLO_* env or the
     # config's protocol.slo block) — gather them once for the sampler,
@@ -814,11 +806,11 @@ async def _run_replica(args) -> int:
             pass
 
     def dump_engine_obs() -> None:
-        # Engine dispatcher spans + queue-wait histograms ride the
-        # shutdown dump alongside the replica's stage dump (no-op unless
-        # MINBFT_TRACE_DUMP is set — recorded events must land
-        # somewhere, not silently vanish).  The queue histograms feed
-        # the cluster critical-path merge (obs/critpath.py).
+        # The engine's dispatch rows (always recorded, obs/trace.py
+        # DISPATCH_COLUMNS) + queue-wait histograms ride the shutdown
+        # dump alongside the replica's stage dump (no-op unless
+        # MINBFT_TRACE_DUMP is set).  The queue histograms feed the
+        # cluster critical-path merge (obs/critpath.py).
         base = os.environ.get(obs_trace.TRACE_DUMP_ENV)
         if engine is None or not base:
             return
@@ -829,6 +821,7 @@ async def _run_replica(args) -> int:
         doc = obs_critpath.engine_queue_doc(engine, ident=args.id)
         events = engine.drain_obs_events()
         if events:
+            doc["event_columns"] = list(obs_trace.DISPATCH_COLUMNS)
             doc["events"] = [list(e) for e in events]
         # noqa: AH102 - one-shot crash/shutdown dump; forensics cannot rely on executors
         with open(f"{base}.engine{args.id}.json", "w") as fh:

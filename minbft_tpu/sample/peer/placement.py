@@ -32,6 +32,9 @@ def replica_engine(
         return None, "host crypto (--no-batch)"
     import jax
 
+    from ...utils.jaxcache import record_jax_events
+
+    record_jax_events()  # before the warm-up traces the kernels
     backend = jax.default_backend()
     if backend == "cpu" and not on_cpu:
         if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":

@@ -40,6 +40,7 @@ def enable_compilation_cache(min_compile_secs: float = 1.0) -> str:
     Call before the first kernel compile (import time is fine — this only
     sets config, it never initializes a backend).  Disable entirely with
     MINBFT_JAX_CACHE=0 (returns "")."""
+    record_jax_events()
     if os.environ.get("MINBFT_JAX_CACHE", "1") == "0":
         return ""
     import jax
@@ -50,6 +51,26 @@ def enable_compilation_cache(min_compile_secs: float = 1.0) -> str:
         "jax_persistent_cache_min_compile_time_secs", min_compile_secs
     )
     return cache_dir()
+
+
+_recording = False
+
+
+def record_jax_events() -> None:
+    """Put JAX's own trace / lower / compile / cache-retrieval durations
+    on the process timeline (obs/trace.py ``timeline()["jax"]``): one
+    ``jax.monitoring`` listener a process, registered where the program
+    first configures JAX — here and in ``placement.replica_engine`` —
+    so that every kernel trace of a replica's start-up is caught."""
+    global _recording
+    if _recording:
+        return
+    import jax
+
+    from ..obs.trace import note_jax_event
+
+    jax.monitoring.register_event_duration_secs_listener(note_jax_event)
+    _recording = True
 
 
 def entry_count(cache_dir: str) -> int:

@@ -320,3 +320,176 @@ def test_padded_lane_accounting_is_thread_safe():
         t.join()
     got = eng.stats["hmac_sha256"].padded_lanes - base
     assert got == n_threads * per_thread * 7  # bucket 8, batch 1 -> 7 pads
+
+
+# ---------------------------------------------------------------------------
+# the dispatch record (obs/trace.py DISPATCH_COLUMNS): one row per counted batch
+
+
+def _rows(eng):
+    from minbft_tpu.obs.trace import DISPATCH_COLUMNS
+
+    return [dict(zip(DISPATCH_COLUMNS, e)) for e in eng.drain_obs_events()]
+
+
+def _instants(row):
+    from minbft_tpu.obs.trace import DISPATCH_COLUMNS
+
+    return [row[c] for c in DISPATCH_COLUMNS[8:]]
+
+
+def test_dispatch_rows_written_from_max_inflight_workers_are_never_torn():
+    """max_inflight dispatchers stamp their instants on worker threads at
+    once; every row still belongs to one dispatch: its own id, its own
+    items and lanes, eight instants that never decrease, and the rows
+    number exactly the counted batches."""
+
+    async def run():
+        eng = BatchVerifier(max_batch=2, buckets=(2,), max_inflight=4)
+        items = [_hmac_item(i) for i in range(61)]
+        oks = await asyncio.gather(*[eng.verify_hmac_sha256(*it) for it in items])
+        assert all(oks)
+        st = eng.stats["hmac_sha256"]
+        rows = _rows(eng)
+        assert len(rows) == st.batches >= 31
+        assert sum(r["items"] for r in rows) == st.items == 61
+        assert len({r["dispatch_id"] for r in rows}) == len(rows)
+        for r in rows:
+            assert r["lanes"] == 2 and 1 <= r["items"] <= 2 and r["flags"] == 0
+            t = _instants(r)
+            assert t == sorted(t) and t[0] > 0
+        # dispatches overlapped on their worker threads
+        spans = sorted((r["t_worker_start"], r["t_result"]) for r in rows)
+        assert any(b[0] < a[1] for a, b in zip(spans, spans[1:]))
+        reasons = {}
+        for r in rows:
+            reasons[r["reason"]] = reasons.get(r["reason"], 0) + 1
+        assert reasons == st.flush_reasons
+
+    asyncio.run(run())
+
+
+def test_dispatch_rows_flag_timeout_fallback_and_host_queues():
+    """Fallback and timed-out dispatches are recorded with a flag, not
+    dropped; a host queue's rows say that no device phase ran; a dispatch
+    that raised is counted in neither batches nor rows."""
+    import threading
+
+    import numpy as np
+
+    from minbft_tpu.obs import trace as obs_trace
+
+    async def run():
+        eng = BatchVerifier(max_batch=8, dispatch_timeout=0.2)
+        hang = threading.Event()
+
+        def hanging_dispatch(items):
+            hang.wait(30)
+
+        eng._host_fallback_for = lambda name: (
+            lambda items: np.array([True] * len(items), dtype=bool)
+        )
+        q = eng._queue("ecdsa_p256", hanging_dispatch)
+        for k in range(4):  # three hangs write the device off; the fourth skips it
+            assert await asyncio.wait_for(q.submit(b"item-%d" % k), 10) is True
+        hang.set()
+        rows = _rows(eng)
+        assert len(rows) == q.stats.batches == 4
+        timed_out = obs_trace.FLAG_FALLBACK | obs_trace.FLAG_TIMEOUT | obs_trace.FLAG_NO_DEVICE
+        assert [r["flags"] for r in rows[:3]] == [timed_out] * 3
+        assert rows[3]["flags"] == obs_trace.FLAG_FALLBACK | obs_trace.FLAG_NO_DEVICE
+        for r in rows:
+            t = _instants(r)
+            assert t == sorted(t)
+        # the timeout shows in the row: flush to resolved spans it
+        assert rows[0]["t_resolved"] - rows[0]["t_flush"] >= 0.2e9 * 4  # first dispatch: 4x
+        assert rows[3]["t_resolved"] - rows[3]["t_flush"] < 0.15e9
+
+        host = BatchVerifier(max_batch=8)
+        from minbft_tpu.utils import hostcrypto as hc
+
+        d, pub = hc.keygen()
+        dg = hashlib.sha256(b"host").digest()
+        assert await host.verify_ecdsa_p256_host(pub, dg, hc.ecdsa_sign(d, dg))
+        (row,) = _rows(host)
+        assert row["queue"] == "ecdsa_p256_host"
+        assert row["flags"] == obs_trace.FLAG_NO_DEVICE and row["lanes"] == 0
+
+        def raising(items):
+            raise RuntimeError("dispatch failed")
+
+        bad = BatchVerifier(max_batch=8, dispatch_timeout=0)
+        try:
+            await bad._queue("hmac_sha256", raising).submit(b"x")
+        except RuntimeError:
+            pass
+        assert bad.stats["hmac_sha256"].batches == 0 and _rows(bad) == []
+
+    asyncio.run(run())
+
+
+def test_the_dispatch_row_is_never_in_the_callers_way():
+    """The batch's futures resolve before its row is written, so a fault
+    in the recorder cannot hang the protocol; a flush reason the table
+    lacks reads `other`; and the row is made from a copy of the span's
+    instants, so a timed-out worker stamping late changes no written row."""
+    from minbft_tpu.obs import trace as obs_trace
+
+    async def run():
+        eng = BatchVerifier(max_batch=8, buckets=(8,))
+        q = eng._queue("hmac_sha256", eng._dispatch_hmac)
+        noted = []
+
+        def broken_note(span, batch, *rest):
+            noted.append([f.done() for _it, f, _t in batch])
+            raise RuntimeError("recorder fault")
+
+        q._note_dispatch = broken_note
+        assert await asyncio.wait_for(eng.verify_hmac_sha256(*_hmac_item(1)), 30)
+        assert noted == [[True]]  # resolved first
+        del q._note_dispatch
+
+        fut = asyncio.get_running_loop().create_future()
+        item = _hmac_item(2)
+        q.inflight += 1
+        q._inflight_futs[item] = [fut]
+        await q._run([(item, fut, obs_trace.time.monotonic_ns())], "a-new-reason")
+        assert fut.result() is True
+        (row,) = _rows(eng)
+        assert row["reason"] == "other"
+        assert q.stats.flush_reasons["a-new-reason"] == 1
+
+        span = engine_mod._DispatchSpan(engine_mod._phase_names("x"), 99)
+        span.t[:] = [5, 3, 9, 0, 0]  # out of order, as a racing worker could leave them
+        q._note_dispatch(span, [(None, None, 1)], "idle", 4, 20, False)
+        assert span.t == [5, 3, 9, 0, 0]  # the span itself is not rewritten
+        assert _instants(_rows(eng)[-1]) == [1, 4, 5, 5, 9, 9, 9, 20]
+
+    from minbft_tpu.parallel import engine as engine_mod
+
+    asyncio.run(run())
+
+
+def test_dispatch_annotations_do_not_raise_without_a_profiler_session():
+    """The worker's phases and the loop's resolve are TraceAnnotations:
+    with no session they are a flag test, and the probe of a written-off
+    device (a task whose context was copied from a live dispatch) stamps
+    into no live row."""
+    from minbft_tpu.parallel import engine as engine_mod
+
+    span = engine_mod._DispatchSpan(engine_mod._phase_names("ecdsa_p256"), 7)
+    with span.phase("prep", span.PREP):
+        pass
+    assert span.t[span.PREP] > 0 and span.t[span.LAUNCH] == 0
+    assert engine_mod._SPAN.get() is None
+    direct = engine_mod._worker_span()  # a dispatcher called outside _run
+    assert direct.dispatch_id == 0 and direct.t[0] > 0
+
+    async def run():
+        eng = BatchVerifier(max_batch=8, buckets=(8,))
+        assert await eng.verify_hmac_sha256(*_hmac_item(1))
+        (row,) = _rows(eng)
+        assert row["t_launch_end"] > row["t_prep_end"] > row["t_worker_start"]
+        assert engine_mod._SPAN.get() is None  # set in _run's own context only
+
+    asyncio.run(run())

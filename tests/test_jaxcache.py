@@ -95,3 +95,53 @@ def test_entry_count_counts_executables(tmp_path, names, want):
     assert jaxcache.entry_count(str(tmp_path)) == want
     assert jaxcache.entry_count(str(tmp_path / "absent")) == 0
     assert jaxcache.entry_count("") == 0
+
+
+def test_jax_listener_sees_a_fresh_jit_and_names_its_events():
+    """One jax.monitoring listener a process puts JAX's own trace, lower
+    and compile durations on the process timeline; the names are the
+    installed JAX's own (pinned here: a JAX that renames them shows as a
+    failure, not as a silent hole in setup.jax_trace_s)."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    from jax._src import dispatch
+
+    from minbft_tpu.obs import trace as obs_trace
+
+    assert obs_trace.JAX_EVENTS[:3] == (
+        dispatch.JAXPR_TRACE_EVENT,
+        dispatch.JAXPR_TO_MLIR_MODULE_EVENT,
+        dispatch.BACKEND_COMPILE_EVENT,
+    )
+    from jax._src import compiler
+
+    import inspect
+
+    assert obs_trace.JAX_EVENTS[3] in inspect.getsource(compiler)
+
+    jaxcache.record_jax_events()
+    jaxcache.record_jax_events()  # once per process, however often asked
+    from jax._src import monitoring
+
+    listeners = monitoring.get_event_duration_listeners()
+    assert listeners.count(obs_trace.note_jax_event) == 1
+
+    mark = len(obs_trace.timeline()["jax"]["rows"])
+    t0 = time.monotonic_ns()
+    salt = float(t0 % 1000)  # a function JAX has not traced in this process
+
+    def fresh(x):
+        for k in range(400):  # enough operations to take milliseconds to trace
+            x = jnp.sin(x) * salt + float(k)
+        return x
+
+    jax.jit(fresh)(jnp.arange(7.0)).block_until_ready()
+    t1 = time.monotonic_ns()
+    rows = obs_trace.timeline()["jax"]["rows"][mark:]
+    names = {name for name, _t, _d in rows}
+    assert set(obs_trace.JAX_EVENTS[:2]) <= names  # traced and lowered here
+    assert names <= set(obs_trace.JAX_EVENTS)
+    for _name, t_end, duration_ns in rows:
+        assert t0 <= t_end <= t1 and 1_000_000 <= duration_ns <= t1 - t0  # from 1 ms up

@@ -111,14 +111,15 @@ def test_mt_ring_event_loop_plus_worker_threads():
     asyncio.run(run())
 
 
-def test_engine_worker_ring_records_dispatch_spans():
-    """The engine's _note_prep pushes dispatcher span events from worker
-    threads into its MTStageRing; drain decodes queue names."""
+def test_engine_ring_records_one_dispatch_row_per_batch():
+    """The engine's always-on dispatch record: one row per counted batch,
+    written by _run on the loop with the instants the dispatcher stamped
+    on its worker thread; drain decodes queue, kind and flush reason."""
+    from minbft_tpu.obs import trace as obs_trace
     from minbft_tpu.parallel import BatchVerifier
 
     async def run():
         eng = BatchVerifier(max_batch=8, buckets=(8,))
-        eng.enable_obs_ring(capacity=256)
         key, msg, mac = b"\x11" * 32, b"\x22" * 32, b"\x33" * 32
         import hashlib
         import hmac as hmac_mod
@@ -129,16 +130,175 @@ def test_engine_worker_ring_records_dispatch_spans():
         )
         assert all(oks)
         events = eng.drain_obs_events()
-        assert events, "no dispatcher span events recorded"
-        names = {e[0] for e in events}
-        assert names == {"hmac_sha256"}
-        for _name, pad, prep_ns, t_ns in events:
-            assert pad >= 0 and prep_ns >= 0 and t_ns > 0
-        # disabled engines pay one attribute check and record nothing
+        assert len(events) == eng.stats["hmac_sha256"].batches > 0
+        cols = obs_trace.DISPATCH_COLUMNS
+        for e in events:
+            row = dict(zip(cols, e))
+            assert row["engine"] == eng.obs_id
+            assert (row["queue"], row["kind"]) == ("hmac_sha256", "verify")
+            assert row["reason"] in obs_trace.FLUSH_REASONS
+            assert row["lanes"] == 8 and 0 < row["items"] <= 8
+            assert row["flags"] == 0
+            instants = e[cols.index("t_first_enqueue"):]
+            assert len(instants) == 8 and instants[0] > 0
+            assert list(instants) == sorted(instants)
+            # the device phases took time; verify has no finish phase
+            assert row["t_result"] > row["t_worker_start"]
+            assert row["t_finish_end"] == row["t_result"]
+        # the ring always exists: an engine that has dispatched nothing
+        # reads empty, and the two engines' ids differ
         eng2 = BatchVerifier(max_batch=8, buckets=(8,))
-        assert eng2.drain_obs_events() == []
+        assert eng2.drain_obs_events() == [] and eng2.obs_id != eng.obs_id
+        # ... and both are on the process timeline, by id, while alive
+        by_id = {d["engine"]: d for d in obs_trace.timeline()["dispatch"]}
+        assert by_id[eng.obs_id]["rows"] == events
+        assert by_id[eng.obs_id]["dropped"] == 0
+        assert by_id[eng2.obs_id]["rows"] == []
 
     asyncio.run(run())
+
+
+def test_ring_rows_widen_and_dropped_counts_a_wrapped_ring():
+    """A ring's row is as wide as it was made; `dropped` counts the rows
+    a wrapped ring has overwritten, and `read` gives both together."""
+    for cls in (StageRing, MTStageRing):
+        r = cls(capacity=4, width=6)
+        assert r.read() == ([], 0)
+        for k in range(3):
+            r.push_row((k, 1, 2, 3, 4, 5))
+        assert r.read() == ([(k, 1, 2, 3, 4, 5) for k in range(3)], 0)
+        for k in range(3, 11):
+            r.push_row((k, 1, 2, 3, 4, 5))
+        rows, dropped = r.read()
+        assert [row[0] for row in rows] == [7, 8, 9, 10]
+        assert dropped == 7
+        with pytest.raises(ValueError):
+            r.push_row((1, 2, 3, 4))  # a row of another width is refused, not cut
+        with pytest.raises(ValueError):
+            r.push(1, 2, 3, 4)  # the four-store push is the four-wide ring's
+        assert r.read() == (rows, 7)
+        # a four-wide ring takes both, and they land in the same rows
+        four = cls(capacity=4)
+        four.push(1, 2, 3, 4)
+        four.push_row((5, 6, 7, 8))
+        assert four.read() == ([(1, 2, 3, 4), (5, 6, 7, 8)], 0)
+
+
+def test_collector_ring_catches_a_forced_full_pass():
+    import gc
+
+    from minbft_tpu.obs import trace as obs_trace
+
+    obs_trace.install_collector_clock()
+    obs_trace.install_collector_clock()  # once per process, however often asked
+    assert gc.callbacks.count(obs_trace._on_gc) == 1
+    before = len(obs_trace.timeline()["gc"]["rows"])
+    t0 = obs_trace.time.monotonic_ns()
+    gc.collect(2)
+    t1 = obs_trace.time.monotonic_ns()
+    rows = obs_trace.timeline()["gc"]["rows"]
+    assert len(rows) == before + 1
+    generation, t_start, duration_ns = rows[-1]
+    assert generation == 2 and t0 <= t_start and t_start + duration_ns <= t1
+    # short passes of the young generations are not recorded
+    gc.collect(0)
+    newest = obs_trace.timeline()["gc"]["rows"][-1]
+    assert newest == rows[-1] or newest[2] >= obs_trace._MIN_NS
+
+
+@pytest.mark.parametrize("how,low,high", [("sleeps", 0.5, 1.01), ("spins", 0.0, 0.5)])
+def test_loop_idle_clock_tells_a_sleeping_loop_from_a_spinning_one(how, low, high):
+    """The idle clock reads about 1 on a loop that sleeps and about 0 on
+    one that spins (coarse: nothing here is tighter than 2x)."""
+    import time
+
+    from minbft_tpu.obs import looplag
+
+    async def run():
+        loop = asyncio.get_running_loop()
+        clock = looplag.install_idle_clock(loop)
+        assert clock is not None
+        assert looplag.install_idle_clock(loop) is clock  # once per loop
+        t0 = time.monotonic_ns()
+        if how == "sleeps":
+            await asyncio.sleep(0.3)
+        else:
+            while time.monotonic_ns() - t0 < 300_000_000:
+                await asyncio.sleep(0)  # a turn of the loop, always runnable
+        t1 = time.monotonic_ns()
+        mine = [c for c in looplag.idle_clocks() if c["current"]]
+        assert len(mine) == 1
+        rec = mine[0]
+        assert rec["slot_ns"] == 10_000_000 and rec["from_ns"] <= t0
+        lo, hi = t0 // rec["slot_ns"], t1 // rec["slot_ns"]
+        idle = sum(ns for s, ns in rec["idle"] if lo < s < hi)
+        assert all(0 < ns <= rec["slot_ns"] for _s, ns in rec["idle"])
+        return idle / ((hi - lo - 1) * rec["slot_ns"])
+
+    share = asyncio.run(run())
+    assert low <= share <= high, share
+
+
+def test_loop_idle_clock_records_nothing_on_a_loop_without_a_selector():
+    from minbft_tpu.obs import looplag
+
+    class NoSelector:
+        pass
+
+    assert looplag.install_idle_clock(NoSelector()) is None
+
+
+def test_loop_idle_clock_splits_a_sleep_over_its_slots():
+    from minbft_tpu.obs.looplag import LoopIdleClock
+
+    clock = LoopIdleClock()
+    ns = clock.SLOT_NS
+    base = (clock.since_ns // ns + 2) * ns
+    clock.add(base + 4_000_000, base + 2 * ns + 1_000_000)
+    clock.add(base + 2 * ns + 5_000_000, base + 2 * ns + 6_000_000)
+    got = {s - base // ns: idle for s, idle in clock.read()["idle"]}
+    assert got == {0: 6_000_000, 1: ns, 2: 2_000_000}
+
+
+def test_client_rows_count_one_per_request():
+    """Always on, no recorder: one `start` row a request on the process
+    timeline (what finds the benchmark's window), and nothing else."""
+    from minbft_tpu.client import new_client
+    from minbft_tpu.obs import trace as obs_trace
+    from minbft_tpu.sample.authentication import generate_testnet_keys
+    from minbft_tpu.sample.config import SimpleConfiger
+    from minbft_tpu.sample.conn.inprocess import InProcessClientConnector
+    from minbft_tpu.sample.peer.placement import start_local_cluster
+
+    async def run():
+        store = generate_testnet_keys(3, n_clients=1)
+        cfg = SimpleConfiger(n=3, f=1, timeout_request=30.0, timeout_prepare=15.0)
+        cluster = await start_local_cluster(store, cfg, no_batch=True)
+        client = new_client(
+            0, 3, 1, store.client_authenticator(0),
+            InProcessClientConnector(cluster.stubs),
+        )
+        return cluster, client
+
+    async def drive():
+        cluster, client = await run()
+        try:
+            await client.start()
+            assert client._trace is None  # the gated recorder is off
+            mark = len(obs_trace.timeline()["client"]["rows"])
+            for k in range(5):
+                await asyncio.wait_for(client.request(b"op-%d" % k), 30)
+            rows = obs_trace.timeline()["client"]["rows"][mark:]
+        finally:
+            await client.stop()
+            await cluster.stop()
+        return rows
+
+    rows = asyncio.run(drive())
+    mine = [r for r in rows if r[0] == 0]
+    assert [r[2] for r in mine] == ["start"] * 5
+    assert len({seq for _cid, seq, _stage, _t in mine}) == 5
+    assert [r[3] for r in mine] == sorted(r[3] for r in mine)
 
 
 def test_engine_queue_wait_and_service_histograms():

@@ -22,6 +22,7 @@ import hashlib
 import threading
 
 import numpy as np
+import pytest
 
 from minbft_tpu import api
 from minbft_tpu.ops import ed25519 as ed
@@ -275,6 +276,53 @@ def test_sign_queue_hung_dispatch_falls_back_and_writes_off():
         assert asyncio.get_running_loop().time() - t0 < 0.15
         assert sq.stats.host_fallback_items == 4
         hang.set()
+        return True
+
+    assert asyncio.run(scenario())
+
+
+@pytest.mark.parametrize("how", ["device", "fallback", "timeout"])
+def test_sign_dispatch_rows_one_per_counted_batch(how):
+    """The sign queues' side of the dispatch record: rows == batches on
+    the device path, on the host fallback and across a hung dispatch,
+    the eight instants never decreasing, the flags saying which it was."""
+    from minbft_tpu.obs import trace as obs_trace
+
+    cols = obs_trace.DISPATCH_COLUMNS
+
+    async def scenario():
+        d, _pub = hc.keygen()
+        digests = [hashlib.sha256(b"row-%d" % i).digest() for i in range(20)]
+        if how == "device":
+            eng = BatchVerifier(max_batch=_BUCKET, buckets=(_BUCKET,), sign_on_device=True)
+            await asyncio.gather(*[eng.sign_ecdsa_p256(d, dg) for dg in digests])
+            want = 0
+        elif how == "fallback":
+            eng = BatchVerifier(max_batch=_BUCKET, buckets=(_BUCKET,))  # auto: host on CPU
+            await asyncio.gather(*[eng.sign_ecdsa_p256(d, dg) for dg in digests])
+            want = obs_trace.FLAG_FALLBACK | obs_trace.FLAG_NO_DEVICE
+        else:
+            eng = BatchVerifier(max_batch=8, dispatch_timeout=0.1, sign_on_device=True)
+            hang = threading.Event()
+            sq = eng._sign_queue("ecdsa_p256", lambda items: hang.wait(30))
+            sq._device_ever_succeeded = True  # no cold-compile headroom
+            await asyncio.wait_for(sq.submit((d, digests[0])), 10)
+            hang.set()
+            want = (obs_trace.FLAG_FALLBACK | obs_trace.FLAG_TIMEOUT
+                    | obs_trace.FLAG_NO_DEVICE)
+        st = eng.sign_stats["ecdsa_p256"]
+        rows = [dict(zip(cols, e)) for e in eng.drain_obs_events()]
+        assert len(rows) == st.batches > 0
+        assert sum(r["items"] for r in rows) == st.items
+        for r in rows:
+            assert (r["queue"], r["kind"]) == ("sign_ecdsa_p256", "sign")
+            assert r["flags"] == want
+            t = [r[c] for c in cols[8:]]
+            assert t == sorted(t) and t[0] > 0
+            if how == "device":
+                # sign has a finish phase on the worker, after the result
+                assert r["t_finish_end"] > r["t_result"] > r["t_launch_end"]
+                assert r["lanes"] == _BUCKET
         return True
 
     assert asyncio.run(scenario())

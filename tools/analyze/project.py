@@ -402,9 +402,9 @@ def default_config() -> AnalyzeConfig:
                     # worker threads and must hold _stats_lock.
                     "_sign_queues.stats.padded_lanes",
                     "_sign_queues.stats.host_prep_time_s",
-                    # Obs-ring queue-name interning: _obs_queue_id runs
-                    # on worker threads too (lock-free read, locked
-                    # insert).
+                    # Dispatch-record queue-name interning: lock-free
+                    # read, locked insert (a queue is made on the loop;
+                    # timeline() decodes from any thread).
                     "_obs_queue_ids",
                 ),
                 mode="threads",
@@ -482,13 +482,13 @@ def default_config() -> AnalyzeConfig:
                 path="minbft_tpu/obs/trace.py",
                 cls="StageRing",
                 locks=(),
-                guarded=("_a", "_b", "_c", "_t", "_idx", "_n"),
+                guarded=("_buf", "_idx", "_n", "_pushed"),
             ),
             LockClassSpec(
                 path="minbft_tpu/obs/trace.py",
                 cls="MTStageRing",
                 locks=("_lock",),
-                guarded=("_a", "_b", "_c", "_t", "_idx", "_n"),
+                guarded=("_buf", "_idx", "_n", "_pushed"),
                 mode="threads",
             ),
             # The recorder's pairing map is event-loop confined like the
