@@ -453,7 +453,10 @@ def timeline() -> dict:
     - ``dispatch``: per live engine ``{"engine", "rows", "dropped"}``,
       rows as :data:`DISPATCH_COLUMNS` with names decoded;
     - ``gc``: rows ``(generation, t_start, duration_ns)``, every
-      generation-2 pass and any pass of 1 ms or more;
+      generation-2 pass and any pass of 1 ms or more; beside them the
+      collector's policy as it is now: ``frozen`` (objects in the permanent
+      generation, which no pass walks) and ``thresholds``
+      (placement.settle_collector sets both once warm-up ends);
     - ``jax``: rows ``(event, t_end, duration_ns)``, every event of
       :data:`JAX_EVENTS` that took 1 ms or more;
     - ``client``: rows ``(client_id, seq, "start", t)``, one a request;
@@ -471,7 +474,12 @@ def timeline() -> dict:
     return {
         "dispatch_columns": list(DISPATCH_COLUMNS),
         "dispatch": dispatch,
-        "gc": {"rows": gc_rows, "dropped": gc_dropped},
+        "gc": {
+            "rows": gc_rows,
+            "dropped": gc_dropped,
+            "frozen": gc.get_freeze_count(),
+            "thresholds": list(gc.get_threshold()),
+        },
         "jax": {
             "rows": [(JAX_EVENTS[i], t, d) for i, t, d in jax_rows],
             "dropped": jax_dropped,

@@ -630,11 +630,13 @@ async def _run_replica(args) -> int:
         engine_pool.engines if engine_pool is not None
         else [engine] if engine is not None else []
     )
-    if to_warm:
-        import time as _time
+    # Then the collector is settled (warm_engines' last act, engines or
+    # none): what start-up left is frozen before the listener binds.
+    import time as _time
 
-        t_warm = _time.monotonic()
-        await warm_engines(to_warm, warm_schemes)
+    t_warm = _time.monotonic()
+    await warm_engines(to_warm, warm_schemes)
+    if to_warm:
         print(
             f"replica {args.id} engine warm ({', '.join(warm_schemes)}) in "
             f"{_time.monotonic() - t_warm:.1f}s",

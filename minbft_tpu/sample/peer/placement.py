@@ -16,8 +16,35 @@ and the choice is always reported, never silent.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import os
 from typing import List, Optional, Tuple
+
+# The collector's thresholds of a serving replica (settle_collector), from
+# every pass timed on the chip machine's host with 128 writes in flight
+# (PERF.md section 6, PR 27).  No pass of any generation collected
+# anything there: what the protocol allocates dies by reference count, so
+# every pass is overhead and its cost is the objects it walks, 0.4-0.9 us
+# each.
+# - 50,000 allocations net of deallocations before a youngest pass.  At
+#   CPython's 700 a block of 128 commits (~400 tracked objects a write in
+#   flight, ~50,000 in all) trips it 105-180 times a second, and with the
+#   middle passes that follow that is 4.5-6.5 % of the loop, and every
+#   write in flight is promoted (~117,000 objects a second into the oldest
+#   generation).  Above the writes in flight only what stays is counted:
+#   the heap's own growth, 7,000-10,000 objects a second, so a pass comes
+#   every 2-6 s, walks at most 50,000 objects and holds the loop 35-50 ms
+#   (about 1 %).  25,000 still trips on the blocks (2.6 % at n=7);
+#   100,000 holds the loop 77-89 ms a pass for the same rate.
+# - a middle pass after 3 youngest ones, not 11: it walks what those
+#   promoted, 50-145 ms every 6-20 s (under 1 %); after 11 it would walk
+#   half a million objects at once.
+# - 10 middle passes before a full one, CPython's own: with promotions down
+#   to the heap's growth that is minutes apart, and after it the
+#   interpreter's quarter rule (a full pass only once a quarter of the
+#   oldest generation is new) holds full passes to 4 x (cost an object) x
+#   (growth a second), under 2 % at any heap size.
+COLLECTOR_THRESHOLDS = (50_000, 2, 10)
 
 
 def replica_engine(
@@ -120,7 +147,8 @@ async def warm_engines(engines, schemes=("ecdsa_p256",)) -> None:
     to its device: engines that share one warm one after the other (the
     first compiles, the rest reuse its executable), engines on distinct
     devices side by side (each compiles its own — a four-chip pool warms
-    in the time of one chip, not four)."""
+    in the time of one chip, not four).  Warm-up ends with
+    :func:`settle_collector`, with engines or with none."""
     import asyncio
 
     by_device: dict = {}
@@ -132,6 +160,31 @@ async def warm_engines(engines, schemes=("ecdsa_p256",)) -> None:
             await warm_engine(engine, schemes)
 
     await asyncio.gather(*[one_after_the_other(g) for g in by_device.values()])
+    settle_collector()
+
+
+def settle_collector() -> None:
+    """The collector's policy of a replica about to serve, set where
+    warm-up ends (:func:`warm_engines`' last act, so ``peer run``, the
+    in-process clusters and the pool phase all get it from one place,
+    before ``start()`` and before the listener binds: the one full pass it
+    costs is off every protocol timer).
+
+    Tracing the kernels leaves ~700,000 tracked objects alive for the life
+    of the process (jaxprs, lowered modules, the imports), and CPython
+    walks all of them in every full pass: 0.35-0.44 s a pass, 12-15 passes
+    in 20 s of service.  ``gc.freeze()`` moves them to the permanent
+    generation, which no pass walks; the ``gc.collect()`` before it keeps
+    garbage out of there, and the one after it (over an empty heap, under
+    1 ms) resets the interpreter's count of long-lived objects, which the
+    quarter rule would otherwise still take from the frozen heap.  Then
+    :data:`COLLECTOR_THRESHOLDS`.  The collector stays on and keeps its own
+    accounting; nothing here reads a configuration.  A second call only
+    freezes what has been allocated since."""
+    gc.collect()
+    gc.freeze()
+    gc.collect()
+    gc.set_threshold(*COLLECTOR_THRESHOLDS)
 
 
 @dataclasses.dataclass

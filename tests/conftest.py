@@ -11,7 +11,10 @@ in place before the CPU client is (lazily) created, and the platform is
 forced through ``jax.config`` which wins over the env var.
 """
 
+import gc
 import os
+
+import pytest
 
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -34,6 +37,21 @@ from minbft_tpu.utils.loop import maybe_enable_uvloop, uvloop_requested  # noqa:
 
 if uvloop_requested():
     maybe_enable_uvloop()
+
+
+# The collector as this process found it.  placement.settle_collector
+# (the end of every warm-up) freezes the heap and raises the thresholds
+# process-wide; a serving replica wants that for life, 700 cases in one
+# process do not want each other's frozen heaps.
+_COLLECTOR_AS_FOUND = gc.get_threshold()
+
+
+@pytest.fixture(autouse=True)
+def _collector_as_found():
+    yield
+    if gc.get_threshold() != _COLLECTOR_AS_FOUND:
+        gc.unfreeze()
+        gc.set_threshold(*_COLLECTOR_AS_FOUND)
 
 
 async def make_cluster(
