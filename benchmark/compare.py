@@ -3,20 +3,21 @@
 The reference is the service's semantics written down plainly, with nothing
 of the program in it: a hash chain replayed serially over the committed
 order (``sha256(height || previous digest || payload)`` per block, the
-digest being the write's result), and OpenSSL's ECDSA over the reply bytes
-that the clients accepted.  Every number compared is a count of breaches
-of a guarantee that the configuration states, so every limit is 0.
+digest being the write's result), and OpenSSL's verdict on the signature of
+every reply that the clients accepted, by the file of the configuration's
+scheme (``benchmark/verifiers/<scheme>.py``).  Every number compared is a
+count of breaches of a guarantee that the configuration states, so every
+limit is 0.
 """
 
 from __future__ import annotations
 
 import asyncio
 import hashlib
+import itertools
 import struct
 import time
-from typing import Dict, Iterable, List, Sequence
-
-from .manifest import BenchmarkError
+from typing import Callable, Dict, Iterable, List, Sequence, Tuple
 
 LIMITS = {
     "never_answered": 0,
@@ -43,43 +44,12 @@ def replay(order: Sequence[bytes]) -> tuple:
     return prev, results
 
 
-class ReplyVerifier:
-    """OpenSSL's verdict on a reply's signature, by the configuration's
-    scheme and the replicas' public keys."""
-
-    def __init__(self, scheme: str, replica_pubs: dict):
-        if scheme != "ecdsa-p256":
-            raise BenchmarkError(f"no reference verifier for scheme {scheme!r} yet")
-        from cryptography.hazmat.primitives.asymmetric import ec
-
-        self._keys = {
-            rid: ec.EllipticCurvePublicNumbers(x, y, ec.SECP256R1()).public_key()
-            for rid, (x, y) in replica_pubs.items()
-        }
-
-    def valid(self, replica_id: int, msg: bytes, signature: bytes) -> bool:
-        from cryptography.exceptions import InvalidSignature
-        from cryptography.hazmat.primitives import hashes
-        from cryptography.hazmat.primitives.asymmetric import ec, utils
-
-        key = self._keys.get(replica_id)
-        if key is None or len(signature) != 64:
-            return False
-        der = utils.encode_dss_signature(
-            int.from_bytes(signature[:32], "big"), int.from_bytes(signature[32:], "big")
-        )
-        try:
-            key.verify(der, msg, ec.ECDSA(hashes.SHA256()))
-        except InvalidSignature:
-            return False
-        return True
-
-
 def quorum_shortfalls(issued: Iterable, accepted_by_client: Sequence[Sequence[tuple]],
-                      verifier: ReplyVerifier, f: int) -> int:
+                      valid: Callable[[int, bytes, bytes], bool], f: int) -> int:
     """Acknowledged writes with fewer than f+1 replies that the client had
     accepted by the time of the ack, each from a distinct replica, each
-    naming this client and this result, each validly signed."""
+    naming this client and this result, each validly signed
+    (``valid(replica id, bytes signed, signature)``: the reference's verdict)."""
     by_digest: List[Dict[bytes, list]] = []
     for accepted in accepted_by_client:
         index: Dict[bytes, list] = {}
@@ -98,33 +68,42 @@ def quorum_shortfalls(issued: Iterable, accepted_by_client: Sequence[Sequence[tu
         want = hashlib.sha256(rec.result).digest()
         for pos, rid, cid, msg, sig in by_digest[rec.client].get(want, ()):
             if pos < rec.mark and cid == rec.client and rid not in voters:
-                if verifier.valid(rid, msg, sig):
+                if valid(rid, msg, sig):
                     voters.add(rid)
         if len(voters) < f + 1:
             short += 1
     return short
 
 
-def engine_counts(engine, queue: str) -> dict:
-    """One engine's counters of ``queue`` (verify and sign side), through
-    the engine's public ``stats``/``sign_stats``/``written_off``."""
-    v = engine.stats.get(queue)
-    s = engine.sign_stats.get(queue)
-    return {
-        "verify_items": v.items if v else 0,
-        "verify_batches": v.batches if v else 0,
-        "verify_padded": v.padded_lanes if v else 0,
-        "verify_prep_s": v.host_prep_time_s if v else 0.0,
-        "verify_timeouts": v.dispatch_timeouts if v else 0,
-        "verify_wait_buckets": list(v.queue_wait.buckets) if v else [],
-        "sign_items": s.items if s else 0,
-        "sign_batches": s.batches if s else 0,
-        "sign_padded": s.padded_lanes if s else 0,
-        "sign_prep_s": s.host_prep_time_s if s else 0.0,
-        "sign_timeouts": s.dispatch_timeouts if s else 0,
-        "sign_fallback": s.host_fallback_items if s else 0,
-        "written_off": len(engine.written_off()),
-    }
+# key here -> attribute of the engine's VerifyStats / SignStats
+_COUNTED = {"items": "items", "batches": "batches", "padded": "padded_lanes",
+            "prep_s": "host_prep_time_s", "timeouts": "dispatch_timeouts"}
+
+
+def engine_counts(engine, queues: Sequence[str]) -> dict:
+    """One engine's counters, summed over its device ``queues`` (the keys
+    of the engine's public ``stats`` / ``sign_stats``; histogram buckets
+    added bucket by bucket), and under ``items`` and ``batches`` those of
+    each side ``(queue, "verify" | "sign")`` by itself."""
+    out: dict = {f"{kind}_{key}": 0 for kind in ("verify", "sign") for key in _COUNTED}
+    out.update(verify_wait_buckets=[], sign_fallback=0,
+               written_off=len(engine.written_off()), items={}, batches={})
+    for queue in queues:
+        for kind, stats in (("verify", engine.stats.get(queue)),
+                            ("sign", engine.sign_stats.get(queue))):
+            out["items"][queue, kind] = out["batches"][queue, kind] = 0
+            if stats is None:  # a side the engine has not been asked for
+                continue
+            for key, attribute in _COUNTED.items():
+                out[f"{kind}_{key}"] += getattr(stats, attribute)
+            out["items"][queue, kind] = stats.items
+            out["batches"][queue, kind] = stats.batches
+            if kind == "sign":
+                out["sign_fallback"] += stats.host_fallback_items
+            else:
+                out["verify_wait_buckets"] = [a + b for a, b in itertools.zip_longest(
+                    out["verify_wait_buckets"], stats.queue_wait.buckets, fillvalue=0)]
+    return out
 
 
 def counts_delta(after: dict, before: dict) -> dict:
@@ -133,18 +112,22 @@ def counts_delta(after: dict, before: dict) -> dict:
         if isinstance(v, list):
             b = before.get(k) or [0] * len(v)
             out[k] = [x - y for x, y in zip(v, b)]
+        elif isinstance(v, dict):
+            out[k] = {side: n - before.get(k, {}).get(side, 0) for side, n in v.items()}
         else:
             out[k] = v - before.get(k, 0)
     return out
 
 
-def device_path_faults(deltas: Sequence[dict], after: Sequence[dict]) -> int:
-    """Engines that did no device work in the window, plus every dispatch
+def device_path_faults(deltas: Sequence[dict], after: Sequence[dict],
+                       sides: Iterable[Tuple[str, str]]) -> int:
+    """Engines that did no device work in the window on a side ``(queue,
+    kind)`` for which the configuration names a kernel, plus every dispatch
     timeout, host-fallback item and written-off queue the process has seen."""
+    sides = set(sides)
     faults = 0
     for d, now in zip(deltas, after):
-        faults += d["verify_items"] <= 0
-        faults += d["sign_items"] <= 0
+        faults += sum(d["items"][side] <= 0 for side in sides)
         faults += now["verify_timeouts"] + now["sign_timeouts"]
         faults += now["sign_fallback"] + now["written_off"]
     return faults
@@ -186,7 +169,7 @@ def compare(
     executed = set()
     for chain in chains:
         executed.update(chain)
-    verifier = ReplyVerifier(config["scheme"], system.store.replica_pubs())
+    valid = system.verifier.make(system.store.replica_pubs())
     acked = [r for r in issued if r.acked is not None]
     return {
         "never_answered": len(issued) - len(acked),
@@ -197,9 +180,10 @@ def compare(
         "unrequested_executed": len(
             executed - system.requested - forged - system.forged_sent),
         "acks_short_of_quorum": quorum_shortfalls(
-            issued, [r.accepted for r in system.recorders], verifier, config["f"]
+            issued, [r.accepted for r in system.recorders], valid, config["f"]
         ),
-        "device_path_faults": device_path_faults(deltas, after),
+        "device_path_faults": device_path_faults(
+            deltas, after, ((m.QUEUE, m.KIND) for m in system.kernels.values())),
         "view_changes": sum(int(r.metrics.current_view) for r in system.cluster.replicas),
     }
 

@@ -11,7 +11,8 @@ import hashlib
 from benchmark.kernels import p256_textbook as tb
 
 TRACE_NAME = "jit__kg_comb_widen"
-BATCHES = "sign_batches"
+QUEUE = "ecdsa_p256"  # the key of the engine's ``sign_stats``
+KIND = "sign"
 CALIBRATION_RUNS = 2
 
 FIELD_MULS = tb.BITS * tb.DOUBLE + (tb.BITS // 2) * tb.MIXED_ADD

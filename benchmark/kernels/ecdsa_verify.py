@@ -6,14 +6,17 @@ Textbook (FIPS 186, double-and-add with Shamir's trick): w = s^-1 mod n
 affine (one inversion, 2 multiplications), compare with r.
 """
 
+import contextlib
 import hashlib
 
 from benchmark.kernels import p256_textbook as tb
 
 # How the program's jit names the kernel in a profiler trace (XLA Modules).
 TRACE_NAME = "jit__verify_one_packed"
-# Which engine counter counts its dispatches (benchmark.compare.engine_counts).
-BATCHES = "verify_batches"
+# The engine queue whose batches are its dispatches: the key of the engine's
+# public ``stats`` (``sign_stats`` for a sign kernel), and which side of it.
+QUEUE = "ecdsa_p256"
+KIND = "verify"
 # Dispatches of it in one profiler session: one fills the device's trace buffer.
 CALIBRATION_RUNS = 1
 
@@ -43,3 +46,20 @@ async def dispatch_once(engine, salt: bytes) -> None:
     digest = hashlib.sha256(salt).digest()
     if not await engine.verify_ecdsa_p256(q, digest, hostcrypto.ecdsa_sign(d, digest)):
         raise RuntimeError("calibration: the device rejected a valid signature")
+
+
+@contextlib.contextmanager
+def skip():
+    """The control ``verify_skipped``: while entered, this kernel answers
+    "valid" in every lane (patched at the module-level entry the engine's
+    dispatcher looks up on every call)."""
+    import numpy as np
+
+    from minbft_tpu.ops import p256
+
+    kernel = p256.ecdsa_verify_kernel_packed
+    p256.ecdsa_verify_kernel_packed = lambda packed: np.ones(packed.shape[0], bool)
+    try:
+        yield
+    finally:
+        p256.ecdsa_verify_kernel_packed = kernel

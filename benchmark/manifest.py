@@ -10,6 +10,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import re
 from typing import Callable, Dict, List, Optional
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -31,6 +32,16 @@ def read_json(path: str) -> dict:
 
 def load_manifest(root: str = ROOT) -> dict:
     return read_json(os.path.join(root, "BENCHMARK.json"))
+
+
+def by_name(root: str, kind: str, name: str, what: str):
+    """The module of ``<root>/benchmark/<kind>/<name>.py``: one file for
+    each reader, kernel and reference verifier, found by its name (which
+    may hold dots and dashes, so it is loaded by path, not imported)."""
+    path = os.path.join(root, "benchmark", kind, name + ".py")
+    if not os.path.isfile(path):
+        raise BenchmarkError(f"no {what} {name!r}: add the file {path}")
+    return load_module(path)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,33 +69,21 @@ class Cell:
     traffic: dict  # benchmark/traffic/<traffic>.json
     end_to_end: List[dict]  # the manifest's entries that this cell reports
     per_layer: List[LayerMetric]
+    root: str = ROOT  # the checkout whose files the cell was loaded from
 
 
 def _in_cell(entry: dict, cell: str) -> bool:
     return "workloads" not in entry or cell in entry["workloads"]
 
 
-def load_reader(name: str) -> Callable[[object], Optional[float]]:
-    """``read`` of benchmark/layer_metrics/<name>.py (a metric's name may
-    hold dots, so the file is loaded by path, not imported by name)."""
-    module = load_module(os.path.join(HERE, "layer_metrics", name + ".py"))
-    return module.read
-
-
 def load_module(path: str):
     if not os.path.isfile(path):
         raise BenchmarkError(f"no such file: {path}")
-    tag = os.path.splitext(os.path.basename(path))[0].replace(".", "_")
+    tag = re.sub(r"\W", "_", os.path.splitext(os.path.basename(path))[0])
     spec = importlib.util.spec_from_file_location(f"benchmark._byname_{tag}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
-
-
-def load_kernel(name: str):
-    """benchmark/kernels/<name>.py: the kernel's jit name in a trace and the
-    textbook work and bytes of one dispatch."""
-    return load_module(os.path.join(HERE, "kernels", name + ".py"))
 
 
 def load_peaks(device_kind: str) -> dict:
@@ -97,8 +96,10 @@ def load_peaks(device_kind: str) -> dict:
     return peaks["devices"][device_kind]
 
 
-def load_cell(name: str, manifest: Optional[dict] = None) -> Cell:
-    manifest = manifest or load_manifest()
+def load_cell(name: str, manifest: Optional[dict] = None, root: str = ROOT) -> Cell:
+    """The cell ``name`` of ``manifest`` (default: ``root``'s BENCHMARK.json)
+    with its files, all found under ``root``."""
+    manifest = manifest or load_manifest(root)
     cells = {w["name"]: w for w in manifest["workloads"]}
     if name not in cells:
         raise BenchmarkError(f"no workload {name!r}; BENCHMARK.json has {sorted(cells)}")
@@ -106,23 +107,41 @@ def load_cell(name: str, manifest: Optional[dict] = None) -> Cell:
     configs = {c["name"]: c for c in manifest["configs"]}
     if w["config"] not in configs:
         raise BenchmarkError(f"workload {name}: no config {w['config']!r}")
-    config = read_json(os.path.join(ROOT, configs[w["config"]]["file"]))
-    traffic = read_json(os.path.join(HERE, "traffic", w["traffic"] + ".json"))
+    config = read_json(os.path.join(root, configs[w["config"]]["file"]))
+    traffic = read_json(os.path.join(root, "benchmark", "traffic", w["traffic"] + ".json"))
     e2e = [m for m in manifest["end_to_end"] if _in_cell(m, name)]
     reported = {m["name"] for m in e2e}
     per_layer = [
         LayerMetric(
-            m["name"], m["unit"], m["better"], m["source"], m["layer"],
-            m["moves"], load_reader(m["name"]),
+            m["name"], m["unit"], m["better"], m["source"], m["layer"], m["moves"],
+            by_name(root, "layer_metrics", m["name"], "reader of the per-layer metric").read,
         )
         for m in manifest["per_layer"]
         if _in_cell(m, name) and m["moves"] in reported
     ]
     return Cell(name, int(w["chips"]), w["config"], config, w["traffic"],
-                traffic, e2e, per_layer)
+                traffic, e2e, per_layer, root)
 
 
 def load_kernels(cell: Cell) -> Dict[str, object]:
     """The kernels this cell's configuration dispatches, by the config's
-    ``kernels`` list -> {name: module of benchmark/kernels/<name>.py}."""
-    return {k: load_kernel(k) for k in cell.config["kernels"]}
+    ``kernels`` list -> {name: module of benchmark/kernels/<name>.py}: the
+    engine queue it runs in (``QUEUE``, the key of ``engine.stats`` /
+    ``engine.sign_stats``) and on which side of it (``KIND``), its jit name
+    in a trace, the textbook work and bytes of one dispatch, one dispatch
+    through an engine, and for a verify kernel ``skip()``, the control."""
+    return {k: by_name(cell.root, "kernels", k, "kernel") for k in cell.config["kernels"]}
+
+
+def device_queues(kernels: Dict[str, object]) -> List[str]:
+    """A configuration's device queues: its kernels' distinct ``QUEUE``s,
+    in the order of its ``kernels`` list."""
+    return list(dict.fromkeys(module.QUEUE for module in kernels.values()))
+
+
+def load_verifier(cell: Cell):
+    """benchmark/verifiers/<scheme>.py, the plain reference for the scheme the
+    configuration's replicas sign replies with: ``make(replica_pubs)`` ->
+    ``valid(replica_id, msg, signature) -> bool``."""
+    return by_name(cell.root, "verifiers", cell.config["scheme"],
+                   "reference verifier for the scheme")

@@ -1,5 +1,5 @@
 """What a window leaves for the metrics to read, and the arithmetic that
-turns it into numbers: percentiles, counter deltas, collector pauses.
+turns it into numbers: percentiles, counter deltas.
 
 The per-layer readers (``benchmark/layer_metrics/<name>.py``) get one
 :class:`Observations` and nothing else.
@@ -8,9 +8,7 @@ The per-layer readers (``benchmark/layer_metrics/<name>.py``) get one
 from __future__ import annotations
 
 import dataclasses
-import gc
 import math
-import time
 from typing import Dict, List, Optional, Sequence
 
 
@@ -42,36 +40,6 @@ def log2_bucket_percentile(buckets: Sequence[int], q: float) -> Optional[float]:
     return None
 
 
-class GcTimer:
-    """Times the collector's passes through ``gc.callbacks`` (traced runs
-    only): (generation, start, seconds) of each pass."""
-
-    def __init__(self):
-        self.passes: List[tuple] = []
-        self._start = 0.0
-
-    def _callback(self, phase: str, info: dict) -> None:
-        if phase == "start":
-            self._start = time.perf_counter()
-        else:
-            now = time.perf_counter()
-            self.passes.append((info["generation"], self._start, now - self._start))
-
-    def __enter__(self) -> "GcTimer":
-        gc.callbacks.append(self._callback)
-        return self
-
-    def __exit__(self, *exc) -> None:
-        gc.callbacks.remove(self._callback)
-
-    def pause_s(self, generation: int, opened: float, closed: float) -> float:
-        return sum(
-            min(start + dur, closed) - max(start, opened)
-            for gen, start, dur in self.passes
-            if gen == generation and start < closed and start + dur > opened
-        )
-
-
 @dataclasses.dataclass
 class Observations:
     """One window, as the per-layer readers see it."""
@@ -80,7 +48,6 @@ class Observations:
     latencies_ms: List[float]  # sorted; every write issued in the window that was answered
     commits: int  # writes acknowledged inside the window
     engine_deltas: List[dict]  # compare.counts_delta per engine, over the window
-    gc_pause_s: Dict[int, float]  # generation -> seconds of passes inside the window
     device_kind: str
     platform: str
     kernels: Dict[str, object]  # name -> module of benchmark/kernels/<name>.py

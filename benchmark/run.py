@@ -20,7 +20,6 @@ _T0 = time.perf_counter()  # set-up counts from here: before JAX is imported
 
 import argparse  # noqa: E402
 import asyncio  # noqa: E402
-import contextlib  # noqa: E402
 import json  # noqa: E402
 import logging  # noqa: E402
 import os  # noqa: E402
@@ -72,16 +71,16 @@ def memory_peak_bytes() -> int:
     return int(max(peaks))
 
 
-async def one_window(system, mix: Mix, seed: int, seconds: float, trace: bool,
+async def one_window(system, mix: Mix, seed: int, seconds: float,
                      tag: bytes = b"w") -> dict:
     """Drive one window over a built system and compare what it produced.
     -> everything a result line, or a control's line, is made from."""
-    queue = system.config["device_queue"]
+    queues = system.queues
     window = Window(system, mix, seed, seconds, tag)
     loop = asyncio.get_running_loop()
 
     def counts() -> list:
-        return [cmp.engine_counts(e, queue) for e in system.engines]
+        return [cmp.engine_counts(e, queues) for e in system.engines]
 
     def since(then: list, now: list) -> list:
         return [cmp.counts_delta(a, b) for a, b in zip(now, then)]
@@ -89,8 +88,7 @@ async def one_window(system, mix: Mix, seed: int, seconds: float, trace: bool,
     before = counts()
     at_close: list = []
     handle = loop.call_later(seconds, lambda: at_close.append(counts()))
-    with observe.GcTimer() if trace else contextlib.nullcontext() as gc_timer:
-        await window.run()
+    await window.run()
     handle.cancel()
     deltas = since(before, at_close[0] if at_close else counts())
     peak = memory_peak_bytes()
@@ -109,9 +107,6 @@ async def one_window(system, mix: Mix, seed: int, seconds: float, trace: bool,
         "commits": sum(r.acked <= window.closed for r in answered),
         "deltas": deltas,
         "memory_peak_bytes": peak,
-        "gc_pause_s": {
-            g: gc_timer.pause_s(g, window.opened, window.closed) for g in (0, 1, 2)
-        } if trace else {},
     }
 
 
@@ -156,7 +151,7 @@ async def measure(cell, device: dict, seed: int, seconds: float, trace: bool) ->
     from . import system as sut
 
     config, mix = sized(cell, device)
-    system = await sut.build(config, mix.clients, on_cpu=device["rehearsal"])
+    system = await sut.build(cell, config, mix.clients, on_cpu=device["rehearsal"])
     try:
         return await measured(cell, device, system, mix, seed, seconds, trace)
     finally:
@@ -167,10 +162,9 @@ async def measured(cell, device: dict, system, mix: Mix, seed: int, seconds: flo
                    trace: bool) -> dict:
     """The run from the end of set-up on: the window, the comparison, the
     traced sessions, the result."""
-    config = system.config
-    kernels = manifest.load_kernels(cell)
+    config, kernels = system.config, system.kernels
     setup_s = time.perf_counter() - _T0
-    got = await one_window(system, mix, seed, seconds, trace)
+    got = await one_window(system, mix, seed, seconds)
     result = {
         "correct": cmp.verdict(got["numbers"]),
         "attempted": len(got["window"].issued),
@@ -184,20 +178,21 @@ async def measured(cell, device: dict, system, mix: Mix, seed: int, seconds: flo
     if not trace:
         result["metrics"] = end_to_end(cell, got, seconds, setup_s)
     else:
-        await tracing.quiet(system, config["device_queue"])
+        await tracing.quiet(system)
         cal = await tracing.calibrate(
             tracing.HostClockProfiler() if device["rehearsal"] else tracing.Profiler(),
             tracing.Dispatcher(system.engines[0], kernels), kernels,
             deadline=tracing.deadline(_T0, setup_s),
         )
-        dispatches = {
-            k: sum(d[m.BATCHES] for d in got["deltas"]) for k, m in kernels.items()
+        dispatches = {  # each kernel's from its own queue's counter
+            k: sum(d["batches"][m.QUEUE, m.KIND] for d in got["deltas"])
+            for k, m in kernels.items()
         }
         busy = {k: dispatches[k] * cal["kernel_time_s"][k] for k in kernels}
         obs = observe.Observations(
             window_s=seconds, latencies_ms=got["latencies_ms"],
             commits=got["commits"], engine_deltas=got["deltas"],
-            gc_pause_s=got["gc_pause_s"], device_kind=device["kind"],
+            device_kind=device["kind"],
             platform=device["platform"], kernels=kernels,
             kernel_time_s=cal["kernel_time_s"], kernel_dispatches=dispatches,
             lanes=config["engine"]["buckets"][0], busy_s=sum(busy.values()),

@@ -75,22 +75,31 @@ def dispatch_rows(tl: dict, opened: int) -> Optional[List[dict]]:
     return out
 
 
-def kernel_ns_by_kind(obs) -> Dict[str, int]:
-    """{"verify" | "sign": the calibrated kernel time of one dispatch, ns}:
-    a kernel's file names the engine counter of its dispatches
-    (``verify_batches``, ``sign_batches``), and a row names its kind."""
+def kernel_ns_by_side(obs) -> Dict[Tuple[str, str], int]:
+    """{(queue, kind): the calibrated kernel time of one dispatch, ns}: a
+    kernel's file names the engine queue it runs in (``QUEUE``, the key of
+    ``engine.stats`` / ``engine.sign_stats``) and on which side (``KIND``)."""
     return {
-        module.BATCHES.split("_")[0]: round(obs.kernel_time_s[name] * 1e9)
+        (module.QUEUE, module.KIND): round(obs.kernel_time_s[name] * 1e9)
         for name, module in obs.kernels.items()
         if name in obs.kernel_time_s
     }
 
 
-def device_intervals(rows: Iterable[dict], kernel_ns: Dict[str, int],
+def side(row: dict) -> Tuple[str, str]:
+    """A dispatch row's (queue, kind) as the kernels' files name them.  The
+    ring names a queue by its label, which for a sign queue is ``sign_`` +
+    the key of ``engine.sign_stats`` (``parallel/engine.py``,
+    ``LABEL_PREFIX``); this is the one place that knows."""
+    queue, kind = row["queue"], row["kind"]
+    return (queue.removeprefix("sign_") if kind == "sign" else queue), kind
+
+
+def device_intervals(rows: Iterable[dict], kernel_ns: Dict[Tuple[str, str], int],
                      order: str = "result") -> List[Tuple[dict, int, int]]:
-    """The model: one chip that runs one kernel at a time, each for its
-    calibrated time, as early as it can.  A kernel cannot start before its
-    launch began (``t_prep_end``: in an annotated session the device event
+    """The model: one chip that runs one kernel at a time, each for the
+    calibrated time of its own (queue, kind), as early as it can.  A kernel
+    cannot start before its launch began (``t_prep_end``: in an annotated session the device event
     starts with the ``launch`` phase, about a millisecond before the
     jitted call returns, and under load ``t_launch_end`` is stamped later
     still, once the worker has the interpreter lock again).  Whenever the
@@ -103,7 +112,7 @@ def device_intervals(rows: Iterable[dict], kernel_ns: Dict[str, int],
     the order (a server that never idles with work waiting is busy at the
     same instants whatever it picks), so the idle classes do not either;
     which dispatch waits how long does, and the readers use ``"result"``.
-    Device dispatches only (no flag set).
+    Device dispatches only (no flag set), of the sides that have a kernel.
     -> [(row, start, end)] in device order.  The residual ``t_result -
     end`` is the launch call's return plus the result's way back
     (:func:`residuals` reports it, unclipped).  Result order minimises the
@@ -112,7 +121,7 @@ def device_intervals(rows: Iterable[dict], kernel_ns: Dict[str, int],
     ``"launch"``, :func:`overdrawn` and :func:`latest_intervals`."""
     key = {"result": "t_result", "launch": "t_prep_end"}[order]
     device = sorted(
-        (r for r in rows if not r["flags"] and r["kind"] in kernel_ns),
+        (r for r in rows if not r["flags"] and side(r) in kernel_ns),
         key=lambda r: r["t_prep_end"],
     )
     out, launched, free_at, i = [], [], 0, 0
@@ -123,7 +132,7 @@ def device_intervals(rows: Iterable[dict], kernel_ns: Dict[str, int],
             heapq.heappush(launched, (device[i][key], i))
             i += 1
         row = device[heapq.heappop(launched)[1]]
-        out.append((row, free_at, free_at + kernel_ns[row["kind"]]))
+        out.append((row, free_at, free_at + kernel_ns[side(row)]))
         free_at = out[-1][2]
     return out
 
@@ -281,7 +290,7 @@ def analyse(obs, tl: Optional[dict]) -> Optional[Analysis]:
     out = Analysis(opened, closed)
 
     rows = dispatch_rows(tl, opened)
-    kernel_ns = kernel_ns_by_kind(obs)
+    kernel_ns = kernel_ns_by_side(obs)
     intervals = None
     if rows is not None and kernel_ns:
         intervals = device_intervals(rows, kernel_ns)

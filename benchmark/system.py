@@ -26,7 +26,7 @@ import dataclasses
 import time
 from typing import Dict, List, Optional
 
-from .manifest import BenchmarkError
+from .manifest import BenchmarkError, device_queues, load_kernels, load_verifier
 
 
 class Recorder:
@@ -169,6 +169,8 @@ class Forger:
 @dataclasses.dataclass
 class System:
     config: dict
+    kernels: Dict[str, object]  # the configuration's benchmark/kernels/ files, in its order
+    verifier: object  # its scheme's benchmark/verifiers/ file (the comparison's reference)
     cluster: object  # placement.LocalCluster
     store: object  # the KeyStore (public keys for the comparison)
     n_clients: int
@@ -185,6 +187,11 @@ class System:
     @property
     def engines(self):
         return self.cluster.engines
+
+    @property
+    def queues(self) -> List[str]:
+        """The device queues: the kernels' distinct ``QUEUE``s, in their order."""
+        return device_queues(self.kernels)
 
     async def stop(self) -> None:
         await self.forger.stop()
@@ -232,15 +239,17 @@ async def attach_clients(system: System, client_f: Optional[int] = None,
         system.taps.append(tap)
 
 
-async def build(config: dict, n_clients: int, on_cpu: bool = False) -> System:
-    """Start the configuration's cluster and ``n_clients`` clients, and
-    commit one write (first-contact USIG epochs) before any window."""
+async def build(cell, config: dict, n_clients: int, on_cpu: bool = False) -> System:
+    """Start the cluster of ``config`` (the cell's configuration as this
+    run sizes it) and ``n_clients`` clients, and commit one write
+    (first-contact USIG epochs) before any window."""
     from minbft_tpu.sample.authentication import generate_testnet_keys
     from minbft_tpu.sample.config import SimpleConfiger
     from minbft_tpu.sample.conn.inprocess import InProcessClientConnector
     from minbft_tpu.sample.peer.placement import start_local_cluster
 
     _check_supported(config)
+    kernels, verifier = load_kernels(cell), load_verifier(cell)
     asyncio.get_running_loop().set_task_factory(asyncio.eager_task_factory)
     n, f = config["n"], config["f"]
     store = generate_testnet_keys(
@@ -259,7 +268,8 @@ async def build(config: dict, n_clients: int, on_cpu: bool = False) -> System:
         await cluster.stop()
         raise BenchmarkError(f"a replica chose host crypto: {cluster.placement}")
     forger = Forger(InProcessClientConnector(cluster.stubs), n, n_clients)
-    system = System(config, cluster, store, n_clients, [], [], [], forger, warm_s)
+    system = System(config, kernels, verifier, cluster, store, n_clients, [], [], [],
+                    forger, warm_s)
     try:
         await attach_clients(system)
         await forger.start()

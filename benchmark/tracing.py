@@ -36,6 +36,7 @@ import tempfile
 import time
 from typing import Dict, List, Optional
 
+from .compare import engine_counts
 from .manifest import BenchmarkError
 
 SESSIONS = 5  # unusable sessions in a row that end the run
@@ -221,14 +222,13 @@ class Dispatcher:
         await self._kernels[kernel].dispatch_once(self._engine, salt)
 
 
-async def quiet(system, queue: str, seconds: float = QUIET_S, timeout: float = 30.0) -> None:
-    """Until no engine's batch counters have moved for ``seconds``."""
+async def quiet(system, seconds: float = QUIET_S, timeout: float = 30.0) -> None:
+    """Until no engine's batch counters, in any of the configuration's
+    device queues, have moved for ``seconds``."""
+    queues = system.queues
+
     def batches():
-        return [
-            (e.stats[queue].batches if queue in e.stats else 0,
-             e.sign_stats[queue].batches if queue in e.sign_stats else 0)
-            for e in system.engines
-        ]
+        return [engine_counts(e, queues)["batches"] for e in system.engines]
 
     deadline = time.monotonic() + timeout
     last, since = batches(), time.monotonic()

@@ -61,8 +61,22 @@ def test_cell_loads_with_its_config_traffic_and_readers(name):
     assert (mix.loop, mix.clients, mix.depth) == ("closed", 16, 8)
     assert {m["name"] for m in cell.end_to_end} == {
         "goodput_rps", "finality_mean_ms", "finality_p95_ms", "setup_s"}
-    assert len(cell.per_layer) == 9 and all(callable(m.read) for m in cell.per_layer)
-    assert set(manifest.load_kernels(cell)) == {"ecdsa_verify", "ecdsa_sign"}
+    # its per-layer metrics are the manifest's: every entry that admits the
+    # cell and moves a metric the cell reports, each with its reader's file
+    reported = {m["name"] for m in cell.end_to_end}
+    entries = {m["name"]: m for m in MANIFEST["per_layer"]
+               if name in m.get("workloads", [name]) and m["moves"] in reported}
+    assert entries and [m.name for m in cell.per_layer] == list(entries)
+    for m in cell.per_layer:
+        declared = manifest.by_name(REPO, "layer_metrics", m.name, "reader").DECLARATION
+        assert declared == {k: entries[m.name][k] for k in ("unit", "better", "source", "layer", "moves")}
+        assert callable(m.read)
+    kernels = manifest.load_kernels(cell)
+    assert list(kernels) == cell.config["kernels"]
+    assert manifest.device_queues(kernels) == list(dict.fromkeys(k.QUEUE for k in kernels.values()))
+    assert all(k.KIND in ("verify", "sign") and (k.KIND == "sign" or callable(k.skip))
+               for k in kernels.values())
+    assert callable(manifest.load_verifier(cell).make)
 
 
 @pytest.mark.parametrize("entry", MANIFEST["per_layer"], ids=lambda m: m["name"])
@@ -84,7 +98,7 @@ def test_every_file_under_paths_has_a_contract_name():
                 assert re.match(r"^[A-Za-z0-9_.\-/]+$", rel), rel
 
 
-def test_a_later_pr_adds_cell_config_mix_and_metric_as_files_alone(tmp_path, monkeypatch):
+def test_a_later_pr_adds_cell_config_mix_and_metric_as_files_alone(tmp_path):
     here = tmp_path / "benchmark"
     shutil.copytree(manifest.HERE, here, ignore=shutil.ignore_patterns("__pycache__"))
     config = json.loads((here / "configs" / "n3f1-ecdsa.json").read_text())
@@ -104,14 +118,12 @@ def test_a_later_pr_adds_cell_config_mix_and_metric_as_files_alone(tmp_path, mon
     later["per_layer"].append({"name": "client.finality_p99_ms", "unit": "ms", "better": "lower",
                                "source": "host_clock", "layer": "client", "moves": "finality_p95_ms",
                                "workloads": ["n4f1-ecdsa.open-400"]})
-    monkeypatch.setattr(manifest, "ROOT", str(tmp_path))
-    monkeypatch.setattr(manifest, "HERE", str(here))
-    cell = manifest.load_cell("n4f1-ecdsa.open-400", later)
+    cell = manifest.load_cell("n4f1-ecdsa.open-400", later, root=str(tmp_path))
     assert cell.config["n"] == 4 and Mix.from_file(cell.traffic).loop == "open"
     assert [m.name for m in cell.per_layer] == ["client.finality_p99_ms"]
-    obs = observe.Observations(30.0, [1.0, 2.0, 3.0], 3, [], {}, "TPU v5 lite", "tpu", {}, {}, {}, 512, None)
+    obs = observe.Observations(30.0, [1.0, 2.0, 3.0], 3, [], "TPU v5 lite", "tpu", {}, {}, {}, 512, None)
     assert cell.per_layer[0].read(obs) == pytest.approx(2.98)
-    old = manifest.load_cell(CELLS[0], later)
+    old = manifest.load_cell(CELLS[0], later, root=str(tmp_path))
     assert "client.finality_p99_ms" not in [m.name for m in old.per_layer]
 
 
@@ -135,16 +147,13 @@ def test_payloads_come_from_the_seed_and_never_repeat():
     assert a.forged() == b.forged() and len(a.forged()) == mix.payload_bytes
 
 
-def test_percentiles_and_collector_pauses():
+def test_percentiles_exact_and_from_log2_buckets():
     assert observe.percentile([1, 2, 3, 4, 5], 50) == 3
     assert observe.percentile(list(range(101)), 95) == 95
     buckets = [0] * 64
     buckets[14] = 10  # 8.192 ms < d <= 16.384 ms
     assert 0.008192 < observe.log2_bucket_percentile(buckets, 50) < 0.016384
     assert observe.log2_bucket_percentile([0] * 64, 50) is None
-    timer = observe.GcTimer()
-    timer.passes = [(2, 9.8, 0.4), (2, 12.0, 0.5), (1, 12.0, 0.5), (2, 19.9, 0.4)]
-    assert timer.pause_s(2, 10.0, 20.0) == pytest.approx(0.2 + 0.5 + 0.1)
 
 
 def test_roofline_counts_textbook_work_and_stays_far_under_the_peak():
@@ -155,7 +164,7 @@ def test_roofline_counts_textbook_work_and_stays_far_under_the_peak():
     assert verify["ops"] == 512 * 4930 * 4096 and sign["ops"] == 512 * 3456 * 4096
     least, binds = roofline.least_time_s(verify, peaks)
     assert binds == "compute" and least == pytest.approx(26.3e-6, rel=0.01)
-    obs = observe.Observations(30.0, [], 0, [], {}, "TPU v5 lite", "tpu", kernels,
+    obs = observe.Observations(30.0, [], 0, [], "TPU v5 lite", "tpu", kernels,
                                {"ecdsa_verify": 9.5e-3}, {}, 512, None)
     assert 0.2 < roofline.share_percent(obs, "ecdsa_verify") < 0.4
     assert roofline.share_percent(obs, "ecdsa_sign") is None  # nothing traced: no 0

@@ -30,27 +30,30 @@ COLUMNS = [
     "t_result", "t_finish_end", "t_resolved",
 ]
 KERNELS = {
-    "ecdsa_verify": types.SimpleNamespace(BATCHES="verify_batches"),
-    "ecdsa_sign": types.SimpleNamespace(BATCHES="sign_batches"),
+    "ecdsa_verify": types.SimpleNamespace(QUEUE="ecdsa_p256", KIND="verify"),
+    "ecdsa_sign": types.SimpleNamespace(QUEUE="ecdsa_p256", KIND="sign"),
 }
+VERIFY, SIGN = ("ecdsa_p256", "verify"), ("ecdsa_p256", "sign")
 T0 = 1_000 * SLOT  # the window opens on a slot boundary, 10 s into the clock
 
 
 def observations(window_s=0.1, verify_ms=10.0, sign_ms=1.0):
     return observe.Observations(
-        window_s=window_s, latencies_ms=[], commits=0, engine_deltas=[], gc_pause_s={},
+        window_s=window_s, latencies_ms=[], commits=0, engine_deltas=[],
         device_kind="cpu", platform="cpu", kernels=KERNELS,
         kernel_time_s={"ecdsa_verify": verify_ms / 1e3, "ecdsa_sign": sign_ms / 1e3},
         kernel_dispatches={}, lanes=8, busy_s=None,
     )
 
 
-def dispatch(k, enq, flush, prep_end, result, kind="verify", flags=0, resolved=None, engine=0):
-    """A row with its instants in ms after T0 (the launch call takes 0.5 ms)."""
+def dispatch(k, enq, flush, prep_end, result, kind="verify", flags=0, resolved=None, engine=0,
+             queue="ecdsa_p256"):
+    """A row with its instants in ms after T0 (the launch call takes 0.5 ms);
+    ``queue`` as the engine's stats name it (the ring puts ``sign_`` before a
+    sign queue's name)."""
     t = [T0 + round(x * MS) for x in (enq, flush, flush, prep_end, prep_end + 0.5, result, result,
                                       result if resolved is None else resolved)]
-    queue = "ecdsa_p256" if kind == "verify" else "sign_ecdsa_p256"
-    return (k, engine, queue, kind, 3, 8, "idle", flags, *t)
+    return (k, engine, queue if kind == "verify" else "sign_" + queue, kind, 3, 8, "idle", flags, *t)
 
 
 def timeline(rows=(), gc=(), idle=(), jax=(), dropped=None, loop_from=0):
@@ -142,8 +145,8 @@ def test_one_chip_runs_one_kernel_at_a_time_from_its_launchs_start():
         dispatch(4, 0, 0, 0.5, 60, flags=1),  # a fallback: no kernel of it
     ]
     a = spans.analyse(observations(), timeline(rows=rows))
-    kernel_ns = spans.kernel_ns_by_kind(observations())
-    assert kernel_ns == {"verify": 10 * MS, "sign": MS}
+    kernel_ns = spans.kernel_ns_by_side(observations())
+    assert kernel_ns == {VERIFY: 10 * MS, SIGN: MS}
     table = spans.device_intervals(spans.dispatch_rows(timeline(rows=rows), T0), kernel_ns)
     assert [(r["dispatch_id"], (s - T0) / MS, (e - T0) / MS) for r, s, e in table] == [
         (1, 1, 11), (2, 11, 21), (3, 21, 22)]
@@ -156,6 +159,34 @@ def test_one_chip_runs_one_kernel_at_a_time_from_its_launchs_start():
     assert spans.p50_ms(obs, "result_return_ns") == 1
 
 
+def test_two_verify_kernels_of_one_configuration_are_each_placed_at_their_own_length():
+    """ECDSA messages over HMAC certificates: two verify queues feed the one
+    chip, and a row is its own queue's kernel, not its kind's."""
+    obs = observations()
+    obs.kernels = {**KERNELS, "hmac_verify": types.SimpleNamespace(QUEUE="hmac_sha256", KIND="verify")}
+    obs.kernel_time_s = {"ecdsa_verify": 0.010, "ecdsa_sign": 0.001, "hmac_verify": 0.002}
+    kernel_ns = spans.kernel_ns_by_side(obs)
+    assert kernel_ns == {VERIFY: 10 * MS, SIGN: MS, ("hmac_sha256", "verify"): 2 * MS}
+    rows = [
+        dispatch(1, 0, 0, 1, 12),
+        dispatch(2, 0, 0, 2, 14, queue="hmac_sha256", engine=1),
+        dispatch(3, 0, 0, 3, 15, kind="sign", engine=2),
+        dispatch(4, 0, 0, 40, 43, queue="hmac_sha256"),
+        dispatch(5, 0, 0, 50, 52, kind="sign", queue="ed25519"),  # a side with no kernel's file
+    ]
+    tl = timeline(rows=rows)
+    table = spans.device_intervals(spans.dispatch_rows(tl, T0), kernel_ns)
+    assert [(r["dispatch_id"], (s - T0) / MS, (e - T0) / MS) for r, s, e in table] == [
+        (1, 1, 11), (2, 11, 13), (3, 13, 14), (4, 40, 42)]
+    a = spans.analyse(obs, tl)
+    assert a.classes["busy"] == 15 * MS
+    assert [x / MS for x in a.device_queue_wait_ns] == [0, 9, 10, 0]
+    assert [x / MS for x in a.result_return_ns] == [1, 1, 1, 1]
+    assert a.overdrawn == 0
+    late = spans.latest_intervals(table)
+    assert [(r["dispatch_id"], (e - s) / MS) for r, s, e in late] == [(1, 10), (2, 2), (3, 1), (4, 2)]
+
+
 def test_of_the_launched_dispatches_the_one_whose_result_came_back_first_ran_first():
     """Launch stamps wait for the interpreter lock and can swap; results
     come back in the device's own order."""
@@ -166,13 +197,13 @@ def test_of_the_launched_dispatches_the_one_whose_result_came_back_first_ran_fir
     ]
     a = spans.analyse(observations(), timeline(rows=rows))
     table = spans.device_intervals(spans.dispatch_rows(timeline(rows=rows), T0),
-                                   spans.kernel_ns_by_kind(observations()))
+                                   spans.kernel_ns_by_side(observations()))
     assert [(r["dispatch_id"], (s - T0) / MS, (e - T0) / MS) for r, s, e in table] == [
         (1, 0, 10), (3, 10, 11), (2, 11, 21)]
     assert a.negative_residual_share == 0
     # plain FIFO on the launch's start reads -8.8 ms for the sign ...
     fifo = spans.device_intervals(spans.dispatch_rows(timeline(rows=rows), T0),
-                                  spans.kernel_ns_by_kind(observations()), order="launch")
+                                  spans.kernel_ns_by_side(observations()), order="launch")
     assert [(r["dispatch_id"], (s - T0) / MS, (e - T0) / MS) for r, s, e in fifo] == [
         (1, 0, 10), (2, 10, 20), (3, 20, 21)]
     assert [x / MS for x in spans.residuals(fifo)] == [1, 11.5, pytest.approx(-8.8)]
@@ -186,7 +217,7 @@ def test_of_the_launched_dispatches_the_one_whose_result_came_back_first_ran_fir
 def test_a_result_back_before_any_order_could_have_run_it_is_overdrawn():
     """The order-free check: the work whose results are back by an instant
     against the time the modelled device has been busy by then."""
-    kernel_ns = spans.kernel_ns_by_kind(observations())
+    kernel_ns = spans.kernel_ns_by_side(observations())
 
     def excess_ms(rows):
         table = spans.device_intervals(spans.dispatch_rows(timeline(rows=rows), T0), kernel_ns)
@@ -206,7 +237,7 @@ def test_a_result_back_before_any_order_could_have_run_it_is_overdrawn():
 
 
 def test_latest_placement_ends_each_kernel_at_its_result_or_the_next_ones_start():
-    kernel_ns = spans.kernel_ns_by_kind(observations())
+    kernel_ns = spans.kernel_ns_by_side(observations())
     rows = [dispatch(1, 0, 0, 1, 14), dispatch(2, 0, 0, 2, 22), dispatch(3, 0, 0, 30, 45, kind="sign")]
     table = spans.device_intervals(spans.dispatch_rows(timeline(rows=rows), T0), kernel_ns)
     late = spans.latest_intervals(table)
@@ -238,7 +269,7 @@ def test_the_five_shares_sum_to_one_minus_busy_over_window(seed):
     assert abs(shares - (1 - a.classes["busy"] / a.window_ns)) < 1e-9
     assert all(v >= 0 for v in a.classes.values())
     # when the device is busy does not hang on the order it is given
-    kernel_ns = spans.kernel_ns_by_kind(observations(verify_ms=7.3, sign_ms=0.9))
+    kernel_ns = spans.kernel_ns_by_side(observations(verify_ms=7.3, sign_ms=0.9))
     dev = spans.dispatch_rows(timeline(rows=rows), T0)
     by_result, by_launch = (spans.device_intervals(dev, kernel_ns, order=o) for o in ("result", "launch"))
     inside = [spans.union_ns(spans._clipped(((s, e) for _r, s, e in t), a.opened, a.closed))
@@ -354,7 +385,7 @@ def test_recorded_chip_timeline_passes_the_checks_that_do_not_lean_on_the_order(
     assert abs(by_launch - by_result) < 0.05 * by_result
 
     rows = spans.dispatch_rows(tl, a.opened)
-    kernel_ns = spans.kernel_ns_by_kind(obs)
+    kernel_ns = spans.kernel_ns_by_side(obs)
     loop = next(lp for lp in tl["loops"] if lp.get("current"))
 
     def classes(table):
@@ -393,9 +424,9 @@ def live():
     async def everything():
         config, mix = run.sized(cell, CPU)
         mark = len(obs_trace.timeline()["client"]["rows"])
-        system = await sut.build(config, mix.clients, on_cpu=True)
+        system = await sut.build(cell, config, mix.clients, on_cpu=True)
         try:
-            got = await run.one_window(system, mix, 2**31 + 26, 1.0, True)
+            got = await run.one_window(system, mix, 2**31 + 26, 1.0)
             tl = spans.timeline()
         finally:
             await system.stop()

@@ -14,7 +14,7 @@ sys.path.insert(0, REPO)
 
 from benchmark import manifest, tracing  # noqa: E402
 
-KERNELS = {k: manifest.load_kernel(k) for k in ("ecdsa_verify", "ecdsa_sign")}
+KERNELS = manifest.load_kernels(manifest.load_cell("n3f1-ecdsa.closed-16x8"))
 
 
 def recorded(name: str) -> dict:
