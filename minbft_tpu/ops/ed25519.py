@@ -383,7 +383,9 @@ def prepare_packed(
     return out
 
 
-def _verify_one_packed(row: jnp.ndarray) -> jnp.ndarray:
+# Named apart from ops/p256.py's ``_verify_one_packed``: the jit's name is
+# what a profiler trace shows, and a deployment may run both kernels.
+def _ed25519_verify_one_packed(row: jnp.ndarray) -> jnp.ndarray:
     r32 = row.astype(jnp.uint32)
     L_ = limbs.NLIMBS
     return _verify_one(
@@ -397,7 +399,7 @@ def _verify_one_packed(row: jnp.ndarray) -> jnp.ndarray:
     )
 
 
-ed25519_verify_kernel_packed = per_mode_jit(jax.vmap(_verify_one_packed))
+ed25519_verify_kernel_packed = per_mode_jit(jax.vmap(_ed25519_verify_one_packed))
 
 
 def verify_batch_padded(
@@ -493,12 +495,12 @@ def rb_comb_kernel():
     if _rb_comb_batch is None:
         table = jnp.asarray(_comb_table_np())
 
-        def widen(r16):
+        def _rb_comb_widen(r16):
             return jax.vmap(_rb_comb_one, in_axes=(0, None))(
                 r16.astype(jnp.uint32), table
             )
 
-        _rb_comb_batch = per_mode_jit(widen)
+        _rb_comb_batch = per_mode_jit(_rb_comb_widen)
     return _rb_comb_batch
 
 

@@ -128,7 +128,7 @@ def sharded_ed25519_kernel(mesh: Mesh):
     single-upload form (see :func:`minbft_tpu.ops.ed25519.pack_arrays`)."""
     from ..ops import ed25519 as ed
 
-    return sharded_verifier(ed._verify_one_packed, mesh, 1)
+    return sharded_verifier(ed._ed25519_verify_one_packed, mesh, 1)
 
 
 def sharded_ecdsa_sign_kernel(mesh: Mesh):

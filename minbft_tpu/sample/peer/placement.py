@@ -35,7 +35,9 @@ from typing import List, Optional, Tuple
 #   the heap's own growth, 7,000-10,000 objects a second, so a pass comes
 #   every 2-6 s, walks at most 50,000 objects and holds the loop 35-50 ms
 #   (about 1 %).  25,000 still trips on the blocks (2.6 % at n=7);
-#   100,000 holds the loop 77-89 ms a pass for the same rate.
+#   100,000 holds the loop 77-89 ms a pass for the same rate.  This is the
+#   floor; collector_thresholds() raises it with the replicas one process
+#   carries.
 # - a middle pass after 3 youngest ones, not 11: it walks what those
 #   promoted, 50-145 ms every 6-20 s (under 1 %); after 11 it would walk
 #   half a million objects at once.
@@ -45,6 +47,22 @@ from typing import List, Optional, Tuple
 #   oldest generation is new) holds full passes to 4 x (cost an object) x
 #   (growth a second), under 2 % at any heap size.
 COLLECTOR_THRESHOLDS = (50_000, 2, 10)
+
+# What one replica holds in flight, twice over (PERF.md section 6, PR 29).
+# 128 writes in flight are ~7,000 tracked objects a replica of the process:
+# 25,000 trips on the blocks at n=7 and 50,000 does not (PR 27); at n=31
+# 50,000 trips 1.9 times a second, middle and full passes follow, and the
+# collector holds the loop 11.5 % of a window; 200,000 still trips on some
+# blocks, 400,000 and 800,000 on none.  Twice what is in flight leaves the
+# first threshold to the heap's growth alone, as the floor does at n <= 3.
+YOUNGEST_PER_REPLICA = 14_000
+
+
+def collector_thresholds(replicas: int) -> Tuple[int, int, int]:
+    """:data:`COLLECTOR_THRESHOLDS` with the first raised to what
+    ``replicas`` replicas in this process hold in flight, twice over."""
+    youngest, middle, oldest = COLLECTOR_THRESHOLDS
+    return max(youngest, YOUNGEST_PER_REPLICA * replicas), middle, oldest
 
 
 def replica_engine(
@@ -142,13 +160,14 @@ async def warm_engine(engine, schemes=("ecdsa_p256",)) -> None:
         raise RuntimeError("engine warm-up: the device rejected a valid item")
 
 
-async def warm_engines(engines, schemes=("ecdsa_p256",)) -> None:
+async def warm_engines(engines, schemes=("ecdsa_p256",), replicas: int = 1) -> None:
     """:func:`warm_engine` over several engines.  An executable belongs
     to its device: engines that share one warm one after the other (the
     first compiles, the rest reuse its executable), engines on distinct
     devices side by side (each compiles its own — a four-chip pool warms
     in the time of one chip, not four).  Warm-up ends with
-    :func:`settle_collector`, with engines or with none."""
+    :func:`settle_collector` for the ``replicas`` this process carries,
+    with engines or with none."""
     import asyncio
 
     by_device: dict = {}
@@ -160,10 +179,10 @@ async def warm_engines(engines, schemes=("ecdsa_p256",)) -> None:
             await warm_engine(engine, schemes)
 
     await asyncio.gather(*[one_after_the_other(g) for g in by_device.values()])
-    settle_collector()
+    settle_collector(replicas)
 
 
-def settle_collector() -> None:
+def settle_collector(replicas: int = 1) -> None:
     """The collector's policy of a replica about to serve, set where
     warm-up ends (:func:`warm_engines`' last act, so ``peer run``, the
     in-process clusters and the pool phase all get it from one place,
@@ -178,13 +197,15 @@ def settle_collector() -> None:
     garbage out of there, and the one after it (over an empty heap, under
     1 ms) resets the interpreter's count of long-lived objects, which the
     quarter rule would otherwise still take from the frozen heap.  Then
-    :data:`COLLECTOR_THRESHOLDS`.  The collector stays on and keeps its own
-    accounting; nothing here reads a configuration.  A second call only
-    freezes what has been allocated since."""
+    :func:`collector_thresholds` of the ``replicas`` this process carries
+    (one in ``peer run``, all n in an in-process cluster).  The collector
+    stays on and keeps its own accounting; nothing here reads a
+    configuration.  A second call only freezes what has been allocated
+    since."""
     gc.collect()
     gc.freeze()
     gc.collect()
-    gc.set_threshold(*COLLECTOR_THRESHOLDS)
+    gc.set_threshold(*collector_thresholds(replicas))
 
 
 @dataclasses.dataclass
@@ -243,7 +264,7 @@ async def start_local_cluster(
         engines.append(engine)
         replicas.append(r)
     await warm_engines(
-        [e for e in engines if e is not None], device_schemes(store)
+        [e for e in engines if e is not None], device_schemes(store), replicas=n
     )
     for r in replicas:
         await r.start()

@@ -7,6 +7,8 @@ case."""
 import asyncio
 import gc
 
+import pytest
+
 from minbft_tpu.obs import trace as obs_trace
 from minbft_tpu.sample.peer import placement
 
@@ -90,3 +92,19 @@ def test_policy_is_in_force_when_the_first_replica_starts(monkeypatch):
     assert thresholds == placement.COLLECTOR_THRESHOLDS
     assert frozen >= tracked - 100  # the keys, the modules, the replicas built
     assert unfrozen < 1000 < tracked
+
+
+@pytest.mark.parametrize("replicas, youngest", [(0, 50_000), (1, 50_000), (3, 50_000),
+                                                (7, 98_000), (31, 434_000)])
+def test_first_threshold_grows_with_the_replicas_a_process_carries(replicas, youngest):
+    """Twice what the replicas hold in flight, never under the floor; the
+    middle and oldest thresholds are the constants at any size."""
+    assert placement.collector_thresholds(replicas) == (youngest, 2, 10)
+    placement.settle_collector(replicas)
+    assert gc.get_threshold() == (youngest, 2, 10)
+
+
+def test_warm_engines_settles_the_collector_for_the_replicas_it_is_told():
+    asyncio.run(placement.warm_engines([], replicas=31))
+    assert gc.get_threshold() == placement.collector_thresholds(31)
+    assert obs_trace.timeline()["gc"]["thresholds"] == [434_000, 2, 10]
