@@ -281,9 +281,9 @@ def bench_ecdsa(batch: int, mode: str = "unrolled", prefix: str = "ecdsa") -> di
         digest = hashlib.sha256(b"bench").digest()
         sig = hc.ecdsa_sign(d, digest)
         items = [(q, digest, sig)] * batch
-        arrays = [jax.device_put(jnp.asarray(a)) for a in p256.prepare_batch(items)]
+        packed = jax.device_put(jnp.asarray(p256.prepare_packed(items, batch)))
         t0 = time.time()
-        out = p256.ecdsa_verify_kernel(*arrays)
+        out = p256.ecdsa_verify_kernel_packed(packed)
         ok = np.asarray(out)
         compile_s = time.time() - t0
         assert bool(ok.all()), "self-check failed: valid batch rejected"
@@ -296,7 +296,7 @@ def bench_ecdsa(batch: int, mode: str = "unrolled", prefix: str = "ecdsa") -> di
         n_iter = 20
         t0 = time.time()
         for _ in range(n_iter):
-            out = p256.ecdsa_verify_kernel(*arrays)
+            out = p256.ecdsa_verify_kernel_packed(packed)
         res = np.asarray(out)  # forces completion of the in-order stream
         dt = (time.time() - t0) / n_iter
         assert bool(res.all())
@@ -522,9 +522,10 @@ def bench_prep(batch: int = 16384, ed_batch: int = 4096) -> dict:
         )
         for _ in range(batch)
     ]
-    vec = p256.pack_arrays(p256.prepare_batch(items))
-    oracle = p256.pack_arrays(p256.prepare_batch_scalar(items))
-    assert np.array_equal(vec, oracle), "vectorized prep != scalar oracle"
+    assert all(
+        np.array_equal(vec, ref)
+        for vec, ref in zip(p256.prepare_batch(items), p256.prepare_batch_scalar(items))
+    ), "vectorized prep != scalar oracle"
 
     def best_of(fn, n_iter=3):
         best = float("inf")

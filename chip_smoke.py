@@ -428,6 +428,8 @@ def _engine_counts(engine) -> dict:
         "verify_batches": v.batches if v else 0,
         "verify_padded": v.padded_lanes if v else 0,
         "verify_timeouts": v.dispatch_timeouts if v else 0,
+        "table_hits": v.key_table_hits if v else 0,
+        "table_builds": v.key_table_builds if v else 0,
         "sign_items": s.items if s else 0,
         "sign_timeouts": s.dispatch_timeouts if s else 0,
         "sign_fallback": s.host_fallback_items if s else 0,
@@ -448,6 +450,9 @@ def check_engine_on_device(name: str, engine, base: dict) -> dict:
     )
     check(now["sign_fallback"] == 0,
           f"{name}: {now['sign_fallback']} signatures fell back to the host")
+    check(d["table_builds"] == 0 and d["table_hits"] > 0,
+          f"{name}: {d['table_builds']} comb tables built while serving "
+          f"({d['table_hits']} hits): the key store's keys were not primed")
     check(not engine.written_off(),
           f"{name}: device written off for {engine.written_off()}")
     return d
@@ -885,7 +890,11 @@ async def _grouped_run(seed: int, size: Size, chips: int, devices, store) -> dic
         InProcessPeerConnector,
         make_testnet_stubs,
     )
-    from minbft_tpu.sample.peer.placement import replica_authenticator, warm_engines
+    from minbft_tpu.sample.peer.placement import (
+        prime_key_tables,
+        replica_authenticator,
+        warm_engines,
+    )
     from minbft_tpu.sample.requestconsumer import SimpleLedger
 
     n, f, G = 4, 1, size.groups
@@ -907,6 +916,7 @@ async def _grouped_run(seed: int, size: Size, chips: int, devices, store) -> dic
         )
         stubs[i].assign_replica(rt)
         runtimes.append(rt)
+    prime_key_tables(store)  # as `peer run` does, before the engines warm
     await warm_engines([eng for pool in pools for eng in pool.engines])
     mem_base = [
         (dev.memory_stats() or {}).get("peak_bytes_in_use") for dev in devices[:chips]

@@ -163,6 +163,15 @@ def engine_queue_doc(engine, ident: int = 0) -> dict:
         "verify_queue_service": hists(engine.stats, "queue_service"),
         "sign_queue_wait": hists(engine.sign_stats, "queue_wait"),
         "sign_queue_service": hists(engine.sign_stats, "queue_service"),
+        "key_tables": {
+            name: {
+                "hits": st.key_table_hits,
+                "builds": st.key_table_builds,
+                "build_s": st.key_table_build_s,
+            }
+            for name, st in engine.stats.items()
+            if st.key_table_hits or st.key_table_builds
+        },
     }
 
 

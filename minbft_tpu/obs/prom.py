@@ -732,8 +732,12 @@ def _collect_engine(engine, base: Dict[str, str]) -> List[Family]:
             "batches": [],
             "padded_lanes": [],
             "dispatch_timeouts": [],
+            "key_table_hits": [],
+            "key_table_builds": [],
         }
-        seconds: Dict[str, List] = {"device": [], "host_prep": []}
+        seconds: Dict[str, List] = {
+            "device": [], "host_prep": [], "key_table_build": [],
+        }
         flushes: List = []
         occupancy: List = []
         depth_samples: List = []
@@ -746,6 +750,9 @@ def _collect_engine(engine, base: Dict[str, str]) -> List[Family]:
                 counters[k].append((lb, getattr(st, k, 0)))
             seconds["device"].append((lb, st.device_time_s))
             seconds["host_prep"].append((lb, st.host_prep_time_s))
+            seconds["key_table_build"].append(
+                (lb, getattr(st, "key_table_build_s", 0.0))
+            )
             qw = getattr(st, "queue_wait", None)
             if qw is not None and (qw.count or qw.negatives):
                 wait_samples.append((lb, qw))
@@ -788,6 +795,16 @@ def _collect_engine(engine, base: Dict[str, str]) -> List[Family]:
         fams.append((f"{p}_host_prep_seconds_total", "counter",
                      "host share of dispatch time (prep/pack/finish)",
                      seconds["host_prep"]))
+        if side == "verify":  # the ECDSA queue's per-key comb tables
+            fams.append((f"{p}_key_table_hits_total", "counter",
+                         "items whose key's comb table was cached",
+                         counters["key_table_hits"]))
+            fams.append((f"{p}_key_table_builds_total", "counter",
+                         "comb tables built inside a dispatch's prep",
+                         counters["key_table_builds"]))
+            fams.append((f"{p}_key_table_build_seconds_total", "counter",
+                         "seconds of those builds (part of host prep)",
+                         seconds["key_table_build"]))
         fams.append((f"{p}_flushes_total", "counter",
                      "queue flushes by reason (full/idle/timer/completion)",
                      flushes))

@@ -1,40 +1,52 @@
-"""Batched ECDSA-P256 verification as a JAX/XLA TPU kernel.
+"""Batched ECDSA-P256 verification (and signing) as JAX/XLA TPU kernels.
 
 This is the north-star hot path: the reference verifies every PREPARE/COMMIT
 UI certificate and client signature serially on CPU (Go crypto/ecdsa at
 sample/authentication/crypto.go:79-89; enclave-side create at
 usig/sgx/enclave/usig.c:36-76, verification in pure Go at
 usig/sgx/sgx-usig.go:81-97).  Here a whole batch of verifications runs as one
-data-parallel XLA program: ``jax.vmap`` over a scalar-shaped verifier whose
-field arithmetic is the fused limb machinery of :mod:`minbft_tpu.ops.limbs`.
+data-parallel XLA program whose field arithmetic is the fused limb machinery
+of :mod:`minbft_tpu.ops.limbs`.
 
 Division of labor (TPU-first):
 
-- **Host** hashes variable-length bytes to the fixed 32-byte digest ``z``
-  (:func:`minbft_tpu.messages.authen_digest`) and computes the two scalars
-  ``u1 = z*s^-1 mod n`` and ``u2 = r*s^-1 mod n`` with native big-int ops —
-  cheap, and it keeps mod-n arithmetic off the device entirely.  The
-  per-batch cost is bounded by Montgomery batch inversion (ONE ``pow``
-  per batch — 3 big-int multiplies per lane) and whole-batch numpy limb
-  packing/range checks; see the "Host-side batch preparation" section.
-- **Device** does everything expensive: the 256-bit double-scalar
-  multiplication ``u1*G + u2*Q`` (interleaved Shamir ladder, Jacobian
-  coordinates, a = -3 doubling), one Fermat inversion to build the G+Q
-  table entry, and the affine-free final check ``X == r * Z^2`` — all
-  constant-shape, batched, jit-compiled once per batch bucket.
+- **Host, once per public key**: a fixed-base comb table of the key,
+  ``T[j][v] = v * 16^j * Q`` (:func:`comb_table`, ~10 ms, 64 KiB), cached
+  by key in a bounded LRU (:class:`_KeyTables`).  A deployment's keys are
+  few and stable — the clients, replicas and USIG identities of one key
+  store — and :func:`prime_key_tables` builds them before a replica serves;
+  any other key is served once by a host scalar multiplication (~1.5 ms)
+  and gets its table when it comes back.
+- **Host, per batch**: hashes variable-length bytes to the fixed 32-byte
+  digest ``z`` (:func:`minbft_tpu.messages.authen_digest`), computes
+  ``u1 = z*s^-1 mod n`` and ``u2 = r*s^-1 mod n`` with native big ints —
+  ONE Montgomery batch inversion per batch, which keeps mod-n arithmetic
+  off the device — range-checks the batch with whole-batch numpy limb
+  compares, and selects with one fancy-index the 64 table rows that each
+  lane's u2 names (:func:`prepare_packed`).
+- **Device** adds: ``u1*G + u2*Q`` as two combs of 64 mixed additions (G's
+  table is a constant of the executable), one complete addition to join
+  them, and the affine-free final check ``X == r * Z^2`` — no doubling, no
+  inversion, constant shape, jit-compiled once per batch bucket
+  (:func:`_verify_one_packed`).
 
 Adversarial-input policy: the mixed-addition formula is incomplete (it
-cannot add a point to itself).  Instead of paying a full doubling inside
-every ladder add, the kernel *detects* the exceptional case and marks the
-lane rejected (``exc`` flag).  Honest signatures hit it with probability
-~2^-250; crafted signatures that steer the ladder into a collision are
-simply rejected, which is always sound — the kernel only ever errs toward
-rejection.  Identity operands (ladder start, Q == -G table entry) are
-handled exactly with constant-shape selects.
+cannot add a point to itself).  Inside one comb that case cannot arise for
+scalars below n; the kernel still *detects* it and marks the lane rejected
+(``exc`` flag), which is always sound — the kernel only ever errs toward
+rejection.  Where the two combs join, every case (equal points, inverse
+points, the identity on either side) is handled exactly with constant-shape
+selects, so a valid signature under ANY key of the curve — Q = G, Q = -G,
+Q = c*G — gets the host verifier's verdict.  A key that is not a point of
+the curve is refused on the host, as OpenSSL refuses it at load.
 """
 
 from __future__ import annotations
 
+import collections
+import dataclasses
+import threading
+import time
 from typing import NamedTuple, Sequence, Tuple
 
 import numpy as np
@@ -53,14 +65,13 @@ from .limbs import (
     fe_from_array,
     fe_is_zero,
     fe_select,
-    mont_inv,
     mont_mul,
     mont_one,
     mont_sqr,
     sub_mod,
     to_limbs,
-    to_mont,
 )
+from .lowering import per_mode_jit
 
 # ---------------------------------------------------------------------------
 # Curve constants (NIST P-256 / secp256r1, FIPS 186-4 D.1.2.3).
@@ -145,123 +156,41 @@ def _madd(
     return Point(x3, y3, z3), exc
 
 
-def _madd_complete_table(p: Point, qx: Fe, qy: Fe, q_inf: jnp.ndarray) -> Point:
-    """madd with the doubling case handled exactly (one extra _dbl) — used
-    once per verify to build the G+Q table entry, where Q == G must yield 2G
-    (a legitimate, if weird, public key)."""
-    res, exc = _madd(p, qx, qy, q_inf)
+def _add_complete(p: Point, q: Point) -> Point:
+    """Complete Jacobian + Jacobian addition (add-1998-cmo-2, 12M+4S, and
+    one ``_dbl`` selected in for p == q).  Every case exact: p == -q falls
+    out as the identity (Z3 = Z1*Z2*H = 0), identity operands are resolved
+    by selects.  Used ONCE per verify, to join the two comb sums — the one
+    place where a multiple of G can meet a multiple of Q."""
+    f = FIELD
+    z1z1 = mont_sqr(f, p.z)
+    z2z2 = mont_sqr(f, q.z)
+    u1 = mont_mul(f, p.x, z2z2)
+    u2 = mont_mul(f, q.x, z1z1)
+    s1 = mont_mul(f, p.y, mont_mul(f, q.z, z2z2))
+    s2 = mont_mul(f, q.y, mont_mul(f, p.z, z1z1))
+    h = sub_mod(f, u2, u1)
+    r = sub_mod(f, s2, s1)
+    hh = mont_sqr(f, h)
+    hhh = mont_mul(f, h, hh)
+    v = mont_mul(f, u1, hh)
+    x3 = sub_mod(f, sub_mod(f, mont_sqr(f, r), hhh), add_mod(f, v, v))
+    y3 = sub_mod(f, mont_mul(f, r, sub_mod(f, v, x3)), mont_mul(f, s1, hhh))
+    z3 = mont_mul(f, mont_mul(f, p.z, q.z), h)
+
+    p_inf = fe_is_zero(p.z)
+    q_inf = fe_is_zero(q.z)
+    same = fe_is_zero(h) & fe_is_zero(r) & ~p_inf & ~q_inf
     d = _dbl(p)
+
+    def pick(a, b, c, dd):  # the sum's, p's, q's, the doubling's coordinate
+        return fe_select(
+            p_inf, c, fe_select(q_inf, b, fe_select(same, dd, a))
+        )
+
     return Point(
-        fe_select(exc, d.x, res.x),
-        fe_select(exc, d.y, res.y),
-        fe_select(exc, d.z, res.z),
+        pick(x3, p.x, q.x, d.x), pick(y3, p.y, q.y, d.y), pick(z3, p.z, q.z, d.z)
     )
-
-
-def _bits_of(scalar_arr: jnp.ndarray) -> jnp.ndarray:
-    """[16] u32 limb array -> [256] bit array, bit j = bit j of the scalar."""
-    shifts = jnp.arange(limbs.LIMB_BITS, dtype=jnp.uint32)
-    return ((scalar_arr[:, None] >> shifts[None, :]) & 1).reshape(256)
-
-
-def _shamir(
-    u1_arr: jnp.ndarray, u2_arr: jnp.ndarray, qx_m: Fe, qy_m: Fe
-) -> Tuple[Point, jnp.ndarray]:
-    """Interleaved double-scalar multiplication u1*G + u2*Q.
-
-    256 iterations of double-then-select-add against the affine table
-    {-, Q, G, G+Q} (indexed by 2*bit(u1) + bit(u2)); the G+Q entry is built
-    on device with one Fermat inversion.  One ``fori_loop``: the compiled
-    program is a handful of loop nodes regardless of batch size.
-
-    Measured dead end (round 3, v5e, batch 4096/16384): signed-window
-    ladders (w=4 and w=5, host-precomputed G tables, device-built Jacobian
-    Q tables, ~30% fewer field multiplies than this ladder) are *slower*
-    here — 77-86k verifies/s vs 110-113k at 4096 — and compile 2-4x
-    longer.  Mosaic schedules this tiny loop body (~19 mults) near peak
-    VPU throughput, while the windowed bodies (~60 mults + 9-17-entry
-    per-lane tables live across the loop) lose more to scheduling and
-    vector-memory pressure than the multiply count saves; per-lane
-    dynamic gathers for table lookups are 6x worse still.  The batch
-    size, not the ladder, is the remaining lever: larger batches
-    amortize the per-dispatch host<->device cost (to be measured on the
-    chip).
-
-    Returns (result, exc) — exc set if any ladder add hit the incomplete
-    case (lane must be rejected; see module docstring).
-    """
-    f = FIELD
-    one = mont_one(f)
-    gx: Fe = _GX_M
-    gy: Fe = _GY_M
-
-    # Table entry G+Q (affine).  Q == ±G handled exactly.
-    gq = _madd_complete_table(Point(gx, gy, one), qx_m, qy_m, jnp.bool_(False))
-    gq_inf = fe_is_zero(gq.z)
-    zsafe = fe_select(gq_inf, one, gq.z)
-    zi = mont_inv(f, zsafe)
-    zi2 = mont_sqr(f, zi)
-    gqx = mont_mul(f, gq.x, zi2)
-    gqy = mont_mul(f, gq.y, mont_mul(f, zi, zi2))
-
-    bits1 = _bits_of(u1_arr)
-    bits2 = _bits_of(u2_arr)
-
-    def body(i, carry):
-        acc, exc = carry
-        j = 255 - i
-        acc = _dbl(acc)
-        b1 = lax.dynamic_index_in_dim(bits1, j, keepdims=False)
-        b2 = lax.dynamic_index_in_dim(bits2, j, keepdims=False)
-        d = b1 * 2 + b2
-        # Select the table entry with elementwise masks (no gathers).
-        is1, is2, is3 = d == 1, d == 2, d == 3
-        ax = fe_select(is1, qx_m, fe_select(is2, gx, gqx))
-        ay = fe_select(is1, qy_m, fe_select(is2, gy, gqy))
-        ainf = jnp.where(d == 0, jnp.bool_(True), is3 & gq_inf)
-        res, e = _madd(acc, ax, ay, ainf)
-        return res, exc | e
-
-    start = Point(one, one, limbs.fe_zero())  # identity
-    return lax.fori_loop(0, 256, body, (start, jnp.bool_(False)))
-
-
-def _verify_one(
-    qx: jnp.ndarray,
-    qy: jnp.ndarray,
-    u1: jnp.ndarray,
-    u2: jnp.ndarray,
-    r: jnp.ndarray,
-    r2: jnp.ndarray,
-    r2_ok: jnp.ndarray,
-    valid: jnp.ndarray,
-) -> jnp.ndarray:
-    """Scalar-shaped ECDSA verify core; limb-array args [16] u32.
-
-    Checks x(R) ≡ r (mod n) without an affine conversion: with R = (X:Y:Z)
-    Jacobian, x(R) = X/Z^2, so x(R) == c  <=>  X == c*Z^2 (all Montgomery).
-    Host supplies both candidates c ∈ {r, r+n} (the second only when
-    r+n < p, flagged by ``r2_ok``).
-
-    ``valid`` carries host-side range checks (r, s in [1, n-1]); the kernel
-    AND-folds it so invalid inputs burn the same cycles as valid ones
-    (constant shape) but always return False.
-    """
-    f = FIELD
-    qx_m = to_mont(f, fe_from_array(qx))
-    qy_m = to_mont(f, fe_from_array(qy))
-    res, exc = _shamir(u1, u2, qx_m, qy_m)
-    inf = fe_is_zero(res.z)
-    z2 = mont_sqr(f, res.z)
-    c1 = mont_mul(f, to_mont(f, fe_from_array(r)), z2)
-    c2 = mont_mul(f, to_mont(f, fe_from_array(r2)), z2)
-    ok = fe_eq(res.x, c1) | (r2_ok & fe_eq(res.x, c2))
-    return ok & ~inf & ~exc & valid
-
-
-from .lowering import per_mode_jit
-
-_verify_batch = per_mode_jit(jax.vmap(_verify_one))
 
 
 # ---------------------------------------------------------------------------
@@ -427,81 +356,425 @@ def prepare_batch(
     return qx, qy, u1, u2, rr, r2, r2_ok, valid
 
 
-def verify_batch(
-    items: Sequence[Tuple[Tuple[int, int], bytes, Tuple[int, int]]],
-) -> np.ndarray:
-    """Convenience wrapper: prepare on host, verify on device -> [B] bool."""
-    arrays = prepare_batch(items)
-    return np.asarray(_verify_batch(*[jnp.asarray(a) for a in arrays]))
+# ---------------------------------------------------------------------------
+# Fixed-base comb tables (host).
+#
+# Write a scalar k = sum_j k_j * 16^j over 64 nibble windows and
+# precompute T[j][v] = v * 16^j * B (affine, Montgomery domain) ON THE HOST
+# for a base point B: then k*B = sum_j T[j][k_j] is 64 mixed additions and
+# NO doubling (~7x fewer field multiplications than a 256-step
+# double-and-add ladder).  Signing uses it with B = G (one table, a
+# compile-time constant of the sign kernel).  Verification uses it TWICE:
+# u1*G against G's table and u2*Q against a table built once per public
+# key — the key set of a deployment is small and stable (clients, replicas
+# and USIG identities of one key store), so the table is cached by key and
+# the device never sees Q itself, only the 64 rows of its table that the
+# lane's u2 selects.
+#
+# What was measured before, and what runs now.  Until PR 30 verification
+# was an interleaved Shamir ladder (256 doublings, 256 unconditional mixed
+# additions against {Q, G, G+Q}, one Fermat inversion for the G+Q entry:
+# ~5,400 field multiplications a lane, 9.56 ms a 512-lane dispatch on a
+# v5e).  Round 3 had tried windowing it and recorded a dead end: signed
+# windows (w = 4, 5) with host tables for G but per-lane Jacobian tables
+# of Q BUILT ON THE DEVICE, at batch 4,096 / 16,384 (the throughput
+# regime), were slower (77-86k against 110-113k verifies/s) and compiled
+# 2-4 times longer — the windowed bodies kept the 256 doublings and held
+# 9-17 table entries a lane live across the loop — and per-lane dynamic
+# gathers for the lookups were 6 times worse still.  None of that is what
+# runs here: no doubling at all, no table on the device but G's constant
+# one, the per-lane rows selected on the host and uploaded as plain input
+# (~2 MB a 512-lane dispatch), and the regime is latency at 512 lanes.
+
+_COMB_WINDOWS = 64
+_COMB_ROW = 2 * limbs.NLIMBS  # one affine point: x limbs | y limbs
 
 
-ecdsa_verify_kernel = _verify_batch  # the raw jitted batch entry point
+def _jac_dbl_host(pt):
+    """Jacobian doubling mod p, a = -3, Python ints (table building)."""
+    x, y, z = pt
+    delta = z * z % P
+    gamma = y * y % P
+    beta = x * gamma % P
+    alpha = 3 * (x - delta) * (x + delta) % P
+    x3 = (alpha * alpha - 8 * beta) % P
+    z3 = ((y + z) * (y + z) - gamma - delta) % P
+    y3 = (alpha * (4 * beta - x3) - 8 * gamma * gamma) % P
+    return x3, y3, z3
 
 
-# Packed I/O: each host->device array is its own transfer, and the
-# 8-argument form pays 8 of them per dispatch (per-dispatch host<->device
-# cost, to be measured on the chip).  One u16 row per lane — limb values
-# are 16-bit by construction, flags are 0/1 — makes the upload a single
-# transfer at half the bytes.
+def _jac_add_host(p1, p2):
+    """Jacobian addition mod p of two finite points with p1 != +-p2 (the
+    only additions a comb table of a point of prime order needs)."""
+    x1, y1, z1 = p1
+    x2, y2, z2 = p2
+    z1z1 = z1 * z1 % P
+    z2z2 = z2 * z2 % P
+    u1 = x1 * z2z2 % P
+    u2 = x2 * z1z1 % P
+    s1 = y1 * z2 * z2z2 % P
+    s2 = y2 * z1 * z1z1 % P
+    h = (u2 - u1) % P
+    r = (s2 - s1) % P
+    hh = h * h % P
+    hhh = h * hh % P
+    v = u1 * hh % P
+    x3 = (r * r - hhh - 2 * v) % P
+    y3 = (r * (v - x3) - s1 * hhh) % P
+    return x3, y3, z1 * z2 * h % P
 
-PACKED_COLS = 6 * limbs.NLIMBS + 2  # qx qy u1 u2 r r2 | r2_ok valid
+
+def _small_multiples_host(base):
+    """[1*base, ..., 15*base], Jacobian: even multiples by doubling (8
+    multiplications against an addition's 16).  ``base`` finite, of order
+    n: no sum meets its addend."""
+    row = [base, _jac_dbl_host(base)]
+    for v in range(3, 16):
+        row.append(
+            _jac_add_host(row[-1], base) if v & 1 else _jac_dbl_host(row[v // 2 - 1])
+        )
+    return row
 
 
-def pack_arrays(arrays) -> np.ndarray:
-    """prepare_batch output -> [B, PACKED_COLS] u16 (one upload)."""
-    qx, qy, u1, u2, rr, r2, r2_ok, valid = arrays
-    return np.concatenate(
-        [
-            qx, qy, u1, u2, rr, r2,
-            r2_ok[:, None].astype(np.uint32),
-            valid[:, None].astype(np.uint32),
-        ],
-        axis=1,
-    ).astype(np.uint16)
+def _affine_mont_host(jac) -> list:
+    """Jacobian points -> [x0, y0, x1, y1, ...] affine, Montgomery domain,
+    with ONE inversion for all of them (:func:`limbs.batch_inv_host`)."""
+    z_inv = limbs.batch_inv_host([z for _, _, z in jac], P)
+    r_mont = (1 << 256) % P
+    coords = []
+    for (x, y, _), zi in zip(jac, z_inv):
+        zi2 = zi * zi % P
+        coords.append(x * zi2 % P * r_mont % P)
+        coords.append(y * zi2 % P * zi % P * r_mont % P)
+    return coords
+
+
+def comb_table(point: Tuple[int, int]) -> np.ndarray:
+    """Affine (x, y) ON the curve -> [64, 16, 32] u16 comb table:
+    ``T[j, v] = x limbs | y limbs`` of ``v * 16^j * point`` in the
+    Montgomery domain; the v = 0 rows (infinity) are zeros and are skipped
+    by the nibble, as the sign kernel skips them.
+
+    Jacobian sums in Python integers and one batch inversion for the 960
+    entries: ~10 ms a key, 64 KiB.  The curve has prime order, so every
+    finite point has order n."""
+    base = (point[0], point[1], 1)
+    jac = []
+    for _ in range(_COMB_WINDOWS):
+        row = _small_multiples_host(base)
+        jac.extend(row)
+        base = _jac_dbl_host(row[7])  # 16 * base = 2 * (8 * base)
+    tab = np.zeros((_COMB_WINDOWS, 16, _COMB_ROW), np.uint16)
+    tab[:, 1:] = limbs.to_limbs_batch(_affine_mont_host(jac)).reshape(
+        _COMB_WINDOWS, 15, _COMB_ROW
+    )
+    return tab
+
+
+def _scalar_mult_row(k: int, point: Tuple[int, int]) -> np.ndarray:
+    """``k * point`` (0 < k < n, point ON the curve) as one table row, [32]
+    u16: a 4-bit window ladder in Python integers, ~1.5 ms.  For the first
+    use of a key that has no comb table (see :class:`_KeyTables`).  The
+    accumulator 16*m*Q never meets its addend v*Q: 16*m + v <= k < n."""
+    small = _small_multiples_host((point[0], point[1], 1))
+    acc = None
+    for shift in range(252, -1, -4):
+        if acc is not None:
+            for _ in range(4):
+                acc = _jac_dbl_host(acc)
+        v = (k >> shift) & 0xF
+        if v:
+            acc = small[v - 1] if acc is None else _jac_add_host(acc, small[v - 1])
+    return limbs.to_limbs_batch(_affine_mont_host([acc])).reshape(_COMB_ROW).astype(np.uint16)
+
+
+# G's comb table as the kernels close over it: [64, 16, 2, NLIMBS] u32.
+# Built at import (~15 ms), not on first use: the verify kernel reads it
+# while it is traced.
+_COMB_TABLE_NP = (
+    comb_table((GX, GY)).reshape(_COMB_WINDOWS, 16, 2, limbs.NLIMBS).astype(np.uint32)
+)
+
+
+@dataclasses.dataclass
+class KeyTableTally:
+    """What one or more :func:`prepare_packed` calls did with the key
+    tables: ``hits`` items whose key's table was cached, ``builds`` tables
+    built (by priming, or inside the call that met a key for the second
+    time) and the seconds they took."""
+
+    hits: int = 0
+    builds: int = 0
+    build_s: float = 0.0
+
+
+class _KeyTables:
+    """Comb tables by public key: a bounded LRU over one preallocated
+    array, so that a batch's rows are ONE fancy-index whatever its mix of
+    keys.  ``slots`` tables of 64 KiB: 64 MiB at the worst (untouched
+    slots are never paged in; a 30-key deployment holds under 2 MiB).  A
+    key met past that evicts the least recently used and is built again
+    when it returns; ``KeyTableTally.builds`` shows it.
+
+    A key gets its table when it is primed (:meth:`ensure`) or on its
+    SECOND use; its first use is served by one scalar multiplication on
+    the host (:func:`prepare_packed`), ~1.5 ms against a build's ~10 ms.
+    So a key that is used once (an engine's warm-up item, a calibration
+    dispatch, a probe) costs no build and no slot, and a table is paid for
+    only by a key that comes back.
+
+    Which keys come here is the key store's to say, not a peer's: the
+    authenticator verifies a client or replica signature under the store's
+    key for the claimed id and a UI under the store's USIG anchor (only
+    the epoch is pinned on first use), and refuses an unknown id before
+    any verification (sample/authentication/authenticator.py).  So builds
+    are bounded by the store, and :func:`prime_key_tables` does them
+    before a replica serves."""
+
+    def __init__(self, slots: int = 1024):
+        self._tables = np.empty((slots, _COMB_WINDOWS, 16, _COMB_ROW), np.uint16)
+        self._rows = self._tables.reshape(-1, _COMB_ROW)  # a view: one row an index
+        self._slot: "collections.OrderedDict[Tuple[int, int], int]" = (
+            collections.OrderedDict()
+        )
+        self._used_once: "collections.OrderedDict[Tuple[int, int], None]" = (
+            collections.OrderedDict()
+        )
+        # Serialises slot assignment AND the gather: a slot must not be
+        # recycled under a reader.  Builds run outside it.
+        self._lock = threading.Lock()
+
+    def __len__(self) -> int:
+        return len(self._slot)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._slot.clear()
+            self._used_once.clear()
+
+    def ensure(self, keys, tally: KeyTableTally) -> None:
+        """Build the table of every key of ``keys`` that has none and is a
+        point of the curve."""
+        for key in set(keys).difference(self._slot):
+            if not is_on_curve(*key):
+                continue
+            t0 = time.perf_counter()
+            table = comb_table(key)
+            with self._lock:
+                if key not in self._slot:  # else another thread built it meanwhile
+                    if len(self._slot) < len(self._tables):
+                        slot = len(self._slot)
+                    else:
+                        _, slot = self._slot.popitem(last=False)
+                    self._tables[slot] = table
+                    self._slot[key] = slot
+            tally.builds += 1
+            tally.build_s += time.perf_counter() - t0
+
+    def rows(self, keys, nibbles: np.ndarray, tally: KeyTableTally):
+        """``keys``: m public keys; ``nibbles``: [m, 64] window values of
+        their lanes' u2.  -> ([m, 64, 32] u16 table rows, [m] bool: False
+        where the key has no table, its rows then being arbitrary)."""
+        lacking = collections.Counter(k for k in keys if k not in self._slot)
+        tally.hits += len(keys) - sum(lacking.values())
+        if lacking:
+            with self._lock:
+                again = [k for k, n in lacking.items() if n > 1 or k in self._used_once]
+                self._used_once.update(dict.fromkeys(lacking))
+                while len(self._used_once) > len(self._tables):
+                    self._used_once.popitem(last=False)
+            self.ensure(again, tally)
+        with self._lock:
+            slots = list(map(self._slot.get, keys))
+            if len(self._slot) == len(self._tables):  # full: keep LRU order
+                for key in set(keys).intersection(self._slot):
+                    self._slot.move_to_end(key)
+            have = np.array([slot is not None for slot in slots], np.bool_)
+            at = np.array([slot or 0 for slot in slots], np.intp)
+            index = (at[:, None] * _COMB_WINDOWS + _WINDOW_INDEX) * 16 + nibbles
+            got = self._rows.take(index.ravel(), axis=0)
+        return got.reshape(len(keys), _COMB_WINDOWS, _COMB_ROW), have
+
+
+_WINDOW_INDEX = np.arange(_COMB_WINDOWS)
+_KEY_TABLES = _KeyTables()
+
+
+def prime_key_tables(keys) -> KeyTableTally:
+    """Build the comb tables of ``keys`` — the P-256 points a key store
+    names — so that no build falls inside a served request."""
+    tally = KeyTableTally()
+    _KEY_TABLES.ensure([tuple(k) for k in keys], tally)
+    return tally
+
+
+# ---------------------------------------------------------------------------
+# Packed verification: the engine's path.
+#
+# One u16 row per lane (limb values are 16-bit by construction, flags are
+# 0/1), one host->device transfer per dispatch:
+#
+#   [0, 2048)     the 64 rows of Q's comb table that u2's nibbles select
+#   [2048, 2064)  u1   (the device selects G's rows itself, from the
+#                       constant table, as the sign kernel does)
+#   [2064, 2080)  u2   (its nibbles flag the infinity rows; 1 where the
+#                       lane's one row is u2*Q itself: a key's first use)
+#   [2080, 2096)  r
+#   [2096, 2112)  r2 = r + n, meaningful where r2_ok
+#   2112, 2113    r2_ok, valid
+
+_Q_COLS = _COMB_WINDOWS * _COMB_ROW
+PACKED_COLS = _Q_COLS + 4 * limbs.NLIMBS + 2
+_NIBBLE_SHIFTS = 4 * np.arange(4, dtype=np.uint32)  # limb i holds windows 4i..4i+3
 
 
 def prepare_packed(
     items: Sequence[Tuple[Tuple[int, int], bytes, Tuple[int, int]]],
     bucket: int,
     out: "np.ndarray | None" = None,
+    tally: "KeyTableTally | None" = None,
 ) -> np.ndarray:
-    """prepare_batch + pack_arrays fused into one [bucket, PACKED_COLS]
-    u16 staging write.  ``out`` (engine-owned staging buffer, recycled
-    across dispatches) is written in place when given; padding the batch
-    to ``bucket`` is a tail slice-zero instead of materializing
-    ``list(items) + [PAD] * k`` and prepping the pad lanes."""
+    """:func:`prepare_batch` (range checks, ONE batch inversion, u1, u2)
+    plus the table rows, as one [bucket, PACKED_COLS] u16 staging write.
+    ``out`` (engine-owned staging buffer, recycled across dispatches) is
+    written in place when given.  Lanes past ``len(items)`` and lanes
+    whose signature or key is out of range get ``valid = 0``; their table
+    rows are whatever the buffer held (the kernel computes on them and
+    ANDs the verdict away).  A key that is not a point of the curve makes
+    its lane invalid here, on the host — OpenSSL's verdict at key load.
+    A lane whose key has no table yet (its first use) carries u2*Q itself
+    as its one row.  ``tally`` (optional) is told the key tables' hits
+    and builds."""
     n = len(items)
     out = limbs.staging_out(out, bucket, PACKED_COLS, n)
-    qx, qy, u1, u2, rr, r2, r2_ok, valid = prepare_batch(items)
+    _qx, _qy, u1, u2, rr, r2, r2_ok, valid = prepare_batch(items)
     L = limbs.NLIMBS
-    out[:n, 0:L] = qx
-    out[:n, L : 2 * L] = qy
-    out[:n, 2 * L : 3 * L] = u1
-    out[:n, 3 * L : 4 * L] = u2
-    out[:n, 4 * L : 5 * L] = rr
-    out[:n, 5 * L : 6 * L] = r2
-    out[:n, 6 * L] = r2_ok
-    out[:n, 6 * L + 1] = valid
-    out[n:] = 0
+    idx = np.flatnonzero(valid)
+    if len(idx):
+        keys = [(items[i][0][0], items[i][0][1]) for i in idx]
+        nib = ((u2[idx][:, :, None] >> _NIBBLE_SHIFTS) & 0xF).reshape(
+            len(idx), _COMB_WINDOWS
+        )
+        got, have = _KEY_TABLES.rows(
+            keys, nib, tally if tally is not None else KeyTableTally()
+        )
+        for k in np.flatnonzero(~have):  # a key without a table
+            lane = idx[k]
+            if not is_on_curve(*keys[k]):
+                valid[lane] = False
+                continue
+            # First use of the key: its one row is u2*Q itself, in window
+            # 0, and the scalar the kernel reads the windows from is 1.
+            got[k, 0] = _scalar_mult_row(limbs.from_limbs_batch(u2[lane : lane + 1])[0], keys[k])
+            u2[lane] = 0
+            u2[lane, 0] = 1
+        out[idx, :_Q_COLS] = got.reshape(len(idx), _Q_COLS)
+    c = _Q_COLS
+    out[:n, c : c + L] = u1
+    out[:n, c + L : c + 2 * L] = u2
+    out[:n, c + 2 * L : c + 3 * L] = rr
+    out[:n, c + 3 * L : c + 4 * L] = r2
+    out[:n, c + 4 * L] = r2_ok
+    out[:n, c + 4 * L + 1] = valid
+    out[n:, c:] = 0
     return out
 
 
-def _verify_one_packed(row: jnp.ndarray) -> jnp.ndarray:
-    r32 = row.astype(jnp.uint32)
-    L = limbs.NLIMBS
-    return _verify_one(
-        r32[0:L],
-        r32[L : 2 * L],
-        r32[2 * L : 3 * L],
-        r32[3 * L : 4 * L],
-        r32[4 * L : 5 * L],
-        r32[5 * L : 6 * L],
-        r32[6 * L] != 0,
-        r32[6 * L + 1] != 0,
+def _nibbles_of(scalar_arr: jnp.ndarray) -> jnp.ndarray:
+    """[16] u32 limb array -> [64] nibble windows, window j = bits 4j..4j+3."""
+    shifts = jnp.asarray(_NIBBLE_SHIFTS)[None, :]
+    return ((scalar_arr[:, None] >> shifts) & 0xF).reshape(_COMB_WINDOWS)
+
+
+def _select_row(tab_j: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """Row ``v`` of one window's [16, 2, L] constant table by an elementwise
+    masked sum — no gather, nothing per-lane resident across the loop."""
+    mask = (jnp.arange(16, dtype=jnp.uint32) == v)[:, None, None]
+    return jnp.sum(jnp.where(mask, tab_j, 0), axis=0)  # [2, L]
+
+
+def _verify_one_packed(packed: jnp.ndarray) -> jnp.ndarray:
+    """ECDSA verify of ONE dispatch: [B, PACKED_COLS] u16 (layout above)
+    -> [B] bool.  (The name is the jit's in a profiler trace,
+    ``jit__verify_one_packed``, which the benchmark finds the kernel by.)
+
+    Two fixed-base combs, no doubling, no inversion: ``u1*G`` sums the
+    rows of G's constant table that u1's nibbles select (on the device, a
+    masked sum, as the sign kernel does) and ``u2*Q`` the rows of the
+    key's table that the HOST selected by u2's nibbles.  Both run as ONE
+    chain of 64 ``_madd`` over 2B chain-lanes — lanes [0, B) carry the G
+    comb, [B, 2B) the Q comb — because a limb vector of 512 lanes fills
+    half a vector register (XLA tiles it ``T(512)``): a second chain of
+    512 beside the first costs no further instruction, and the loop body
+    holds one ``_madd``, not two.  So the batch axis is explicit here, not
+    ``vmap``'s.  Then ONE complete addition joins the halves, and the
+    affine-free check is ``X == r * Z^2`` without an inversion: x(R) =
+    X/Z^2, against both candidates r and r2 = r + n (where ``r2_ok``).
+
+    Exceptional cases: inside one comb a partial sum m*B, m < 16^(j+1),
+    cannot be +-(the next addend k * 16^(j+1) * B) for scalars below n and
+    B of order n, so ``_madd``'s ``exc`` cannot fire; it is OR-folded into
+    a rejection all the same.  The two combs are multiples of DIFFERENT
+    points and can meet (Q = c*G): they are summed apart and joined by
+    :func:`_add_complete`, exact for a1 = +-a2 and for either being the
+    identity (u1 = 0).  ``valid`` carries the host's range checks; invalid
+    and pad lanes burn the same cycles and return False."""
+    f = FIELD
+    B, L, c = packed.shape[0], limbs.NLIMBS, _Q_COLS
+    p32 = packed.astype(jnp.uint32)
+    q_rows = p32[:, :c].reshape(B, _COMB_WINDOWS, 2, L)
+    scalars = p32[:, c : c + 4 * L].reshape(B, 4, L)  # u1, u2, r, r2
+    r2_ok = p32[:, c + 4 * L] != 0
+    valid = p32[:, c + 4 * L + 1] != 0
+    nibbles = jax.vmap(_nibbles_of)
+    nib = jnp.concatenate([nibbles(scalars[:, 0]), nibbles(scalars[:, 1])])
+    g_table = jnp.asarray(_COMB_TABLE_NP)
+    select_rows = jax.vmap(_select_row, in_axes=(None, 0))
+
+    def body(j, carry):
+        acc, exc = carry
+        v = lax.dynamic_index_in_dim(nib, j, axis=1, keepdims=False)  # [2B]
+        g = select_rows(lax.dynamic_index_in_dim(g_table, j, keepdims=False), v[:B])
+        q = lax.dynamic_index_in_dim(q_rows, j, axis=1, keepdims=False)
+        row = jnp.concatenate([g, q])  # [2B, 2, L]
+        acc, e = _madd(
+            acc, fe_from_array(row[:, 0]), fe_from_array(row[:, 1]), v == 0
+        )
+        return acc, exc | e
+
+    def lanes(x):  # a field constant on every chain-lane
+        return tuple(jnp.full((2 * B,), v, jnp.uint32) for v in x)
+
+    start = Point(lanes(mont_one(f)), lanes(mont_one(f)), lanes(limbs.fe_zero()))
+    acc, exc = lax.fori_loop(
+        0, _COMB_WINDOWS, body, (start, jnp.zeros((2 * B,), jnp.bool_))
     )
+    a1 = Point(*(tuple(v[:B] for v in coord) for coord in acc))
+    a2 = Point(*(tuple(v[B:] for v in coord) for coord in acc))
+    res = _add_complete(a1, a2)
+    inf = fe_is_zero(res.z)
+    z2 = mont_sqr(f, res.z)
+    # to_mont with the constant as the first factor: the product is
+    # symmetric, and mont_mul takes its batch shape from the second.
+    c1 = mont_mul(f, mont_mul(f, f.r2_mod, fe_from_array(scalars[:, 2])), z2)
+    c2 = mont_mul(f, mont_mul(f, f.r2_mod, fe_from_array(scalars[:, 3])), z2)
+    ok = fe_eq(res.x, c1) | (r2_ok & fe_eq(res.x, c2))
+    return ok & ~inf & ~(exc[:B] | exc[B:]) & valid
 
 
-ecdsa_verify_kernel_packed = per_mode_jit(jax.vmap(_verify_one_packed))
+ecdsa_verify_kernel_packed = per_mode_jit(_verify_one_packed)
+
+
+def verify_batch(
+    items: Sequence[Tuple[Tuple[int, int], bytes, Tuple[int, int]]],
+) -> np.ndarray:
+    """Convenience wrapper: prepare on host, verify on device -> [B] bool,
+    through the engine's packed kernel.  The batch is padded to a power of
+    two (at least 8), so that callers of many sizes share few compiles."""
+    n = len(items)
+    bucket = max(8, 1 << (n - 1).bit_length())
+    packed = prepare_packed(items, bucket)
+    return np.asarray(ecdsa_verify_kernel_packed(jnp.asarray(packed)))[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -515,6 +788,12 @@ ecdsa_verify_kernel_packed = per_mode_jit(jax.vmap(_verify_one_packed))
 # the host signer (deterministic k), which doubles as the differential
 # test.  Whether a sign batch beats the serial host signer depends on the
 # per-dispatch host<->device cost (to be measured on the chip).
+
+
+def _bits_of(scalar_arr: jnp.ndarray) -> jnp.ndarray:
+    """[16] u32 limb array -> [256] bit array, bit j = bit j of the scalar."""
+    shifts = jnp.arange(limbs.LIMB_BITS, dtype=jnp.uint32)
+    return ((scalar_arr[:, None] >> shifts[None, :]) & 1).reshape(256)
 
 
 def _kg_one(k: jnp.ndarray) -> jnp.ndarray:
@@ -551,56 +830,10 @@ ecdsa_kg_ladder_kernel = per_mode_jit(jax.vmap(_kg_one))
 
 # --- fixed-base comb --------------------------------------------------------
 #
-# k*G with G fixed admits a precomputed-table comb that the general ladder
-# cannot use: write k = sum_j k_j * 16^j over 64 nibble windows and
-# precompute T[j][v] = v * 16^j * G (affine, Montgomery domain) ON THE HOST
-# — then k*G = sum_j T[j][k_j] is just 64 mixed additions with NO doublings
-# (~7x fewer field multiplies than the 256 double+add ladder).  The
-# windowed approach measured as a dead end for the VERIFY ladder (see
-# _shamir's note) fails on per-lane runtime tables; here the table is one
-# global compile-time constant shared by every lane, and each window's
-# lookup is an elementwise masked sum over 16 rows — no gathers, nothing
-# per-lane resident across the loop.
-
-_COMB_WINDOWS = 64
-_COMB_TABLE_NP: np.ndarray | None = None
-
-
-def _comb_table_np() -> np.ndarray:
-    """[64, 16, 2, NLIMBS] u32: T[j][v] = affine(v * 16^j * G), Montgomery
-    domain; the v=0 rows are zeros (skipped via the q_inf flag).  Built
-    once with host big-int affine arithmetic (~1k cheap ops)."""
-    global _COMB_TABLE_NP
-    if _COMB_TABLE_NP is not None:
-        return _COMB_TABLE_NP
-
-    def aff_add(p1, p2):
-        if p1 is None:
-            return p2
-        (x1, y1), (x2, y2) = p1, p2
-        if x1 == x2:
-            if (y1 + y2) % P == 0:
-                return None
-            lam = (3 * x1 * x1 - 3) * pow(2 * y1, -1, P) % P
-        else:
-            lam = (y2 - y1) * pow(x2 - x1, -1, P) % P
-        x3 = (lam * lam - x1 - x2) % P
-        return x3, (lam * (x1 - x3) - y1) % P
-
-    tab = np.zeros((_COMB_WINDOWS, 16, 2, limbs.NLIMBS), np.uint32)
-    base = (GX, GY)  # 16^j * G for the current window
-    for j in range(_COMB_WINDOWS):
-        acc = None
-        for v in range(1, 16):
-            acc = aff_add(acc, base)
-            x, y = acc
-            tab[j, v, 0] = to_limbs((x << 256) % P)
-            tab[j, v, 1] = to_limbs((y << 256) % P)
-        for _ in range(4):  # base <- 16 * base
-            base = aff_add(base, base)
-    _COMB_TABLE_NP = tab
-    return tab
-
+# k*G over G's comb table (see "Fixed-base comb tables" above): 64 mixed
+# additions, no doubling.  The table is one compile-time constant shared by
+# every lane, and each window's lookup is an elementwise masked sum over 16
+# rows (:func:`_select_row`).
 
 def _kg_comb_one(k: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
     """Scalar-shaped k*G via the fixed-base comb (see the note above).
@@ -612,16 +845,13 @@ def _kg_comb_one(k: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
     incomplete madd's p == ±q cases would need m == ±k_{j+1}*16^(j+1)
     (mod n), impossible for honest scalars < n; exc is still folded to
     Z = 0 (host-signer fallback) as defense in depth."""
-    # limb i (16 bits) holds nibble windows 4i..4i+3
-    shifts = (4 * jnp.arange(4, dtype=jnp.uint32))[None, :]
-    nibs = ((k[:, None] >> shifts) & 0xF).reshape(_COMB_WINDOWS)
+    nibs = _nibbles_of(k)
 
     def body(j, carry):
         acc, exc = carry
         tab_j = lax.dynamic_index_in_dim(table, j, keepdims=False)  # [16,2,L]
         v = lax.dynamic_index_in_dim(nibs, j, keepdims=False)
-        mask = (jnp.arange(16, dtype=jnp.uint32) == v)[:, None, None]
-        sel = jnp.sum(jnp.where(mask, tab_j, 0), axis=0)  # [2, L]
+        sel = _select_row(tab_j, v)  # [2, L]
         ax = fe_from_array(sel[0])
         ay = fe_from_array(sel[1])
         res, e = _madd(acc, ax, ay, v == 0)
@@ -647,7 +877,7 @@ def kg_comb_kernel():
     lower; :func:`ecdsa_kg_kernel` is the array-taking entry point.)"""
     global _kg_comb_batch
     if _kg_comb_batch is None:
-        table = jnp.asarray(_comb_table_np())
+        table = jnp.asarray(_COMB_TABLE_NP)
 
         def _kg_comb_widen(k16: jnp.ndarray) -> jnp.ndarray:
             # Widen the u16 upload on device; the wire carries half the

@@ -175,6 +175,13 @@ class VerifyStats:
     # device_time_s is the prep share of the dispatch's await (bench.py
     # reports it as *_prep_share).
     host_prep_time_s: float = 0.0
+    # The ECDSA queue's per-key comb tables (ops/p256.py): items whose
+    # key's table was cached, tables built inside a dispatch's prep (the
+    # second use of a key the key store's priming did not name, or of one
+    # evicted), and the seconds the builds took (part of host_prep_time_s).
+    key_table_hits: int = 0
+    key_table_builds: int = 0
+    key_table_build_s: float = 0.0
     memo_hits: int = 0
     dispatch_timeouts: int = 0  # hung device dispatches rescued on host
     # Flight-recorder gauges (event-loop-side updates only): why each
@@ -1208,13 +1215,18 @@ class BatchVerifier:
     # until the dispatch completes, and a released buffer can be
     # re-acquired and overwritten by a concurrent dispatcher.
 
-    def _note_prep(self, name: str, pad: int, prep_s: float) -> None:
+    def _note_prep(self, name: str, pad: int, prep_s: float, tables=None) -> None:
         """Cross-thread stats update for a dispatcher (worker thread):
-        padded-lane and host-prep accounting under the stats lock."""
+        padded-lane and host-prep accounting under the stats lock;
+        ``tables`` is the ECDSA prep's ``p256.KeyTableTally``."""
         with self._stats_lock:
             st = self._queues[name].stats
             st.padded_lanes += pad
             st.host_prep_time_s += prep_s
+            if tables is not None:
+                st.key_table_hits += tables.hits
+                st.key_table_builds += tables.builds
+                st.key_table_build_s += tables.build_s
 
     def _note_sign_prep(self, name: str, pad: int, prep_s: float) -> None:
         """Sign-queue sibling of :meth:`_note_prep` (worker thread):
@@ -1233,14 +1245,16 @@ class BatchVerifier:
         n = len(items)
         b = span.lanes = _bucket_for(n, self.buckets)
         # Packed single-upload form: one host->device transfer per
-        # dispatch instead of the 8-argument form's eight (per-dispatch
-        # host<->device cost, to be measured on the chip).
+        # dispatch, the lanes' comb-table rows included (ops/p256.py).
         t0 = time.perf_counter()
         staging = self._staging.acquire((b, p256.PACKED_COLS), np.uint16)
+        tables = p256.KeyTableTally()
         try:
             with span.phase("prep", span.PREP):
-                packed = p256.prepare_packed(items, b, out=staging)
-            self._note_prep("ecdsa_p256", b - n, time.perf_counter() - t0)
+                packed = p256.prepare_packed(items, b, out=staging, tally=tables)
+            self._note_prep(
+                "ecdsa_p256", b - n, time.perf_counter() - t0, tables
+            )
             if self.mesh is not None:
                 from . import mesh as mesh_mod
 

@@ -102,11 +102,23 @@ def sharded_verifier(scalar_verify: Callable, mesh: Mesh, n_args: int):
 def sharded_ecdsa_kernel(mesh: Mesh):
     """Batched ECDSA-P256 verify sharded across ``mesh`` — packed
     single-upload form ([B, PACKED_COLS] u16, see
-    :func:`minbft_tpu.ops.p256.pack_arrays`): the batch axis partitions
-    over the mesh; trailing columns replicate per lane."""
-    from ..ops import p256
+    :func:`minbft_tpu.ops.p256.prepare_packed`).  The kernel's batch axis
+    is explicit (it runs its two combs as one chain over 2B chain-lanes),
+    so each device runs the whole kernel on its own B/mesh lanes under
+    ``shard_map``: lanes are independent, nothing crosses chips."""
+    from ..ops import lowering, p256
 
-    return sharded_verifier(p256._verify_one_packed, mesh, 1)
+    return lowering.per_mode_jit(
+        jax.shard_map(
+            p256._verify_one_packed,
+            mesh=mesh,
+            in_specs=P(BATCH_AXIS),
+            out_specs=P(BATCH_AXIS),
+            # the field code starts its accumulators from constants, which
+            # the varying-axes typing would call replicated
+            check_vma=False,
+        )
+    )
 
 
 def hmac_row_verify(row):
@@ -141,7 +153,7 @@ def sharded_ecdsa_sign_kernel(mesh: Mesh):
 
     from ..ops import p256
 
-    table = jnp.asarray(p256._comb_table_np())
+    table = jnp.asarray(p256._COMB_TABLE_NP)
 
     def kg_one(k):
         return p256._kg_comb_one(k.astype(jnp.uint32), table)

@@ -467,6 +467,24 @@ class KeyStore:
     def client_pubs(self) -> Dict[int, object]:
         return {kid: self._decode_sig(self.client_keys, kid)[1] for kid in self.client_keys}
 
+    def ecdsa_p256_points(self) -> list:
+        """Every P-256 public key this store names, as ``(x, y)``: the
+        replicas' and clients' signature keys under the ``ecdsa-p256``
+        scheme, the USIG anchors under an ECDSA keyspec — all the keys an
+        engine's ECDSA queue can be asked to verify under
+        (``placement.prime_key_tables`` builds their comb tables)."""
+        points = []
+        if self.scheme == "ecdsa-p256":
+            points += list(self.replica_pubs().values())
+            points += list(self.client_pubs().values())
+        if self.usig_spec in ("NATIVE_ECDSA", "SOFT_ECDSA"):
+            points += [
+                (int.from_bytes(a[:32], "big"), int.from_bytes(a[32:], "big"))
+                for a in self.usig_anchors().values()
+                if len(a) == 64
+            ]
+        return points
+
     def replica_authenticator(
         self,
         replica_id: int,

@@ -543,6 +543,7 @@ async def _run_replica(args) -> int:
     # own the chip must never carry on with host crypto in silence.
     from .placement import (
         device_schemes,
+        prime_key_tables,
         replica_authenticator,
         replica_engine,
         warm_engines,
@@ -635,6 +636,8 @@ async def _run_replica(args) -> int:
     import time as _time
 
     t_warm = _time.monotonic()
+    if to_warm and "ecdsa_p256" in warm_schemes:
+        prime_key_tables(store)
     await warm_engines(to_warm, warm_schemes)
     if to_warm:
         print(

@@ -126,6 +126,20 @@ def device_schemes(store, mac: bool = False) -> Tuple[str, ...]:
     return tuple(sorted({usig, msg} & {"ecdsa_p256", "ed25519", "hmac_sha256"}))
 
 
+def prime_key_tables(store) -> None:
+    """Build the ECDSA verify kernel's comb table of every P-256 key
+    ``store`` names (ops/p256.py: ~10 ms and 64 KiB a key, cached for the
+    process), where an engine is built for it and before it serves: no
+    build then falls inside a request (an unprimed key is served once by a
+    host scalar multiplication and built on its second use).  Only the
+    store's keys can reach the ECDSA queue (the authenticator verifies
+    under the store's key for the claimed id, and a UI under the store's
+    USIG anchor), so after this a replica builds none."""
+    from ...ops import p256
+
+    p256.prime_key_tables(store.ecdsa_p256_points())
+
+
 async def warm_engine(engine, schemes=("ecdsa_p256",)) -> None:
     """One item signed and verified through each of ``engine``'s queues
     in ``schemes``: the kernels trace, compile (or load from the
@@ -263,8 +277,11 @@ async def start_local_cluster(
         stubs[i].assign_replica(r)
         engines.append(engine)
         replicas.append(r)
+    schemes = device_schemes(store)
+    if "ecdsa_p256" in schemes and any(e is not None for e in engines):
+        prime_key_tables(store)
     await warm_engines(
-        [e for e in engines if e is not None], device_schemes(store), replicas=n
+        [e for e in engines if e is not None], schemes, replicas=n
     )
     for r in replicas:
         await r.start()

@@ -113,7 +113,7 @@ class EcdsaUSIG(_BaseUSIG):
 
     Cert = epoch || r(32) || s(32); ID = epoch || x(32) || y(32).
     Public verification — batchable on TPU via
-    :func:`minbft_tpu.ops.p256.ecdsa_verify_kernel` (the TPU-USIG path
+    :func:`minbft_tpu.ops.p256.ecdsa_verify_kernel_packed` (the TPU-USIG path
     routes verification through the batching engine instead of calling
     :meth:`verify_ui` serially).
     """

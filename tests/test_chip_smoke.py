@@ -72,7 +72,8 @@ def test_engine_check_fails_on_host_fallback_and_timeouts():
     engine = BatchVerifier(max_batch=8)
     v = engine._queue("ecdsa_p256", engine._dispatch_ecdsa)
     s = engine._sign_queue("ecdsa_p256", engine._dispatch_sign_ecdsa)
-    v.stats, s.stats = VerifyStats(items=5, batches=1), SignStats(items=5, batches=1)
+    v.stats = VerifyStats(items=5, batches=1, key_table_hits=5)
+    s.stats = SignStats(items=5, batches=1)
     assert chip_smoke.check_engine_on_device("e", engine, {})["verify_items"] == 5
     # nothing since the baseline = the device did no protocol work
     with pytest.raises(chip_smoke.SmokeFailure, match="no ecdsa_p256 verify"):
@@ -85,6 +86,10 @@ def test_engine_check_fails_on_host_fallback_and_timeouts():
     with pytest.raises(chip_smoke.SmokeFailure, match="dispatch timeouts"):
         chip_smoke.check_engine_on_device("e", engine, {})
     v.stats.dispatch_timeouts = 0
+    v.stats.key_table_builds = 1  # a key the store's priming did not name
+    with pytest.raises(chip_smoke.SmokeFailure, match="were not primed"):
+        chip_smoke.check_engine_on_device("e", engine, {})
+    v.stats.key_table_builds = 0
     v._device_written_off = True
     assert engine.written_off() == ["ecdsa_p256"]
     with pytest.raises(chip_smoke.SmokeFailure, match="written off"):

@@ -286,6 +286,47 @@ def test_host_prep_time_populated_for_device_schemes():
     asyncio.run(run())
 
 
+def test_key_table_hits_and_builds_are_counted_and_exported():
+    """The ECDSA queue's per-key comb tables (ops/p256.py): an unprimed
+    key's second use inside a dispatch is one build, every later item
+    under it a hit; both are in ``engine.stats``, in the Prometheus
+    families next to ``host_prep_seconds_total`` and in the
+    MINBFT_TRACE_DUMP engine doc."""
+    from minbft_tpu.obs import critpath, prom
+    from minbft_tpu.ops import p256
+    from minbft_tpu.utils import hostcrypto as hc
+
+    d, q = hc.keygen()
+    digests = [hashlib.sha256(b"tab-%d" % i).digest() for i in range(5)]
+    sigs = [hc.ecdsa_sign(d, dg) for dg in digests]
+
+    async def run():
+        eng = BatchVerifier(max_batch=8, buckets=(8,))
+        assert await eng.verify_ecdsa_p256(q, digests[0], sigs[0])  # first use
+        st = eng.stats["ecdsa_p256"]
+        assert (st.key_table_hits, st.key_table_builds) == (0, 0)
+        assert await eng.verify_ecdsa_p256(q, digests[1], sigs[1])  # second: built
+        assert (st.key_table_hits, st.key_table_builds) == (0, 1)
+        assert 0.0 < st.key_table_build_s <= st.host_prep_time_s
+        oks = await asyncio.gather(
+            *[eng.verify_ecdsa_p256(q, dg, sg) for dg, sg in zip(digests[2:], sigs[2:])]
+        )
+        assert all(oks)
+        assert (st.key_table_hits, st.key_table_builds) == (3, 1)
+        return eng
+
+    p256._KEY_TABLES.clear()
+    eng = asyncio.run(run())
+    text = prom.render_families(prom._collect_engine(eng, {"replica": "0"}))
+    assert 'minbft_verify_queue_key_table_hits_total{queue="ecdsa_p256",replica="0"} 3' in text
+    assert 'minbft_verify_queue_key_table_builds_total{queue="ecdsa_p256",replica="0"} 1' in text
+    assert "minbft_verify_queue_key_table_build_seconds_total" in text
+    assert "minbft_sign_queue_key_table" not in text
+    doc = critpath.engine_queue_doc(eng)
+    assert doc["key_tables"]["ecdsa_p256"]["hits"] == 3
+    assert doc["key_tables"]["ecdsa_p256"]["builds"] == 1
+
+
 def test_padded_lane_accounting_is_thread_safe():
     """Regression pin for the padded_lanes data race: dispatchers run on
     worker threads (up to max_inflight concurrently) and used to do a bare

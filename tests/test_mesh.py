@@ -48,7 +48,7 @@ def test_sharded_ecdsa_kernel(mesh8, ecdsa_kernel):
     sig = hc.ecdsa_sign(d, digest)
     items = [(q, digest, sig)] * batch
     items[5] = (q, digest, (sig[0], sig[1] ^ 2))  # corrupted lane
-    packed = jnp.asarray(p256.pack_arrays(p256.prepare_batch(items)))
+    packed = jnp.asarray(p256.prepare_packed(items, batch))
 
     out = np.asarray(ecdsa_kernel(packed))
 
@@ -90,7 +90,7 @@ def test_sharded_output_matches_host(mesh8, ecdsa_kernel):
             sig = (sig[0], sig[1] ^ 1)
         items.append((q, digest, sig))
         expected.append(hc.ecdsa_verify(q, digest, sig))
-    packed = jnp.asarray(p256.pack_arrays(p256.prepare_batch(items)))
+    packed = jnp.asarray(p256.prepare_packed(items, batch))
     out = np.asarray(ecdsa_kernel(packed))
     assert out.tolist() == expected
 

@@ -108,3 +108,26 @@ def test_warm_engines_settles_the_collector_for_the_replicas_it_is_told():
     asyncio.run(placement.warm_engines([], replicas=31))
     assert gc.get_threshold() == placement.collector_thresholds(31)
     assert obs_trace.timeline()["gc"]["thresholds"] == [434_000, 2, 10]
+
+
+@pytest.mark.parametrize("scheme, usig_spec, points", [
+    ("ecdsa-p256", "SOFT_ECDSA", 3 + 2 + 3),  # replicas, clients, USIG anchors
+    ("ecdsa-p256", "HMAC_SHA256", 3 + 2),
+    ("ed25519", "SOFT_ECDSA", 3),
+    ("ed25519", "HMAC_SHA256", 0),
+])
+def test_prime_key_tables_builds_every_p256_key_the_store_names(scheme, usig_spec, points):
+    """Only the store's keys can reach an engine's ECDSA queue, and their
+    comb tables are built before a replica serves: after priming, every
+    one of them is a hit."""
+    from minbft_tpu.ops import p256
+    from minbft_tpu.sample.authentication import generate_testnet_keys
+
+    store = generate_testnet_keys(3, n_clients=2, scheme=scheme, usig_spec=usig_spec)
+    keys = store.ecdsa_p256_points()
+    assert len(keys) == len(set(keys)) == points
+    assert all(p256.is_on_curve(*k) for k in keys)
+    p256._KEY_TABLES.clear()
+    placement.prime_key_tables(store)
+    assert len(p256._KEY_TABLES) == points
+    assert p256.prime_key_tables(keys).builds == 0

@@ -63,14 +63,42 @@ def _assert_p256_parity(items):
     for i, (x, y) in enumerate(zip(a, b)):
         assert x.dtype == y.dtype, f"array {i} dtype"
         assert np.array_equal(x, y), f"array {i} diverged"
-    bucket = len(items) + 3
-    packed = p256.prepare_packed(items, bucket)
-    assert np.array_equal(p256.pack_arrays(a), packed[: len(items)])
-    assert not packed[len(items) :].any(), "pad lanes not zeroed"
+    _assert_p256_packed(items, a)
+
+
+def _assert_p256_packed(items, arrays):
+    """The engine's staging write against the oracle's arrays: the scalar
+    columns bit for bit, ``valid`` narrowed to keys ON the curve, and for
+    every valid lane the 64 rows of its key's comb table that u2's nibbles
+    select.  Pad lanes: scalars and flags zero (their rows are unspecified)."""
+    _qx, _qy, u1, u2, rr, r2, r2_ok, valid = arrays
+    n, L, c = len(items), limbs.NLIMBS, p256._Q_COLS
+    p256.prime_key_tables(  # else a key's first use carries u2*Q, not table rows
+        [item[0] for item, v in zip(items, valid) if v and p256.is_on_curve(*item[0])]
+    )
+    packed = p256.prepare_packed(items, n + 3)
+    assert packed.shape == (n + 3, p256.PACKED_COLS) and packed.dtype == np.uint16
+    for k, arr in enumerate((u1, u2, rr, r2)):
+        assert np.array_equal(packed[:n, c + k * L : c + (k + 1) * L], arr), f"scalar {k}"
+    assert np.array_equal(packed[:n, c + 4 * L], r2_ok)
+    on_curve = np.array(
+        [bool(v) and p256.is_on_curve(*items[i][0]) for i, v in enumerate(valid)], bool
+    )
+    assert np.array_equal(packed[:n, c + 4 * L + 1], on_curve)
+    for i in np.flatnonzero(on_curve):
+        nib = (u2[i][:, None] >> (4 * np.arange(4, dtype=np.uint32))) & 0xF
+        want = p256.comb_table(items[i][0])[np.arange(64), nib.reshape(64)]
+        assert np.array_equal(packed[i, :c], want.reshape(c)), f"lane {i} rows"
+    assert not packed[n:, c:].any(), "pad lanes not zeroed"
+
+
+_REAL_KEYS = [hc.scalar_mult(d, (hc.GX, hc.GY)) for d in (1, 2, 0xC0FFEE)]
 
 
 def _fuzz_p256_items(rng, n):
-    """Mix of plausible lanes, boundary values, and garbage."""
+    """Mix of plausible lanes, boundary values, and garbage; one plausible
+    lane in four is under a real key (a point of the curve, so it has
+    comb-table rows)."""
     boundary = [
         0, 1, 2,
         p256.N - 1, p256.N, p256.N + 1,
@@ -90,7 +118,8 @@ def _fuzz_p256_items(rng, n):
         if shape == 0:  # plausible in-range lane
             items.append(
                 (
-                    (rng.randrange(p256.P), rng.randrange(p256.P)),
+                    rng.choice(_REAL_KEYS) if rng.randrange(4) == 0
+                    else (rng.randrange(p256.P), rng.randrange(p256.P)),
                     rng.randbytes(32),
                     (rng.randrange(1, p256.N), rng.randrange(1, p256.N)),
                 )
@@ -98,7 +127,8 @@ def _fuzz_p256_items(rng, n):
         elif shape == 1:  # second-candidate window: r < p - n
             items.append(
                 (
-                    (rng.randrange(p256.P), rng.randrange(p256.P)),
+                    rng.choice(_REAL_KEYS) if rng.randrange(4) == 0
+                    else (rng.randrange(p256.P), rng.randrange(p256.P)),
                     rng.randbytes(32),
                     (rng.randrange(1, p256.P - p256.N), rng.randrange(1, p256.N)),
                 )
@@ -358,10 +388,8 @@ def test_prep_speedup_at_least_5x():
         )
         for _ in range(B)
     ]
-    assert np.array_equal(
-        p256.pack_arrays(p256.prepare_batch(items)),
-        p256.pack_arrays(p256.prepare_batch_scalar(items)),
-    )
+    for vec, ref in zip(p256.prepare_batch(items), p256.prepare_batch_scalar(items)):
+        assert np.array_equal(vec, ref)
 
     def best_of(fn, n=3):
         best = float("inf")
