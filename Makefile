@@ -17,10 +17,12 @@
 #   make fast        native + lint + the unit tier of the test suite (<2min)
 #   make check       native + lint + gate + the FULL test suite (~9min,
 #                    what CI runs)
-#   make gate        bench regression gate (tools/benchgate): the working
-#                    tree's BENCH_extras.json vs the committed
-#                    perf/BENCH_baseline.json, stddev-aware, hard-refusing
-#                    cross-backend comparisons (tpu_unavailable caution)
+#   make gate        tools/benchgate over two committed CPU-container
+#                    artifacts (BENCH_extras.json vs
+#                    perf/BENCH_baseline.json); nothing produces them any
+#                    more, and it says nothing about this system's speed
+#                    (that is BENCHMARK.json + `python3 -m benchmark.run`
+#                    on the chip; ROADMAP.md Queue 3)
 #   make check-race  race tier (VERDICT #5): native usig_test rebuilt and
 #                    run under ThreadSanitizer (concurrent certification
 #                    hammer); skips with a notice if the toolchain lacks
@@ -29,14 +31,13 @@
 #   make chaos       the seeded chaos suite (tests/test_chaos.py) under
 #                    PYTHONDEVMODE=1 + faulthandler; export
 #                    MINBFT_CHAOS_SEED to replay a failed schedule
-#   make bench       the driver's bench entry point (real TPU)
 #
 # Tests force the CPU backend with 8 virtual devices via tests/conftest.py.
 
 PY ?= python
 CXX ?= g++
 
-.PHONY: native lint gate fast check check-race chaos test bench clean
+.PHONY: native lint gate fast check check-race chaos test clean
 
 native:
 	$(MAKE) -C minbft_tpu/native
@@ -70,12 +71,12 @@ chaos:
 # from the run so a real linter FAILURE fails the target (an
 # `a && b || c` chain would swallow it).
 lint:
-	$(PY) -m compileall -q minbft_tpu tests bench.py chip_smoke.py __graft_entry__.py
+	$(PY) -m compileall -q minbft_tpu tests chip_smoke.py __graft_entry__.py
 	$(PY) -m tools.analyze
 	@if $(PY) -c "import ruff" 2>/dev/null; then \
-	    $(PY) -m ruff check minbft_tpu tests bench.py chip_smoke.py __graft_entry__.py; \
+	    $(PY) -m ruff check minbft_tpu tests chip_smoke.py __graft_entry__.py; \
 	elif $(PY) -c "import pyflakes" 2>/dev/null; then \
-	    $(PY) -m pyflakes minbft_tpu tests bench.py chip_smoke.py __graft_entry__.py; \
+	    $(PY) -m pyflakes minbft_tpu tests chip_smoke.py __graft_entry__.py; \
 	else \
 	    echo "ruff/pyflakes not installed; tools/analyze dead-code pass is the floor"; \
 	fi
@@ -92,10 +93,8 @@ fast: native lint
 	    --ignore=tests/test_soak_bounded.py \
 	    --ignore=tests/test_stress_concurrent.py
 
-# Bench regression gate: the committed artifacts must stay in-band.
-# Deterministic (both inputs are committed files), so CI cannot flake
-# here — a failure means a regenerated artifact actually regressed, or
-# someone tried to gate across backend kinds (hard refusal, rc=2).
+# The committed artifacts must stay in-band.  Deterministic (both inputs
+# are committed files); cross-backend comparisons are refused (rc=2).
 gate:
 	$(PY) -m tools.benchgate
 
@@ -103,9 +102,6 @@ check: native lint gate
 	$(PY) -m pytest tests/ -q
 
 test: check
-
-bench:
-	$(PY) bench.py
 
 clean:
 	$(MAKE) -C minbft_tpu/native clean 2>/dev/null || true

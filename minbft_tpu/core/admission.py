@@ -25,14 +25,10 @@ must not be able to convert shed work into sign work: a token bucket
 bounds BUSY emission; beyond the budget sheds stay silent (counted as
 ``admission_busy_suppressed``) and the client's plain retransmit ladder
 carries the backoff.
-
-``MINBFT_ADMISSION=0`` reverts to the pre-ISSUE-15 blocking submit (the
-A/B lever: backpressure-only vs shed-and-signal).
 """
 
 from __future__ import annotations
 
-import os
 import time
 
 from ..messages import Busy, Message, Request, marshal
@@ -50,22 +46,12 @@ _BUSY_BURST = 200
 _RETRY_MIN_MS = 25
 _RETRY_MAX_MS = 1000
 
-_ADMISSION_ENV = "MINBFT_ADMISSION"
-
-
-def admission_enabled() -> bool:
-    return os.environ.get(_ADMISSION_ENV, "").lower() not in (
-        "0", "false", "no",
-    )
-
-
 class AdmissionController:
     """Shed-and-signal submit wrapper for ONE client stream.
 
     Concurrency: confined to the owning stream's event-loop tasks (the
-    ingest tick loop and the per-frame fallback path call submit; nothing
-    else touches the instance) — same confinement contract as
-    ``_BundleIngestor``.
+    ingest tick loop calls submit_msg; nothing else touches the
+    instance) — same confinement contract as ``_BundleIngestor``.
     """
 
     def __init__(self, handlers, proc, out_queue, wrap=None):
@@ -78,24 +64,10 @@ class AdmissionController:
         self._tokens = float(_BUSY_BURST)
         self._refill_at = time.monotonic()
 
-    # -- submit paths (bundle ingest / per-frame fallback) ------------------
+    # -- submit (bundle ingest) ---------------------------------------------
 
     async def submit_msg(self, msg: Message) -> None:
         if await self._proc.try_submit_msg(msg):
-            return
-        await self._shed(msg)
-
-    async def submit(self, data: bytes) -> None:
-        if await self._proc.try_submit(data):
-            return
-        # Decode only on the shed path (the happy path stays zero-copy):
-        # a BUSY needs the request's client/seq attribution.
-        from ..messages import unmarshal
-
-        try:
-            msg = unmarshal(data)
-        except Exception:
-            self._handlers.metrics.inc("admission_shed")
             return
         await self._shed(msg)
 

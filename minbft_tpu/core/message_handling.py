@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import asyncio
 import logging
-import os
 import time
 from collections import OrderedDict
 from typing import AsyncIterator, Dict, Optional
@@ -235,16 +234,8 @@ class Handlers:
         # core/commit.go:74-92; this memo preserves its exact semantics.)
         self._verified: "OrderedDict[tuple, None]" = OrderedDict()
         self._verified_cap = 4 * 4096
-        # dedup_verify=False (measurement mode, set via the configer)
-        # disables this memo so every embedded re-validation actually
-        # reaches the authenticator/engine — the reference's O(n²)
-        # re-verification behavior, used by the bench's no-dedup phase to
-        # report honest protocol-driven device verification rates.
-        self._dedup_verify = getattr(configer, "dedup_verify", True)
 
         def _verified_hit(key: tuple) -> bool:
-            if not self._dedup_verify:
-                return False
             cache = self._verified
             if key in cache:
                 cache.move_to_end(key)
@@ -252,8 +243,6 @@ class Handlers:
             return False
 
         def _verified_put(key: tuple) -> None:
-            if not self._dedup_verify:
-                return
             cache = self._verified
             cache[key] = None
             if len(cache) > self._verified_cap:
@@ -756,14 +745,7 @@ class Handlers:
         engine round trips, requests reached the primary's proposer in
         bundle-sized groups, and PREPAREs shrank — more USIG signing
         (serial by design) and thinner UI-verify batches.
-
-        Skipped entirely (returns 0) in the no-dedup measurement mode:
-        with the engine's in-flight coalescing off, every seeded check
-        would occupy a SECOND device lane and the reported device rate
-        would no longer equal protocol demand.
         """
-        if not self._dedup_verify:
-            return 0
         if not getattr(self.authenticator, "supports_batch_verify", False):
             # No engine behind the batch surface: a seed would verify
             # everything twice on the serial loop for no coalescing win.
@@ -2231,17 +2213,9 @@ class _ConcurrentStreamProcessor:
         self._sem = asyncio.Semaphore(_STREAM_CONCURRENCY)
         self._tasks: set = set()
 
-    async def submit(self, data: bytes) -> None:
-        await self._sem.acquire()
-        task = asyncio.get_running_loop().create_task(self._run(data, None))
-        self._tasks.add(task)
-        task.add_done_callback(self._tasks.discard)
-
     async def submit_msg(self, msg: Message) -> None:
         await self._sem.acquire()
-        task = asyncio.get_running_loop().create_task(self._run(None, msg))
-        self._tasks.add(task)
-        task.add_done_callback(self._tasks.discard)
+        self._start(msg)
 
     async def try_submit_msg(self, msg: Message) -> bool:
         """Non-blocking :meth:`submit_msg`: False when the concurrency
@@ -2256,26 +2230,16 @@ class _ConcurrentStreamProcessor:
         if self._sem.locked():
             return False
         await self._sem.acquire()
-        task = asyncio.get_running_loop().create_task(self._run(None, msg))
-        self._tasks.add(task)
-        task.add_done_callback(self._tasks.discard)
+        self._start(msg)
         return True
 
-    async def try_submit(self, data: bytes) -> bool:
-        """Non-blocking :meth:`submit` (the grouped per-frame fallback
-        path's variant of :meth:`try_submit_msg`)."""
-        if self._sem.locked():
-            return False
-        await self._sem.acquire()
-        task = asyncio.get_running_loop().create_task(self._run(data, None))
+    def _start(self, msg: Message) -> None:
+        task = asyncio.get_running_loop().create_task(self._run(msg))
         self._tasks.add(task)
         task.add_done_callback(self._tasks.discard)
-        return True
 
-    async def _run(self, data: Optional[bytes], msg: Optional[Message]) -> None:
+    async def _run(self, msg: Message) -> None:
         try:
-            if msg is None:
-                msg = unmarshal(data)
             await self._handle(msg)
             if self._on_success is not None:
                 self._on_success()
@@ -2304,29 +2268,13 @@ class _ConcurrentStreamProcessor:
             t.cancel()
 
 
-# Bundle-ingest knobs.  MINBFT_BUNDLE_INGEST=0 reverts every stream pump
-# to the per-frame-task path (the A/B lever perf/BATCH_RUNTIME.md uses);
-# MINBFT_INGEST_MAX bounds the flat frames drained into one tick's bundle
-# (the bench's ingest-batch-size sweep axis).  Read per stream setup, so
-# tests and the bench sweep can toggle without reimporting.
-_BUNDLE_ENV = "MINBFT_BUNDLE_INGEST"
-_INGEST_MAX_ENV = "MINBFT_INGEST_MAX"
 # Transport frames buffered between the stream pump and the tick loop:
 # when full, the pump's put() blocks and the transport sees backpressure
 # (the same role the submit semaphore plays for in-flight tasks).
 _INGEST_RX_BOUND = 256
+# Flat frames drained into one tick's bundle, at most.
+_INGEST_MAX_FRAMES = 1024
 _INGEST_EOF = object()
-
-
-def bundle_ingest_enabled() -> bool:
-    return os.environ.get(_BUNDLE_ENV, "").lower() not in ("0", "false", "no")
-
-
-def _ingest_max_frames() -> int:
-    try:
-        return max(1, int(os.environ.get(_INGEST_MAX_ENV, "1024")))
-    except ValueError:
-        return 1024
 
 
 class _BundleIngestor:
@@ -2359,13 +2307,11 @@ class _BundleIngestor:
         on_error,
         submit,
         preverify=None,
-        max_frames: Optional[int] = None,
     ):
         self._handlers = handlers
         self._on_error = on_error
         self._submit = submit  # async callable(Message)
         self._preverify = preverify  # sync callable(list[Message]) -> int
-        self._max_frames = max_frames or _ingest_max_frames()
         self._rx: asyncio.Queue = asyncio.Queue(maxsize=_INGEST_RX_BOUND)
         self._eof_pending = False
 
@@ -2429,7 +2375,7 @@ class _BundleIngestor:
             flat: list = []
             self._split_into(data, flat)
             saw_eof = False
-            while len(flat) < self._max_frames and not rx.empty():
+            while len(flat) < _INGEST_MAX_FRAMES and not rx.empty():
                 nxt = rx.get_nowait()
                 if nxt is _INGEST_EOF:
                     saw_eof = True
@@ -2581,28 +2527,15 @@ class PeerStreamHandler(api.MessageStreamHandler):
             h.log.warning("dropping peer message: %s", e)
 
         proc = _ConcurrentStreamProcessor(h.handle_peer_message, _drop_peer)
-
-        async def consume_incoming() -> None:
-            if bundle_ingest_enabled():
-                # Peer bundles batch the DECODE (vectorized, item-wise
-                # errors) and the per-tick drain; validation stays
-                # per-message — PREPARE/COMMIT checks are UI-certificate
-                # work that already co-batches across the concurrent
-                # handler tasks.
-                await _BundleIngestor(h, _drop_peer, proc.submit_msg).run(
-                    in_stream
-                )
-                return
-            async for data in in_stream:
-                try:
-                    frames = split_multi(data)
-                except CodecError as e:
-                    _drop_peer(e)
-                    continue
-                for fr in frames:
-                    await proc.submit(fr)
-
-        tasks.append(loop.create_task(consume_incoming()))
+        # Peer bundles batch the DECODE (vectorized, item-wise errors)
+        # and the per-tick drain; validation stays per-message —
+        # PREPARE/COMMIT checks are UI-certificate work that already
+        # co-batches across the concurrent handler tasks.
+        tasks.append(
+            loop.create_task(
+                _BundleIngestor(h, _drop_peer, proc.submit_msg).run(in_stream)
+            )
+        )
 
         try:
             while True:
@@ -2671,36 +2604,21 @@ class ClientStreamHandler(api.MessageStreamHandler):
         # bound is exhausted, shed with a signed BUSY on out_queue instead
         # of blocking the ingest tick (open-loop offered load would wedge
         # the rx queue at its bound while the generator keeps pushing).
-        # MINBFT_ADMISSION=0 reverts to the blocking backpressure path.
-        if admission_mod.admission_enabled():
-            adm = admission_mod.AdmissionController(h, proc, out_queue)
-            submit_msg, submit_frame = adm.submit_msg, adm.submit
-        else:
-            submit_msg, submit_frame = proc.submit_msg, proc.submit
+        adm = admission_mod.AdmissionController(h, proc, out_queue)
 
         async def consume() -> None:
-            if bundle_ingest_enabled():
-                # Bundle-ingest hot path: drain everything buffered per
-                # tick, decode it as ONE vectorized batch, seed the
-                # engine with the bundle's signature checks in one call,
-                # then fan out in arrival order (the _TurnSequencer
-                # tickets are issued in fan-out order, so the ordering
-                # boundary is unchanged).
-                await _BundleIngestor(
-                    h,
-                    _drop_client,
-                    submit_msg,
-                    preverify=h.preverify_requests,
-                ).run(in_stream)
-            else:
-                async for data in in_stream:
-                    try:
-                        frames = split_multi(data)
-                    except CodecError as e:
-                        _drop_client(e)
-                        continue
-                    for fr in frames:
-                        await submit_frame(fr)
+            # Bundle-ingest hot path: drain everything buffered per
+            # tick, decode it as ONE vectorized batch, seed the
+            # engine with the bundle's signature checks in one call,
+            # then fan out in arrival order (the _TurnSequencer
+            # tickets are issued in fan-out order, so the ordering
+            # boundary is unchanged).
+            await _BundleIngestor(
+                h,
+                _drop_client,
+                adm.submit_msg,
+                preverify=h.preverify_requests,
+            ).run(in_stream)
             await proc.drain()
             await out_queue.put(FIN)
 
@@ -2863,7 +2781,6 @@ async def run_peer_connection(
     peer_state = handlers.peer_states.peer(peer_id)
 
     backoff = ReconnectBackoff()
-    ingest = bundle_ingest_enabled()
     while not done.is_set():
         proc = _ConcurrentStreamProcessor(handlers.handle_peer_message, _drop, _ok)
         attempt_start = time.monotonic()
@@ -2948,23 +2865,18 @@ async def run_peer_connection(
                 except CodecError as e:
                     _drop(e)
                     continue
-                if ingest:
-                    # The publisher's drain_multi already coalesced this
-                    # frame into a bundle — decode it as one vectorized
-                    # batch (item-wise errors) and fan the typed messages
-                    # out, instead of spawning a decode task per frame.
-                    # (The dial loop keeps its own watchdog-raced read
-                    # structure, so the rx-queue tick loop is not used
-                    # here.)
-                    handlers.metrics.observe_ingest(len(frames))
-                    for m in unmarshal_batch(frames):
-                        if isinstance(m, CodecError):
-                            _drop(m)
-                        else:
-                            await proc.submit_msg(m)
-                else:
-                    for fr in frames:
-                        await proc.submit(fr)
+                # The publisher's drain_multi already coalesced this
+                # frame into a bundle — decode it as one vectorized
+                # batch (item-wise errors) and fan the typed messages
+                # out.  (The dial loop keeps its own watchdog-raced read
+                # structure, so the rx-queue tick loop is not used
+                # here.)
+                handlers.metrics.observe_ingest(len(frames))
+                for m in unmarshal_batch(frames):
+                    if isinstance(m, CodecError):
+                        _drop(m)
+                    else:
+                        await proc.submit_msg(m)
                 if _gap_wedged():
                     handlers.metrics.inc("gap_redials")
                     handlers.log.warning(

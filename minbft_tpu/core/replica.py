@@ -197,9 +197,9 @@ class _Replica(api.Replica):
         await asyncio.gather(*self._tasks, return_exceptions=True)
         self._tasks.clear()
         # JSON trace dump on shutdown (no-op unless MINBFT_TRACE_DUMP is
-        # set): one file per replica, bench.py ingests them.  A crash
-        # dump may already exist — this overwrites it with the complete
-        # ring (same path, fuller data).
+        # set): one file per replica (obs/trace.py::load_dumps reads
+        # them back).  A crash dump may already exist — this overwrites
+        # it with the complete ring (same path, fuller data).
         self.dump_trace()
 
 

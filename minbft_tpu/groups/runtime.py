@@ -36,12 +36,11 @@ import logging
 from typing import AsyncIterator, Dict, List, Optional, Set, Tuple
 
 from .. import api
-from ..core.admission import AdmissionController, admission_enabled
+from ..core.admission import AdmissionController
 from ..core.message_handling import (
     _BundleIngestor,
     _ConcurrentStreamProcessor,
     _TurnSequencer,
-    bundle_ingest_enabled,
 )
 from ..core.replica import _Replica
 from ..messages import (
@@ -598,15 +597,9 @@ class _GroupBundleIngestor(_BundleIngestor):
                 # processor sheds its own messages (client retransmission
                 # heals), the shared tick loop keeps draining the other
                 # groups — the isolation contract, at the handler layer.
-                # With admission control on, the shed is signaled (signed
-                # group-tagged BUSY) instead of silent.
-                if st.adm is not None:
-                    await st.adm.submit_msg(m)
-                elif not await st.proc.try_submit_msg(m):
-                    st.h.metrics.inc("messages_dropped")
-                    st.h.log.warning(
-                        "group processor saturated, dropping client message"
-                    )
+                # The shed is signaled (signed group-tagged BUSY), not
+                # silent.
+                await st.adm.submit_msg(m)
 
 
 class _GroupClientState:
@@ -676,15 +669,11 @@ class _GroupedClientStreamHandler(api.MessageStreamHandler):
                     _h.log.warning("dropping client message: %s", e)
 
                 st.proc = _ConcurrentStreamProcessor(handle_one, _drop)
-                st.adm = (
-                    AdmissionController(
-                        st.h,
-                        st.proc,
-                        out_queue,
-                        wrap=lambda b, _gid=gid: pack_group(_gid, b),
-                    )
-                    if admission_enabled()
-                    else None
+                st.adm = AdmissionController(
+                    st.h,
+                    st.proc,
+                    out_queue,
+                    wrap=lambda b, _gid=gid: pack_group(_gid, b),
                 )
                 states[gid] = st
             return st
@@ -696,33 +685,7 @@ class _GroupedClientStreamHandler(api.MessageStreamHandler):
             rt.log.warning("dropping client frame: %s", e)
 
         async def consume() -> None:
-            if bundle_ingest_enabled():
-                await _GroupBundleIngestor(rt, state, _drop_stream).run(
-                    in_stream
-                )
-            else:
-                async for data in in_stream:
-                    try:
-                        frames = split_multi(data)
-                    except CodecError as e:
-                        _drop_stream(e)
-                        continue
-                    for fr in frames:
-                        try:
-                            gid, inner = split_group(fr)
-                            sub = split_multi(inner)
-                        except CodecError as e:
-                            _drop_stream(e)
-                            continue
-                        st = state(gid)
-                        if st is not None:
-                            for one in sub:
-                                # same drop-on-saturation isolation
-                                # contract as the bundle path above
-                                if st.adm is not None:
-                                    await st.adm.submit(one)
-                                elif not await st.proc.try_submit(one):
-                                    st.h.metrics.inc("messages_dropped")
+            await _GroupBundleIngestor(rt, state, _drop_stream).run(in_stream)
             for st in states.values():
                 if st is not None:
                     await st.proc.drain()

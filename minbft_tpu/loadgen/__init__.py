@@ -2,8 +2,8 @@
 bounded-connection traffic generator measuring latency from SCHEDULED
 arrival time, and the replay-census faithfulness contract.
 
-See ``perf/LOAD.md`` for the methodology and ``peer load`` /
-``bench.py bench_load`` for the entry points.
+README §Load testing has the methodology; ``peer load`` is the entry
+point.
 """
 
 from .arrivals import (

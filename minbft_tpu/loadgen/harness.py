@@ -527,8 +527,9 @@ class OpenLoopGenerator:
         per-request finality from the SCHEDULED arrival, keyed
         ``"cid:seq"``.  Feeding this alongside replica trace dumps
         upgrades breach classification from recv-origin to
-        scheduled-origin (the coordinated-omission rule of perf/LOAD.md
-        applied to the forensics path, not just the percentile path)."""
+        scheduled-origin (the coordinated-omission rule, README §Load
+        testing, applied to the forensics path, not just the percentile
+        path)."""
         sched_lat_ns = {}
         for p in self._resolved:
             cid, seq = p.key  # (client_id, seq) — a public identity pair

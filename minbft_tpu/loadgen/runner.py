@@ -1,6 +1,5 @@
 """Local-cluster load runs (ISSUE 15): one entry point shared by
-``peer load``, ``bench.py bench_load``, the CI load-smoke step, and the
-tests.
+``peer load``, the CI load-smoke step, and the tests.
 
 Stands up an in-process n-replica cluster whose CLIENT traffic rides
 REAL loopback TCP (``TcpReplicaServer`` in front of each replica;
@@ -146,8 +145,7 @@ async def run_local_load(
         n=n,
         f=f,
         # Steady-state measurement: an overloaded-but-shedding replica
-        # must not detonate a view-change cascade mid-run (the bench
-        # convention; see bench.py _bench_cluster).
+        # must not detonate a view-change cascade mid-run.
         timeout_request=900.0,
         timeout_prepare=450.0,
         batchsize_prepare=batchsize_prepare,

@@ -6,7 +6,8 @@ instrumentation is leveled logging):
 
 - :mod:`~minbft_tpu.obs.trace` — per-request stage spans into
   preallocated ring buffers, with per-stage log2 histograms and the
-  JSON trace dump (``MINBFT_TRACE_DUMP=path``) bench.py ingests;
+  JSON trace dump (``MINBFT_TRACE_DUMP=path``) that ``load_dumps``,
+  the critical-path merge and ``peer slo --dumps`` read;
 - :mod:`~minbft_tpu.obs.hist` — fixed-bucket mergeable latency
   histograms (the streaming counterpart of the exact-but-unmergeable
   :class:`~minbft_tpu.utils.metrics.LatencyReservoir`), with negative
@@ -29,8 +30,7 @@ instrumentation is leveled logging):
 - :mod:`~minbft_tpu.obs.ledger` — the device-utilization ledger: busy
   vs idle wall-seconds per engine queue, lanes classed useful /
   padding / memo-duplicate / host-fallback, and the multiplicative
-  headroom decomposition against a calibrated per-backend ceiling
-  (perf/UTILIZATION.md);
+  headroom decomposition against a calibrated per-backend ceiling;
 - :mod:`~minbft_tpu.obs.runinfo` — per-incarnation ``RUN_ID`` and the
   ``minbft_build_info`` attribution block every dump and exposition
   carries;

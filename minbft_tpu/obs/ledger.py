@@ -21,9 +21,9 @@ The headline is the multiplicative headroom identity
     effective_rate = ceiling × busy_fraction × fill_efficiency × useful_fraction
 
 where ``ceiling`` is the CALIBRATED full-batch lane rate for the
-backend (a one-shot probe on the warm queue of the run's own device,
-stamped ``probe:<platform>`` — bench.py supplies it), and the three
-factors are defined so the
+backend (the caller supplies it with its provenance, e.g. a one-shot
+probe on the warm queue of the run's own device stamped
+``probe:<platform>``), and the three factors are defined so the
 product is EXACT, not approximate:
 
 - ``busy_fraction  = busy_s / wall_s``              (idle loses the rest)
@@ -39,8 +39,7 @@ product is EXACT, not approximate:
 
 so ``ceiling × busy × fill × useful ≡ useful_lanes / wall_s`` — the
 factor-product invariant tests/test_ledger.py pins to fp tolerance.
-Reading it is perf/UTILIZATION.md's job; emitting it into the bench
-artifact (``*_util_*`` keys) is bench.py's.
+:meth:`DeviceLedger.util_keys` flattens it into ``*_util_*`` keys.
 
 Multichip readiness: the ledger carries ``n_devices`` (the engine's
 mesh width) and reports per-device rates alongside the pooled ones, so
@@ -247,9 +246,8 @@ class DeviceLedger:
 
     def util_keys(self, prefix: str, queue: str,
                   now: Optional[float] = None) -> Dict[str, object]:
-        """The bench-artifact key block for one queue: the decomposition
-        factors, the lane classes, and the provenance stamps — the
-        ``*_util_*`` schema bench.py documents and benchgate gates."""
+        """The flat key block for one queue: the decomposition factors,
+        the lane classes, and the provenance stamps (``*_util_*``)."""
         wins = self.snapshot(now=now)
         win = wins.get(f"verify:{queue}") or wins.get(f"sign:{queue}")
         if win is None:
@@ -290,7 +288,7 @@ class PoolLedger:
       across the whole pool, with mean-across-chips busy semantics (a
       striped dispatch occupies every chip for its span, so its busy
       seconds weigh ``chips``×);
-    - :meth:`util_keys` — the bench-artifact block: per-chip
+    - :meth:`util_keys` — the flat key block: per-chip
       ``{prefix}_chip{c}_util_busy``/``_util_fill`` + lane census, and
       the POOL-AGGREGATE block in the exact :meth:`DeviceLedger.util_keys`
       schema, where the aggregate ceiling is the per-chip ceiling ×

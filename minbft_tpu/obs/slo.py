@@ -44,7 +44,7 @@ client recorders) falls back to recv-origin paths built from the
 replica stages alone.  When a load-generator metadata doc is present
 (``kind: "loadgen"``, written by the open-loop harness), classification
 switches to SCHEDULED-origin latencies — the coordinated-omission rule
-from perf/LOAD.md — and the pre-entry wait is attributed to an explicit
+of README §Load testing — and the pre-entry wait is attributed to an explicit
 ``sched_wait`` segment, so per-request segments still sum exactly to
 the classified spend (the invariant tests/test_slo.py pins).
 """

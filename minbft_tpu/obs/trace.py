@@ -49,9 +49,9 @@ from .hist import Log2Histogram
 
 # Replica capture points, in pipeline order.  ``ingest`` is the
 # bundle-runtime entry (the tick that decoded this request's frame
-# bundle); ``recv`` is the legacy per-task entry (MINBFT_BUNDLE_INGEST=0)
+# bundle); ``recv`` is the per-message entry (``handle_client_message``)
 # — both are ENTRY stages (they open spans, never record durations), so
-# retransmit gaps can't pollute the cost table on either path.
+# retransmit gaps can't pollute the cost table.
 REPLICA_STAGES: Tuple[str, ...] = (
     "ingest",
     "recv",

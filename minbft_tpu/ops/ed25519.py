@@ -219,8 +219,7 @@ def prepare_batch_scalar(
     items: Sequence[Tuple[bytes, bytes, bytes]], bucket: int
 ) -> Tuple[np.ndarray, ...]:
     """Per-item reference prep — the differential ORACLE for the
-    vectorized :func:`prepare_batch` (kept verbatim, selectable via
-    MINBFT_SCALAR_PREP=1)."""
+    vectorized :func:`prepare_batch`, kept verbatim."""
     import hashlib
 
     b = bucket
@@ -278,8 +277,6 @@ def prepare_batch(
     (:func:`minbft_tpu.ops.limbs.batch_inv_host`).  Bit-identical to
     :func:`prepare_batch_scalar`.
     """
-    if limbs.SCALAR_PREP:
-        return prepare_batch_scalar(items, bucket)
     import hashlib
 
     b = bucket

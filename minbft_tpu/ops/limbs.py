@@ -37,7 +37,6 @@ data-parallel substrate.
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Tuple
 
 import numpy as np
@@ -76,12 +75,6 @@ def from_limbs(limbs) -> int:
 
 
 # --- whole-batch conversions (the vectorized host-prep substrate) ----------
-
-# Escape hatch shared by the p256/ed25519 prep paths: route prepare_batch
-# to the per-item scalar oracle (checked at call time, so tests can flip
-# it without re-importing).
-SCALAR_PREP = os.environ.get("MINBFT_SCALAR_PREP", "") == "1"
-
 
 def staging_out(out, bucket: int, cols: int, n: int) -> np.ndarray:
     """Validate (or allocate) a [bucket, cols] u16 staging buffer for a

@@ -212,8 +212,7 @@ def _add_complete(p: Point, q: Point) -> Point:
 #   (:func:`limbs.limbs_lt`) feeding the kernel's ``valid`` lanes.
 #
 # ``prepare_batch_scalar`` keeps the original per-item path bit-for-bit as
-# the differential oracle (tests assert packed-array identity) and as a
-# runtime escape hatch (MINBFT_SCALAR_PREP=1).
+# the differential oracle (tests assert packed-array identity).
 
 _ZERO128 = b"\x00" * 128  # one all-zero packed record (r | s | x | y)
 _N_WORDS = limbs.words_of(N)
@@ -226,8 +225,7 @@ def prepare_batch_scalar(
 ) -> Tuple[np.ndarray, ...]:
     """Per-item reference prep: one ``pow(s, -1, N)`` and six ``to_limbs``
     per lane.  The differential ORACLE for the vectorized
-    :func:`prepare_batch` — kept verbatim, selectable via
-    MINBFT_SCALAR_PREP=1."""
+    :func:`prepare_batch`, kept verbatim."""
     b = len(items)
     qx = np.zeros((b, limbs.NLIMBS), np.uint32)
     qy = np.zeros((b, limbs.NLIMBS), np.uint32)
@@ -266,8 +264,6 @@ def prepare_batch(
     the batch shape never changes.  Bit-identical to
     :func:`prepare_batch_scalar`.
     """
-    if limbs.SCALAR_PREP:
-        return prepare_batch_scalar(items)
     b = len(items)
     nl = limbs.NLIMBS
     if b == 0:

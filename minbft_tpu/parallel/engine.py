@@ -172,8 +172,7 @@ class VerifyStats:
     # Host share of the dispatch: time the worker thread spent preparing
     # and packing the batch (limb conversion, batch inversion, staging
     # writes) BEFORE the kernel call — so host_prep_time_s /
-    # device_time_s is the prep share of the dispatch's await (bench.py
-    # reports it as *_prep_share).
+    # device_time_s is the prep share of the dispatch's await.
     host_prep_time_s: float = 0.0
     # The ECDSA queue's per-key comb tables (ops/p256.py): items whose
     # key's table was cached, tables built inside a dispatch's prep (the
@@ -678,17 +677,6 @@ class _SchemeQueue(_DispatchQueue):
         return outs
 
     def _enqueue(self, item) -> "asyncio.Future | _Resolved":
-        if not self.engine.dedup:
-            # Measurement mode (round-4 verdict weak #1): every submission
-            # occupies its own device lane — no memo, no in-flight
-            # coalescing — so device traffic equals the protocol's logical
-            # verification demand.  Duplicate items in one batch resolve
-            # together on the first lane's pop (same pure-function verdict).
-            loop = asyncio.get_running_loop()
-            fut = loop.create_future()
-            self._inflight_futs.setdefault(item, []).append(fut)
-            self.pending.append((item, fut, time.monotonic_ns()))
-            return fut
         verdict = self._memo.get(item)
         if verdict is None:
             verdict = self._neg_memo.get(item)
@@ -720,14 +708,12 @@ class _SchemeQueue(_DispatchQueue):
                     fut.set_exception(e)
 
     def _resolve(self, batch, results, fell_back: bool) -> None:
-        dedup = self.engine.dedup
         for (it, _f, _t), ok in zip(batch, results):
             ok = bool(ok)
-            if dedup:
-                # Pure function: verdicts (both ways) are stable — but they
-                # age out of segregated LRUs so garbage cannot evict good.
-                memo = self._memo if ok else self._neg_memo
-                memo[it] = ok
+            # Pure function: verdicts (both ways) are stable — but they
+            # age out of segregated LRUs so garbage cannot evict good.
+            memo = self._memo if ok else self._neg_memo
+            memo[it] = ok
             for fut in self._inflight_futs.pop(it, ()):
                 if not fut.done():
                     fut.set_result(ok)
@@ -816,7 +802,6 @@ class BatchVerifier:
         max_inflight: int = 2,
         mesh=None,
         dispatch_timeout: float = 90.0,
-        dedup: bool = True,
         sign_on_device: Optional[bool] = None,
         device=None,
     ):
@@ -830,11 +815,6 @@ class BatchVerifier:
         # the backend initializes it); tests force True to exercise the
         # device path on CPU.
         self._sign_on_device = sign_on_device
-        # dedup=False is a MEASUREMENT mode: every logical verification
-        # occupies a device lane (no memo, no in-flight coalescing), so
-        # reported device verifies/s equals protocol demand — see
-        # _SchemeQueue.submit.  Production keeps dedup on.
-        self.dedup = dedup
         # Liveness net against a device fault: a device dispatch that
         # exceeds this many seconds (generous — the first dispatch gets
         # _FIRST_TIMEOUT_FACTOR times it for its cold compile) is

@@ -142,7 +142,7 @@ class _GroupEngine:
         return self._call("sign_ed25519", seed, msg)
 
     def __getattr__(self, name):
-        # stats / queue_depths / dedup / buckets / ... — read-side
+        # stats / queue_depths / buckets / ... — read-side
         # passthrough to the current home engine.
         return getattr(self._pool._engines[self._pool.home_chip(self.group)],
                        name)

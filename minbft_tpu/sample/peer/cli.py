@@ -226,7 +226,7 @@ def build_parser(options: dict | None = None) -> argparse.ArgumentParser:
         default=_opt("chips", 1, section="run"),
         help="home chips for the multi-device engine pool (grouped "
         "runtime only): each consensus group's verify/sign traffic is "
-        "placed on one chip's engine (perf/SHARDING.md §multi-chip).  "
+        "placed on one chip's engine (parallel/pool.py).  "
         "0 = all visible devices; clamps to the device count; 1 "
         "(default) = the single shared engine.  Ignored with --no-batch "
         "or on the CPU backend (same rule as --batch).",

@@ -2,7 +2,7 @@
 
 The crypto kernels are compile-dominated on cold processes (tens of
 seconds per kernel shape on the TPU, minutes on the CPU): every entry
-point (``chip_smoke.py``, ``bench.py``, ``peer run``, the tests) loads
+point (``chip_smoke.py``, ``benchmark/run.py``, ``peer run``, the tests) loads
 the executables an earlier process compiled instead of compiling them
 again.
 

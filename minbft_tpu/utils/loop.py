@@ -16,8 +16,8 @@ Knob: ``MINBFT_UVLOOP``
 
 Call :func:`maybe_enable_uvloop` BEFORE ``asyncio.run`` — it installs
 the event-loop policy, which only affects loops created afterwards.
-``peer run`` and bench.py both do; tests exercise both loops via the
-same knob (tests/conftest.py, CI's uvloop step).
+The ``peer`` CLI does; tests exercise both loops via the same knob
+(tests/conftest.py, CI's uvloop step).
 """
 
 from __future__ import annotations

@@ -11,6 +11,7 @@ in place before the CPU client is (lazily) created, and the platform is
 forced through ``jax.config`` which wins over the env var.
 """
 
+import asyncio
 import gc
 import os
 
@@ -92,6 +93,31 @@ async def make_cluster(
     for r in replicas:
         await r.start()
     return replicas, c_auths, stubs, ledgers
+
+
+async def all_reach(read, k, timeout=5.0):
+    """Poll ``read()``, a list of counts, until every count is at least
+    ``k``.  Fails with the counts on timeout."""
+    loop = asyncio.get_running_loop()
+    deadline = loop.time() + timeout
+    while True:
+        counts = read()
+        if all(n >= k for n in counts):
+            return
+        assert loop.time() < deadline, (
+            f"counts at {counts}, want >= {k} after {timeout}s"
+        )
+        await asyncio.sleep(0.02)
+
+
+async def ledgers_reach(ledgers, k, timeout=5.0):
+    """Wait until every ledger of ``ledgers`` holds at least ``k`` blocks.
+
+    ``client.request()`` returns on f+1 matching replies, so the other
+    replicas may still be executing: a test that holds ALL ledgers to a
+    count waits here first."""
+    await all_reach(lambda: [lg.length for lg in ledgers], k, timeout)
+
 
 # Persistent compilation cache: the crypto kernels are compile-dominated on
 # the CPU backend (a cold ECDSA ladder compile is ~2 min), so warm runs

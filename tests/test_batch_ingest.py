@@ -8,8 +8,7 @@
 - Engine batch feed: ``submit_many`` lands a whole bundle in ONE flush.
 - ``Handlers.preverify_requests``: the batch verification seed shares
   the per-message memo discipline and fails item-wise.
-- The bundle-ingest cluster path commits end-to-end, and the
-  MINBFT_BUNDLE_INGEST=0 lever really reverts to the per-task pumps.
+- The bundle-ingest cluster path commits end-to-end.
 - ``_ConcurrentStreamProcessor.cancel`` iterates a snapshot (a task
   finishing during cancel mutates the set via its done-callback).
 """
@@ -37,7 +36,7 @@ from minbft_tpu.messages import codec as codec_mod
 from minbft_tpu.messages.codec import CodecError
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from conftest import make_cluster  # noqa: E402
+from conftest import ledgers_reach, make_cluster  # noqa: E402
 
 
 def _clear_intern():
@@ -286,15 +285,9 @@ def test_preverify_seeds_one_engine_batch_and_coalesces():
     assert asyncio.run(run())
 
 
-@pytest.mark.parametrize("bundle", ["1", "0"])
-def test_cluster_commits_on_both_ingest_paths(bundle, monkeypatch):
-    """End-to-end: the same small cluster commits with bundle ingest on
-    (default) and with the MINBFT_BUNDLE_INGEST=0 per-task lever — and
-    the ingest tick metrics appear exactly on the bundle path."""
-    if bundle == "0":
-        monkeypatch.setenv("MINBFT_BUNDLE_INGEST", "0")
-    else:
-        monkeypatch.delenv("MINBFT_BUNDLE_INGEST", raising=False)
+def test_cluster_commits_on_the_bundle_ingest_path():
+    """End-to-end: a small cluster commits through bundle ingest, and
+    the ingest tick metrics appear."""
 
     async def run():
         from minbft_tpu.client import new_client
@@ -311,16 +304,12 @@ def test_cluster_commits_on_both_ingest_paths(bundle, monkeypatch):
             ticks = sum(
                 r.metrics.counters.get("ingest_ticks", 0) for r in replicas
             )
-            if bundle == "0":
-                assert ticks == 0
-            else:
-                assert ticks > 0
-                frames = sum(
-                    r.metrics.counters.get("ingest_frames", 0)
-                    for r in replicas
-                )
-                assert frames >= ticks
-            assert all(lg.length >= 5 for lg in ledgers)
+            assert ticks > 0
+            frames = sum(
+                r.metrics.counters.get("ingest_frames", 0) for r in replicas
+            )
+            assert frames >= ticks
+            await ledgers_reach(ledgers, 5)
             return True
         finally:
             await client.stop()

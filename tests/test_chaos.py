@@ -19,7 +19,7 @@ import sys
 
 import pytest
 
-from conftest import make_cluster
+from conftest import ledgers_reach, make_cluster
 from minbft_tpu.client import new_client
 from minbft_tpu.messages import Commit, Request
 from minbft_tpu.sample.config import SimpleConfiger
@@ -223,6 +223,7 @@ def test_adversary_equivocation_rejected():
         accepted = []
         r0 = await asyncio.wait_for(client.request(b"equiv-seed"), 30)
         accepted.append((b"equiv-seed", r0))
+        await ledgers_reach(ledgers, 1)
 
         # A genuine client-signed request to re-batch (from replica 1's
         # own COMMIT, which embeds the primary's PREPARE).
@@ -262,6 +263,7 @@ def test_adversary_equivocation_rejected():
         # honest workload continues (view change deposes the adversary)
         r1 = await asyncio.wait_for(client.request(b"after-equiv"), 45)
         accepted.append((b"after-equiv", r1))
+        await ledgers_reach(ledgers[1:], len(accepted), timeout=_t(30))
         InvariantChecker(replicas, ledgers, correct=(1, 2, 3)).check(accepted)
 
         await client.stop()
@@ -293,10 +295,7 @@ def test_adversary_stale_replay_wrong_view_and_counter_gap():
         accepted = []
         r0 = await asyncio.wait_for(client.request(b"adv-seed"), 30)
         accepted.append((b"adv-seed", r0))
-        for _ in range(200):
-            if all(lg.length == 1 for lg in ledgers):
-                break
-            await asyncio.sleep(0.02)
+        await ledgers_reach(ledgers, 1)
 
         # Replica 2 turns adversarial (still within f=1).
         genuine_commit = next(
@@ -352,6 +351,7 @@ def test_adversary_stale_replay_wrong_view_and_counter_gap():
         # honest workload still commits (primary 0 is honest and alive)
         r1 = await asyncio.wait_for(client.request(b"adv-after"), 30)
         accepted.append((b"adv-after", r1))
+        await ledgers_reach((ledgers[0], ledgers[1], ledgers[3]), len(accepted))
         InvariantChecker(replicas, ledgers, correct=(0, 1, 3)).check(accepted)
 
         await client.stop()
@@ -386,10 +386,7 @@ def test_adversary_conflicting_replies_stay_below_quorum():
                 break
             await asyncio.sleep(0.02)
         assert forger.replies_sent >= 1  # the liar really voted
-        for _ in range(200):
-            if all(lg.length == 1 for lg in (ledgers[0], ledgers[1], ledgers[3])):
-                break
-            await asyncio.sleep(0.02)
+        await ledgers_reach((ledgers[0], ledgers[1], ledgers[3]), 1)
         assert res == ledgers[0].block(1).digest()
         InvariantChecker(replicas, ledgers, correct=(0, 1, 3)).check(
             [(b"honest-op", res)]
@@ -446,11 +443,7 @@ def test_view_change_completes_under_message_loss():
         for r in replicas[1:]:
             cur, _ = await r.handlers.view_state.hold_view()
             assert cur >= 1, f"replica {r.id} still in view {cur}"
-        deadline = asyncio.get_running_loop().time() + _t(30)
-        while asyncio.get_running_loop().time() < deadline:
-            if all(lg.length >= 2 for lg in ledgers[1:]):
-                break
-            await asyncio.sleep(0.05)
+        await ledgers_reach(ledgers[1:], 2, timeout=_t(30))
         InvariantChecker(replicas, ledgers, correct=(1, 2, 3)).check(accepted)
         assert net.census.counters.get("drop", 0) >= 1
 
@@ -498,11 +491,7 @@ def test_stalled_primary_triggers_view_change_inprocess():
         net.unstall_replica(0)
         # committed-results is a convergence property (f+1 replies prove
         # only f+1 executions) — give laggards a bounded catch-up first.
-        deadline = asyncio.get_running_loop().time() + _t(30)
-        while asyncio.get_running_loop().time() < deadline:
-            if all(lg.length >= len(accepted) for lg in ledgers[1:]):
-                break
-            await asyncio.sleep(0.05)
+        await ledgers_reach(ledgers[1:], len(accepted), timeout=_t(30))
         InvariantChecker(replicas, ledgers, correct=(1, 2, 3)).check(accepted)
 
         await client.stop()
@@ -573,11 +562,7 @@ def test_stalled_primary_triggers_view_change_tcp():
             net.unstall_replica(0)
             # committed-results is a convergence property — wait for the
             # correct laggards before holding every ledger to it.
-            deadline = asyncio.get_running_loop().time() + _t(30)
-            while asyncio.get_running_loop().time() < deadline:
-                if all(lg.length >= len(accepted) for lg in ledgers[1:]):
-                    break
-                await asyncio.sleep(0.05)
+            await ledgers_reach(ledgers[1:], len(accepted), timeout=_t(30))
             InvariantChecker(replicas, ledgers, correct=(1, 2, 3)).check(accepted)
         finally:
             await client.stop()
@@ -688,11 +673,7 @@ def test_idle_refresh_heals_silent_tail_loss():
         try:
             r0 = await asyncio.wait_for(client.request(b"tail-seed"), _t(30))
             accepted.append((b"tail-seed", r0))
-            deadline = asyncio.get_running_loop().time() + _t(15)
-            while asyncio.get_running_loop().time() < deadline:
-                if all(lg.length == 1 for lg in ledgers):
-                    break
-                await asyncio.sleep(0.02)
+            await ledgers_reach(ledgers, 1, timeout=_t(15))
 
             # r3 alone on the wrong side; the client stays with the
             # majority so NOTHING reaches r3 from here on.
@@ -707,15 +688,8 @@ def test_idle_refresh_heals_silent_tail_loss():
             # idle-refresh this wedges forever: the partition dropped
             # frames on streams that stayed up, so r3 sees only silence.
             net.heal_partition()
-            deadline = asyncio.get_running_loop().time() + _t(45)
-            while asyncio.get_running_loop().time() < deadline:
-                if ledgers[3].length >= len(accepted):
-                    break
-                await asyncio.sleep(0.05)
-            assert ledgers[3].length >= len(accepted), (
-                f"r3 ledger stuck at {ledgers[3].length}/{len(accepted)} "
-                "after heal (idle-refresh did not deliver the tail)"
-            )
+            # (a timeout here: idle-refresh did not deliver the tail)
+            await ledgers_reach(ledgers[3:], len(accepted), timeout=_t(45))
             assert replicas[3].metrics.counters.get("idle_redials", 0) >= 1
             InvariantChecker(replicas, ledgers).check(accepted)
         finally:
@@ -795,11 +769,7 @@ def test_chaos_soak_commits_under_faults():
             # the laggards a bounded catch-up before holding every
             # ledger to the accepted set.
             checker.check()
-            deadline = asyncio.get_running_loop().time() + 45
-            while asyncio.get_running_loop().time() < deadline:
-                if all(lg.length >= len(accepted) for lg in ledgers):
-                    break
-                await asyncio.sleep(0.05)
+            await ledgers_reach(ledgers, len(accepted), timeout=45)
             checker.check(accepted)
 
             # Phase B: partition {r0,r1} | {r2,r3} while traffic flows
@@ -877,13 +847,7 @@ def test_chaos_soak_commits_under_faults():
             assert len(accepted) == 24
             assert all(res for _, res in accepted)
             # ...on EVERY replica (the stalled ex-primary catches up).
-            deadline = asyncio.get_running_loop().time() + 60
-            while asyncio.get_running_loop().time() < deadline:
-                if all(lg.length >= len(accepted) for lg in ledgers):
-                    break
-                await asyncio.sleep(0.1)
-            lengths = [lg.length for lg in ledgers]
-            assert all(l >= len(accepted) for l in lengths), lengths
+            await ledgers_reach(ledgers, len(accepted), timeout=60)
 
             # Safety invariants across ALL replicas at teardown.
             summary = checker.check(accepted)

@@ -271,8 +271,8 @@ def test_first_dispatch_gets_cold_compile_headroom():
 def test_host_prep_time_populated_for_device_schemes():
     """Round-6 prep/device split: every device dispatch accounts its host
     prep (pack) time separately, so host_prep_time_s is non-zero whenever
-    a batch went through a device queue — the measurement bench.py turns
-    into *_prep_share."""
+    a batch went through a device queue (the benchmark reads it as
+    hostprep.ms_per_commit)."""
 
     async def run():
         eng = BatchVerifier(max_batch=8, buckets=(8,))

@@ -15,6 +15,7 @@ import sys
 
 import pytest
 
+from conftest import ledgers_reach
 from minbft_tpu import api
 from minbft_tpu.groups import (
     GroupAuthenticator,
@@ -50,6 +51,11 @@ TIME_SCALE = 5.0 if sys.flags.dev_mode else 1.0
 
 def _t(seconds: float) -> float:
     return seconds * TIME_SCALE
+
+
+def _group_ledgers(ledgers, g):
+    """Every replica's ledger of group ``g``."""
+    return [row[g] for row in ledgers]
 
 
 _log = logging.getLogger("minbft.groups.test")
@@ -237,13 +243,10 @@ def _mg_client(client_id, n, f, client_auths, stubs, **kw):
 
 
 # ---------------------------------------------------------------------------
-# runtime: commit across groups on shared transport, both ingest paths.
+# runtime: commit across groups on shared transport.
 
 
-@pytest.mark.parametrize("ingest", ["1", "0"])
-def test_group_runtime_commits_across_groups(ingest, monkeypatch):
-    monkeypatch.setenv("MINBFT_BUNDLE_INGEST", ingest)
-
+def test_group_runtime_commits_across_groups():
     async def run():
         runtimes, c_auths, stubs, ledgers = await make_group_cluster(
             n=4, f=1, n_groups=2
@@ -262,6 +265,7 @@ def test_group_runtime_commits_across_groups(ingest, monkeypatch):
             assert all(per_g), f"hash routing starved a group: {per_g}"
             # every replica's per-group ledger holds exactly its shard
             for g in range(2):
+                await ledgers_reach(_group_ledgers(ledgers, g), per_g[g], _t(5))
                 lens = [ledgers[i][g].length for i in range(4)]
                 assert all(l == per_g[g] for l in lens), (g, lens, per_g)
             # per-group observability labels are threaded through
@@ -291,6 +295,7 @@ def test_pinned_group_and_unknown_group_frames():
             await asyncio.wait_for(
                 client.request(b"pinned", group=1), _t(60)
             )
+            await ledgers_reach(_group_ledgers(ledgers, 1), 1, _t(5))
             assert [ledgers[i][1].length for i in range(4)] == [1] * 4
             assert all(ledgers[i][0].length == 0 for i in range(4))
             with pytest.raises(ValueError):
@@ -312,6 +317,7 @@ def test_pinned_group_and_unknown_group_frames():
             await out.aclose()
             # and the cluster still works afterwards
             await asyncio.wait_for(client.request(b"after", group=0), _t(60))
+            await ledgers_reach(_group_ledgers(ledgers, 0), 1, _t(5))
             assert [ledgers[i][0].length for i in range(4)] == [1] * 4
         finally:
             await client.stop()
@@ -466,9 +472,7 @@ def test_wedged_group_does_not_block_others():
                 ),
                 _t(60),
             )
-            assert all(
-                ledgers[i][0].length >= len(ops) for i in range(4)
-            ), [ledgers[i][0].length for i in range(4)]
+            await ledgers_reach(_group_ledgers(ledgers, 0), len(ops), _t(5))
         finally:
             await client.stop()
             for rt in runtimes:
@@ -515,9 +519,7 @@ def test_saturated_group_processor_never_blocks_the_shared_drain(monkeypatch):
             ]
             await asyncio.sleep(_t(1.0))  # reach the replicas and park
             await asyncio.wait_for(client.request(b"ok", group=0), _t(60))
-            assert all(
-                ledgers[i][0].length >= 1 for i in range(4)
-            ), [ledgers[i][0].length for i in range(4)]
+            await ledgers_reach(_group_ledgers(ledgers, 0), 1, _t(5))
         finally:
             for t in floods:
                 t.cancel()
@@ -727,27 +729,17 @@ def test_groups_chaos_soak_one_group_faulted():
                 g: len(accepted[g]) for g in range(G)
             }
             assert per_group_expected[_CHAOS_GROUP] == 9
-            deadline = asyncio.get_running_loop().time() + _t(60)
-            while asyncio.get_running_loop().time() < deadline:
-                if all(
-                    ledgers[i][g].length >= per_group_expected[g]
-                    for i in range(4)
-                    for g in range(G)
-                ):
-                    break
-                await asyncio.sleep(0.1)
             for g in range(G):
-                lens = [ledgers[i][g].length for i in range(4)]
-                assert all(
-                    l >= per_group_expected[g] for l in lens
-                ), (g, lens)
+                await ledgers_reach(
+                    _group_ledgers(ledgers, g), per_group_expected[g], _t(60)
+                )
 
             # per-group safety invariants over per-group cores/ledgers
             summaries = {}
             for g in range(G):
                 checker = InvariantChecker(
                     [rt.group(g) for rt in runtimes],
-                    [ledgers[i][g] for i in range(4)],
+                    _group_ledgers(ledgers, g),
                 )
                 summaries[g] = checker.check(accepted[g])
             # the injected faults really happened, in the target group's

@@ -1,7 +1,6 @@
 """Prometheus exposition tests: text-format rendering, the stdlib
-``/metrics`` endpoint scraped off a live in-process cluster, the
-``peer metrics`` one-shot subcommand, and the bench-keys regression pin
-(tracing disabled must be key-identical to tracing absent)."""
+``/metrics`` endpoint scraped off a live in-process cluster, and the
+``peer metrics`` one-shot subcommand."""
 
 import asyncio
 import os
@@ -291,88 +290,3 @@ def test_peer_metrics_subcommand_scrapes(capsys):
     # a dead endpoint is a clean error, not a traceback
     rc = cli.main(["metrics", f"127.0.0.1:{port}", "--timeout", "0.5"])
     assert rc == 1
-
-
-# ---------------------------------------------------------------------------
-# bench-keys regression: tracing disabled == tracing absent
-
-
-def _bench_cluster_keys(trace: bool):
-    import bench
-
-    out = asyncio.run(
-        bench._bench_cluster(
-            4, 1, 24,
-            n_clients=4,
-            usig_kind="hmac",
-            max_batch=8,
-            depth=4,
-            prefix="pin",
-            trace=trace,
-        )
-    )
-    return set(out)
-
-
-# The exact key set _bench_cluster emitted BEFORE the flight recorder
-# existed: a tracing-DISABLED run must reproduce it byte-identically —
-# the recorder must be invisible unless asked for.
-_PINNED_BENCH_KEYS = {
-    "pin_request_latency_p50_ms",
-    "pin_request_latency_p99_ms",
-    "pin_exec_latency_p50_ms",
-    "pin_exec_latency_p99_ms",
-    "pin_messages_handled",
-    "pin_messages_dropped",
-    "pin_n",
-    "pin_f",
-    "pin_clients",
-    "pin_requests",
-    "pin_committed_req_per_sec",
-    # Bundle-ingest fill gauges (ISSUE 6): ALWAYS present — 0-valued when
-    # MINBFT_BUNDLE_INGEST=0 — so the key set cannot depend on a runtime
-    # toggle (the byte-identical contract this pin enforces).
-    "pin_ingest_batch_mean",
-    "pin_ingest_ticks_per_sec",
-    "pin_batched_verifies",
-    "pin_batches",
-    "pin_mean_batch",
-    "pin_device_verifies_per_sec",
-    "pin_logical_verifies",
-    "pin_memo_hits",
-    "pin_hmac_sha256_prep_share",
-    # REPLY signing rides the engine sign queue even on the CPU backend
-    # (host fallback, recorded) — these four predate the recorder.
-    "pin_device_signs_per_sec",
-    "pin_queue_signs",
-    "pin_sign_fallback_items",
-    "pin_sign_share",
-}
-
-
-@pytest.mark.slow
-def test_bench_keys_trace_disabled_is_byte_identical():
-    keys = _bench_cluster_keys(trace=False)
-    assert keys == _PINNED_BENCH_KEYS
-    assert not any("_stage_" in k for k in keys)
-
-
-@pytest.mark.slow
-def test_bench_keys_trace_enabled_adds_only_stage_keys():
-    keys = _bench_cluster_keys(trace=True)
-    extra = keys - _PINNED_BENCH_KEYS
-    assert extra, "traced run must add stage keys"
-    # a traced run adds exactly the per-stage attribution AND the
-    # cluster critical-path keys (ISSUE 8) — nothing else
-    assert all(
-        "pin_stage_" in k or "pin_critpath_" in k for k in extra
-    ), sorted(extra)
-    # and the replica pipeline is fully attributed
-    for name in ("verify_done", "commit_quorum", "execute", "reply_sent"):
-        assert f"pin_stage_{name}_p50_ms" in keys
-        assert f"pin_stage_{name}_share" in keys
-    # the critical path carries its full stable segment set
-    from minbft_tpu.obs.critpath import SEGMENTS
-
-    for seg in SEGMENTS:
-        assert f"pin_critpath_{seg}_share" in keys, seg

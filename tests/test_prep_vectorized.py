@@ -185,15 +185,12 @@ def test_p256_prep_adversarial_edges():
     assert r2_ok[13] and valid[13]
 
 
-def test_p256_prep_scalar_flag_roundtrip(monkeypatch):
-    """MINBFT_SCALAR_PREP=1 (limbs.SCALAR_PREP, shared by both schemes)
-    routes prepare_batch to the oracle."""
-    monkeypatch.setattr(limbs, "SCALAR_PREP", True)
+def test_p256_prep_scalar_oracle_roundtrip():
+    """The scalar oracle and the vectorized prep agree on one signed item."""
     d, q = hc.keygen()
     digest = hashlib.sha256(b"flag").digest()
     items = [(q, digest, hc.ecdsa_sign(d, digest))]
-    a = p256.prepare_batch(items)
-    monkeypatch.setattr(limbs, "SCALAR_PREP", False)
+    a = p256.prepare_batch_scalar(items)
     b = p256.prepare_batch(items)
     for x, y in zip(a, b):
         assert np.array_equal(x, y)
@@ -374,8 +371,7 @@ def test_engine_staging_reuse_thread_hammer():
 def test_prep_speedup_at_least_5x():
     """Acceptance: >=5x host-prep throughput for prepare_batch at B=16384
     vs the scalar oracle on the same host (and bit-identical output on
-    the same items).  bench.py's bench_prep reports the same measurement
-    as extras."""
+    the same items)."""
     import time
 
     rng = random.Random(0x5EED)

@@ -298,7 +298,7 @@ class AsyncHygienePass(Pass):
     name = "async-hygiene"
     description = "no blocking sinks reachable from the event loop"
     scope = (
-        "coroutine call graph over minbft_tpu/ + bench.py; sinks: "
+        "coroutine call graph over minbft_tpu/; sinks: "
         "blocking calls, sync file IO, sync lock acquire, 3-arg pow"
     )
 

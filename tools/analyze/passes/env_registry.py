@@ -5,7 +5,7 @@ surface; an undocumented knob is unusable and an undead registry entry
 is a trap.  The pass collects every getenv-shaped site — any string
 constant that IS a qualifying name (docstrings excluded; a name
 embedded in prose never full-matches) plus f-string prefixes
-(``f"MINBFT_BENCH_{name}"`` -> ``MINBFT_BENCH_*``) — and cross-checks
+(``f"MINBFT_FOO_{name}"`` -> ``MINBFT_FOO_*``) — and cross-checks
 the committed registry ``tools/analyze/ENV_VARS.md``:
 
 ER501  a live variable absent from the registry
@@ -31,7 +31,7 @@ _ENTRY_RE = re.compile(r"^\|\s*`(?P<name>[A-Z0-9_*]+)`\s*\|\s*(?P<desc>.*?)\s*\|
 _HEADER = """\
 # Environment variable registry
 
-Every `MINBFT_*`/`CONSENSUS_*` variable the runtime, bench harness or
+Every `MINBFT_*`/`CONSENSUS_*` variable the runtime or the driver's
 entry point reads — enforced by the `env-registry` analyzer pass
 (ER501: unregistered, ER502: dead entry, ER503: missing description).
 Regenerate with `python -m tools.analyze --write-env-registry`; the
@@ -127,7 +127,7 @@ class EnvRegistryPass(Pass):
     name = "env-registry"
     description = "MINBFT_*/CONSENSUS_* knobs registered in ENV_VARS.md"
     scope = (
-        "getenv sites in minbft_tpu/ + bench.py + __graft_entry__.py vs "
+        "getenv sites in minbft_tpu/ + __graft_entry__.py vs "
         "tools/analyze/ENV_VARS.md"
     )
 

@@ -1,35 +1,10 @@
-"""SD: the four bench/metrics key-schema sources must agree.
+"""SD: a Prometheus name a test pins must be one ``obs/prom.py`` registers.
 
-Every PR so far has reconciled these by hand ("pinned key set
-updated").  The pass extracts, statically:
+The pass extracts, statically, the families registered in
+``obs/prom.py`` (string constants and f-strings, placeholders normalized
+to ``*``: ``f"minbft_{name}_total"`` -> ``minbft_*_total``) and the
+``minbft_*`` string literals in the configured test files.
 
-1. EMITTED bench key families — dict-literal keys and subscript
-   assignments in ``bench.py``, f-string placeholders normalized to
-   ``*`` (``f"{prefix}_req_per_sec_mean"`` -> ``*_req_per_sec_mean``);
-2. GATED families — the module-level ``_*_SUFFIX``/``_*_PREFIX``
-   string constants in ``tools/benchgate`` (LOAD-named suffixes
-   combine with the LOAD prefix: ``load_*_p99_ms``), plus ``_*_KEY``
-   constants taken verbatim as exact-match patterns (the recovery
-   headlines gate on whole key names, not suffix rules);
-3. DOC'D families — the ``bench.py`` module docstring's "Extras
-   schema" section (2-space-indented key-spec lines; ``/``- and
-   ``,``-separated alternatives; leading-underscore tokens attach to
-   the previous full token's first segment; ``{var}`` -> ``*``);
-4. Prometheus families registered in ``obs/prom.py`` plus the
-   ``minbft_*`` names PINNED in the configured tests.
-
-Cross-checks (family-vs-family matching is glob-pattern
-intersection):
-
-SD701  emitted headline family (``*_req_per_sec_mean``,
-       ``*_util_effective_per_sec``, ``*_goodput_per_sec``) that no
-       benchgate pattern covers — a headline nobody gates regresses
-       silently
-SD702  gated pattern intersecting no emitted family — the gate is dead
-SD703  doc'd family intersecting no emitted family — the schema header
-       advertises keys the bench no longer produces
-SD704  emitted rate family (``*_per_sec``) absent from the schema
-       header — undocumented telemetry nobody can read
 SD705  ``minbft_*`` name pinned in a test but registered by no prom
        family (exposition suffixes ``_bucket``/``_count``/``_sum``
        stripped before matching)
@@ -40,13 +15,11 @@ from __future__ import annotations
 import ast
 import re
 from fnmatch import fnmatchcase
-from typing import Dict, List, Tuple
+from typing import List
 
 from ..core import Finding, Pass, Project, register_pass
 
-_TOKEN_RE = re.compile(r"^[A-Za-z_{*][A-Za-z0-9_{},*]*$")
 _PATTERN_RE = re.compile(r"^[a-z0-9_*]+$")
-_GATE_NAME_RE = re.compile(r"^_[A-Z0-9_]*?(SUFFIX|PREFIX|KEY)$")
 _EXPO_SUFFIXES = ("_bucket", "_count", "_sum")
 
 
@@ -68,111 +41,25 @@ def _key_pattern(node: ast.AST) -> str:
     return ""
 
 
-def _glob_intersects(a: str, b: str) -> bool:
-    """True when some concrete string matches BOTH ``*``-glob patterns."""
-    la, lb = len(a), len(b)
-    memo: Dict[Tuple[int, int], bool] = {}
-
-    def go(i: int, j: int) -> bool:
-        key = (i, j)
-        hit = memo.get(key)
-        if hit is not None:
-            return hit
-        memo[key] = False  # cycle guard (star self-loops)
-        r = False
-        if i == la and j == lb:
-            r = True
-        if not r and i < la and a[i] == "*":
-            r = go(i + 1, j)
-        if not r and j < lb and b[j] == "*":
-            r = go(i, j + 1)
-        if not r and i < la and j < lb:
-            ai, bj = a[i], b[j]
-            if ai == "*" or bj == "*" or ai == bj:
-                r = go(i + 1, j + 1)
-            if not r and ai == "*" and bj != "*":
-                r = go(i, j + 1)
-            if not r and bj == "*" and ai != "*":
-                r = go(i + 1, j)
-        memo[key] = r
-        return r
-
-    return go(0, 0)
-
-
-def _braces_to_star(tok: str) -> str:
-    return re.sub(r"\{[^{}]*\}", "*", tok)
-
-
 @register_pass
 class SchemaDriftPass(Pass):
     code_prefix = "SD"
     name = "schema-drift"
-    description = "bench keys, benchgate gates, prom names and test pins agree"
-    scope = (
-        "bench.py emitted keys + schema header vs tools/benchgate gates "
-        "vs obs/prom.py families vs test-pinned names"
-    )
+    description = "prom names pinned in tests are registered"
+    scope = "obs/prom.py families vs minbft_* names pinned in tests"
 
     def run(self, project: Project) -> List[Finding]:
         cfg = getattr(project.config, "schema", None)
         if cfg is None:
             return []
-        # Analyzing a tree without the bench surface (--root on a
+        # Analyzing a tree without the prom surface (--root on a
         # fixture/scratch checkout) is not drift — there is nothing to
         # cross-check.  The --selftest liveness gate keeps this from
         # silently disabling the pass on the real repo.
-        if not project.exists(cfg.bench_module):
+        if not project.exists(cfg.prom_module):
             return []
         findings: List[Finding] = []
-        emitted = self._emitted(project, cfg)       # pattern -> first line
-        gated = self._gated(project, cfg)           # pattern -> line
-        documented = self._documented(project, cfg)  # pattern -> line
-        prom = self._prom_families(project, cfg)     # patterns
-
-        # SD701: emitted headline families must be gated
-        for pat, line in sorted(emitted.items()):
-            if pat in cfg.exempt:
-                continue
-            if not any(pat.endswith(s) for s in cfg.headline_suffixes):
-                continue
-            if not any(_glob_intersects(pat, g) for g in gated):
-                findings.append(Finding(
-                    "SD701", cfg.bench_module, line,
-                    f"headline family {pat!r} is emitted but no benchgate "
-                    "pattern covers it — the headline regresses silently",
-                ))
-
-        # SD702: every gate must be reachable by an emitted family
-        for pat, line in sorted(gated.items()):
-            if not any(_glob_intersects(pat, e) for e in emitted):
-                findings.append(Finding(
-                    "SD702", cfg.benchgate_module, line,
-                    f"gated pattern {pat!r} matches no key family bench.py "
-                    "emits — the gate is dead",
-                ))
-
-        # SD703: every doc'd family must still be emitted
-        for pat, line in sorted(documented.items()):
-            if not any(_glob_intersects(pat, e) for e in emitted):
-                findings.append(Finding(
-                    "SD703", cfg.bench_module, line,
-                    f"schema header documents {pat!r} but bench.py emits no "
-                    "matching key — dead documentation",
-                ))
-
-        # SD704: emitted rate families must be documented
-        for pat, line in sorted(emitted.items()):
-            if pat in cfg.exempt:
-                continue
-            if not any(pat.endswith(s) for s in cfg.documented_suffixes):
-                continue
-            if not any(_glob_intersects(pat, d) for d in documented):
-                findings.append(Finding(
-                    "SD704", cfg.bench_module, line,
-                    f"emitted family {pat!r} is absent from the bench.py "
-                    "schema header — undocumented telemetry",
-                ))
+        prom = self._prom_families(project, cfg)
 
         # SD705: test-pinned prom names must be registered
         for rel in cfg.pinned_tests:
@@ -207,130 +94,7 @@ class SchemaDriftPass(Pass):
 
     # -- source extraction ---------------------------------------------------
 
-    def _emitted(self, project, cfg) -> Dict[str, int]:
-        out: Dict[str, int] = {}
-
-        def add(pat: str, line: int) -> None:
-            if pat and _PATTERN_RE.match(pat) and pat not in out:
-                out[pat] = line
-
-        tree = project.tree(cfg.bench_module)
-        for node in ast.walk(tree):
-            if isinstance(node, ast.Dict):
-                for k in node.keys:
-                    if k is not None:
-                        add(_key_pattern(k), k.lineno)
-            elif isinstance(node, (ast.Assign, ast.AugAssign, ast.AnnAssign)):
-                targets = (
-                    node.targets
-                    if isinstance(node, ast.Assign)
-                    else [node.target]
-                )
-                for t in targets:
-                    if isinstance(t, ast.Subscript):
-                        add(_key_pattern(t.slice), t.lineno)
-        return out
-
-    def _gated(self, project, cfg) -> Dict[str, int]:
-        if not project.exists(cfg.benchgate_module):
-            return {}
-        tree = project.tree(cfg.benchgate_module)
-        suffixes: List[Tuple[str, str, int]] = []  # (const name, value, line)
-        prefixes: Dict[str, str] = {}
-        exacts: List[Tuple[str, int]] = []  # _*_KEY constants, verbatim
-        for node in tree.body:
-            if not (
-                isinstance(node, ast.Assign)
-                and len(node.targets) == 1
-                and isinstance(node.targets[0], ast.Name)
-                and isinstance(node.value, ast.Constant)
-                and isinstance(node.value.value, str)
-            ):
-                continue
-            cname = node.targets[0].id
-            if not _GATE_NAME_RE.match(cname):
-                continue
-            if cname.endswith("PREFIX"):
-                prefixes[cname] = node.value.value
-            elif cname.endswith("KEY"):
-                exacts.append((node.value.value, node.lineno))
-            else:
-                suffixes.append((cname, node.value.value, node.lineno))
-        out: Dict[str, int] = {}
-        for value, line in exacts:
-            out.setdefault(value, line)
-        for cname, value, line in suffixes:
-            prefix = ""
-            for pname, pvalue in prefixes.items():
-                # e.g. _LOAD_P99_SUFFIX pairs with _LOAD_PREFIX
-                tag = pname[1:].rsplit("_", 1)[0]  # "LOAD"
-                if tag and tag in cname:
-                    prefix = pvalue
-                    break
-            out.setdefault(prefix + "*" + value, line)
-        return out
-
-    def _documented(self, project, cfg) -> Dict[str, int]:
-        tree = project.tree(cfg.bench_module)
-        doc = ast.get_docstring(tree, clean=False)
-        if not doc:
-            return {}
-        # docstring body starts on the module's first line
-        base_line = tree.body[0].value.lineno if tree.body else 1
-        out: Dict[str, int] = {}
-        in_schema = False
-        last_full = ""
-        for off, raw in enumerate(doc.splitlines()):
-            line = raw.rstrip()
-            if "Extras schema" in line:
-                in_schema = True
-                continue
-            if line.strip().startswith("Environment knobs"):
-                break
-            if not in_schema or not line.strip():
-                continue
-            indent = len(line) - len(line.lstrip())
-            if indent < 2:
-                continue  # unindented prose around the key-spec block
-            continuation = indent > 2
-            # strip the prose description: first 3+-space run ends the
-            # key-spec field; {var}/{a,b,c} placeholders become * BEFORE
-            # splitting so enumerations don't shatter on their commas
-            field = _braces_to_star(
-                re.split(r"\s{3,}", line.strip(), maxsplit=1)[0]
-            )
-            for tok in re.split(r"[\s/,]+", field):
-                tok = tok.strip("()+.;:")
-                if not tok or not _TOKEN_RE.match(tok):
-                    continue
-                if continuation and not (
-                    tok.startswith("_") or "*" in tok
-                ):
-                    continue  # prose words on wrapped lines — key tokens
-                    # there either attach as _suffixes or carry a
-                    # {placeholder} (now a *)
-                if tok.startswith("_"):
-                    if not last_full:
-                        continue
-                    # attach the suffix alternative to the previous full
-                    # token's stem: through its first placeholder star
-                    # (load_*_p50_ms + _p99_ms -> load_*_p99_ms), else
-                    # its first literal segment
-                    if "*" in last_full:
-                        stem = last_full[: last_full.index("*") + 1]
-                    else:
-                        stem = last_full.split("_", 1)[0]
-                    pat = stem + tok
-                else:
-                    pat = tok
-                    last_full = pat
-                if _PATTERN_RE.match(pat):
-                    out.setdefault(pat, base_line + off)
-        return out
-
     def _prom_families(self, project, cfg) -> List[str]:
-        if not project.exists(cfg.prom_module):
-            return []
         pats: List[str] = []
         for node in ast.walk(project.tree(cfg.prom_module)):
             pat = _key_pattern(node) if isinstance(
@@ -353,27 +117,16 @@ class SchemaDriftPass(Pass):
         from ..project import AnalyzeConfig, SchemaDriftConfig
 
         files = {
-            "bench.py": (
-                '"""Bench.\n\n'
-                "Extras schema:\n"
-                "  cfg_req_per_sec_mean   headline\n\n"
-                "Environment knobs:\n"
-                '  NONE\n"""\n'
-                "out = {}\n"
-                'out["cfg_req_per_sec_mean"] = 1.0\n'
-            ),
-            "gate.py": "_MEAN_SUFFIX = \"_req_per_sec_meanX\"\n",
             "prom.py": "FAM = \"minbft_up\"\n",
+            "test_pins.py": "BAD = \"minbft_never_registered_total\"\n",
         }
-        # the gate suffix matches nothing bench emits -> SD702 (and the
-        # emitted headline is covered by no gate -> SD701)
+        # the pinned name matches no registered family -> SD705
         config = AnalyzeConfig(
-            source_roots=("bench.py",), lock_classes=(), trace=None,
+            source_roots=("prom.py",), lock_classes=(), trace=None,
             exhaustiveness=None, secrets=None, dead=None,
             schema=SchemaDriftConfig(
-                bench_module="bench.py",
-                benchgate_module="gate.py",
                 prom_module="prom.py",
+                pinned_tests=("test_pins.py",),
             ),
         )
         return files, config

@@ -67,7 +67,7 @@ class TaskLifecyclePass(Pass):
     name = "task-lifecycle"
     description = "background tasks are retained; tracked sets iterated safely"
     scope = (
-        "create_task/ensure_future sites in minbft_tpu/ + bench.py; "
+        "create_task/ensure_future sites in minbft_tpu/; "
         "tracked-set iteration vs done-callback mutation"
     )
 

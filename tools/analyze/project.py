@@ -231,47 +231,13 @@ class TaskLifecycleConfig:
 
 @dataclasses.dataclass(frozen=True)
 class SchemaDriftConfig:
-    """The four key-schema sources the SD pass cross-checks.
+    """What the SD pass cross-checks: ``minbft_*`` names pinned in tests
+    must match a Prometheus family registered by the prom module
+    (pinned-but-unregistered, SD705)."""
 
-    Families are glob-ish patterns over key names (``*`` = any run of
-    characters, from f-string placeholders).  The checks:
-
-    - an EMITTED family whose suffix marks it headline-grade must match
-      a GATED pattern (emitted-but-ungated, SD701);
-    - every GATED pattern must intersect an emitted family
-      (gated-but-never-emitted, SD702);
-    - every family documented in the bench schema header must intersect
-      an emitted family (doc'd-but-dead, SD703);
-    - emitted rate families (``documented_suffixes``) must be covered by
-      the schema header (emitted-but-undocumented, SD704);
-    - ``minbft_*`` names pinned in tests must match a Prometheus family
-      registered by the prom module (pinned-but-unregistered, SD705).
-    """
-
-    bench_module: str = "bench.py"
-    benchgate_module: str = "tools/benchgate/__init__.py"
     prom_module: str = "minbft_tpu/obs/prom.py"
-    # Test files whose string literals pin bench keys / prom names.
+    # Test files whose string literals pin prom names.
     pinned_tests: Tuple[str, ...] = ()
-    # Suffixes that make an emitted family headline-grade (must be gated).
-    headline_suffixes: Tuple[str, ...] = (
-        "_req_per_sec_mean",
-        "_util_effective_per_sec",
-        "_goodput_per_sec",
-    )
-    # Suffixes whose emitted families must appear in the schema header.
-    documented_suffixes: Tuple[str, ...] = (
-        "_per_sec",
-        # SLO surface (ISSUE 19): the finality/goodness pair is emitted
-        # at every curve and grid point and the p99 half is gated, so
-        # drift between bench.py, benchgate, and the schema header is
-        # exactly what SD704 exists to catch.
-        "_finality_p99_ms",
-        "_slo_good_fraction",
-    )
-    # Emitted families exempt from SD701/SD704 with a reason each
-    # (progress/diagnostic keys that are deliberately not gated).
-    exempt: Dict[str, str] = dataclasses.field(default_factory=dict)
 
 
 # ---------------------------------------------------------------------------
@@ -286,7 +252,8 @@ class EnvRegistryConfig:
     ``roots`` must appear in the committed registry markdown with a
     one-line description; registry entries matching no live site are
     dead.  F-string env names contribute prefix wildcards
-    (``MINBFT_BENCH_CFG*``) that keep their expansions alive.
+    (``f"MINBFT_FOO_{x}"`` -> ``MINBFT_FOO_*``) that keep their
+    expansions alive.
     """
 
     roots: Tuple[str, ...] = ()
@@ -321,7 +288,6 @@ def default_config() -> AnalyzeConfig:
             "minbft_tpu",
             "tests",
             "tools/analyze",
-            "bench.py",
             "__graft_entry__.py",
         ),
         lock_classes=(
@@ -364,7 +330,7 @@ def default_config() -> AnalyzeConfig:
                 path="minbft_tpu/core/message_handling.py",
                 cls="_BundleIngestor",
                 locks=(),
-                guarded=("_rx", "_eof_pending", "_max_frames"),
+                guarded=("_rx", "_eof_pending"),
             ),
             # Tick accounting the ingest path feeds from the event loop;
             # the Prometheus scrape thread only READS (GIL-atomic ints,
@@ -667,23 +633,19 @@ def default_config() -> AnalyzeConfig:
                 "minbft_tpu",
                 "tests",
                 "tools/analyze",
-                "bench.py",
                 "__graft_entry__.py",
             ),
         ),
         async_hygiene=AsyncHygieneConfig(
             # Product code only: tests block freely (pytest-asyncio runs
-            # each loop for one test), and bench's sync warmup helpers
-            # run before the loop starts.
-            roots=("minbft_tpu", "bench.py"),
+            # each loop for one test).
+            roots=("minbft_tpu",),
             boundary={},  # filled below once real boundary sites are known
         ),
         tasks=TaskLifecycleConfig(
-            roots=("minbft_tpu", "bench.py"),
+            roots=("minbft_tpu",),
         ),
         schema=SchemaDriftConfig(
-            bench_module="bench.py",
-            benchgate_module="tools/benchgate/__init__.py",
             prom_module="minbft_tpu/obs/prom.py",
             # Tests that pin PRODUCT prom families by literal name.
             # (test_metrics_endpoint.py pins only its own local fixture
@@ -695,6 +657,6 @@ def default_config() -> AnalyzeConfig:
             ),
         ),
         env=EnvRegistryConfig(
-            roots=("minbft_tpu", "bench.py", "__graft_entry__.py"),
+            roots=("minbft_tpu", "__graft_entry__.py"),
         ),
     )

@@ -9,8 +9,8 @@
 # branch below only says that the tests' CPU backend still works.  A
 # run that was meant for the chip and finds none fails — `peer run` says
 # on stderr which crypto it chose and why, `chip_smoke.py` and
-# `bench.py` exit non-zero (the latter unless JAX_PLATFORMS=cpu was
-# asked for).  Nothing in the repo reads this script or the probe.
+# `python3 -m benchmark.run` exit non-zero.  Nothing in the repo reads
+# this script or the probe.
 set -u
 cd "$(dirname "$0")/.."
 

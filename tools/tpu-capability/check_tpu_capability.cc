@@ -17,7 +17,7 @@
 // tri-state exit so tools/prerequisite-check.sh can branch on it — for a
 // person to read.  No program path may take status 1 as permission to
 // continue a chip run on the CPU: a run meant for the chip that finds
-// none fails (chip_smoke.py, bench.py, `peer run`'s placement line).
+// none fails (chip_smoke.py, benchmark/run.py, `peer run`'s placement line).
 
 #include <dirent.h>
 #include <dlfcn.h>
