@@ -98,6 +98,17 @@ class Authenticator(abc.ABC):
         """Verify ``tag`` over ``msg`` against ``peer_id``'s key for
         ``role``; raises :class:`AuthenticationError` on failure."""
 
+    def precheck_message_authen_tags(self, role: AuthenticationRole, items) -> int:
+        """A caller's notice of what it is about to ask, one by one,
+        through :meth:`verify_message_authen_tag`: ``items = [(peer_id,
+        msg, tag), ...]``, all of one transport frame.  An implementation
+        may verify them together now and answer the calls that follow from
+        what it found (the sample authenticator's host path does, in one
+        native call off the interpreter lock); it decides nothing here and
+        must give the same verdicts either way.  -> how many it verified
+        ahead.  Default: none."""
+        return 0
+
     @property
     def supports_batch_verify(self) -> bool:
         """True when :meth:`verify_message_authen_tags` lands a bundle on

@@ -7,7 +7,12 @@ Reference structure (client/):
   requestbuffer.go:59-88);
 - per-replica connection task pair: outgoing pumps the request stream,
   incoming authenticates REPLYs (ReplicaAuthen + client-ID check,
-  reference client/message-handling.go:161-170) and feeds the collector;
+  reference client/message-handling.go:161-170) and feeds the collector.
+  The replies that one transport frame carries are first handed to the
+  authenticator together (``precheck_message_authen_tags``): the sample
+  authenticator verifies them in ONE native call, off the interpreter
+  lock and side by side on helper threads (utils/replycheck.py), and the
+  reply-by-reply authentication that follows finds its verdicts there;
 - collector: f+1 matching replies by SHA256(result), dedup'd by replica ID
   (reference client/request.go:83-97, requestbuffer.go:219-236).
 
@@ -34,6 +39,7 @@ from typing import AsyncIterator, Dict, Optional
 
 from .. import api
 from ..obs import trace as obs_trace
+from ..utils import replycheck
 from ..utils.backoff import ReconnectBackoff, RetransmitBackoff
 from ..messages import (
     Busy,
@@ -169,12 +175,23 @@ class Client:
         )
         # Verified BUSY shed signals received (observable by load harnesses).
         self.busy_signals = 0
+        # What the reply checks cost (utils/replycheck.py): checks, how
+        # many of them in native batches off the interpreter lock, how
+        # many one by one inline, quorums formed.
+        self.reply_checks = replycheck.ReplyCheckStats()
+        self._checker: Optional[replycheck.ReplyChecker] = None
+        # The authenticator's seed call, where it (or what wraps it) has one.
+        self._precheck_tags = getattr(
+            authenticator, "precheck_message_authen_tags", None
+        )
         self._log = logging.getLogger(f"minbft_tpu.client.{client_id}")
 
     # -- connections --------------------------------------------------------
 
     async def start(self) -> None:
         loop = asyncio.get_running_loop()
+        if self._checker is None:
+            self._checker = replycheck.acquire()
         for rid in range(self.n):
             handler = self._connector.replica_message_stream_handler(rid)
             if handler is None:
@@ -211,6 +228,10 @@ class Client:
         await asyncio.gather(*self._tasks, return_exceptions=True)
         self._tasks.clear()
         self._started = False
+        if self._checker is not None:
+            # the last client of the loop to let go ends the helper threads
+            replycheck.release(self._checker)
+            self._checker = None
         # Fail in-flight requests instead of leaving their callers parked
         # on futures nothing will ever resolve.
         for pending in list(self._pending.values()):
@@ -221,7 +242,9 @@ class Client:
         if self._trace is not None:
             # No-op unless MINBFT_TRACE_DUMP is set (live-scrape-only
             # recorders have nothing to flush).
-            obs_trace.dump_recorder(self._trace)
+            obs_trace.dump_recorder(
+                self._trace, extra={"reply_checks": self.reply_checks.to_dict()}
+            )
 
     async def _outgoing(self, q: asyncio.Queue) -> AsyncIterator[bytes]:
         # Coalesce a pipelined burst of requests into one transport
@@ -260,19 +283,25 @@ class Client:
                         frames = split_multi(data)
                     except CodecError:
                         continue
-                    for fr in frames:
+                    msgs = self._addressed(replica_id, frames)
+                    if (
+                        len(msgs) >= replycheck.MIN_BATCH
+                        and self._precheck_tags is not None
+                    ):
+                        self._precheck(msgs)
+                    for msg, signed in msgs:
                         # A poison frame (reply handling raising — only
                         # local bugs or transient verifier/backend errors
                         # reach here; auth and codec failures are swallowed
-                        # inside _handle_reply) costs the FRAME, not the
-                        # connection.  A run of them tears the stream down
-                        # for a BACKOFF redial — never permanently: a
-                        # transient verifier outage must not sever >f
-                        # streams forever (the wedge this loop exists to
-                        # prevent), while a deterministic bug self-throttles
-                        # at the ladder cap.
+                        # inside _addressed / _handle_reply) costs the
+                        # FRAME, not the connection.  A run of them tears
+                        # the stream down for a BACKOFF redial — never
+                        # permanently: a transient verifier outage must not
+                        # sever >f streams forever (the wedge this loop
+                        # exists to prevent), while a deterministic bug
+                        # self-throttles at the ladder cap.
                         try:
-                            await self._handle_reply(replica_id, fr)
+                            await self._handle_reply(msg, signed)
                             consecutive_errors = 0
                         except asyncio.CancelledError:
                             raise
@@ -327,27 +356,66 @@ class Client:
             )
             await asyncio.sleep(delay)
 
-    async def _handle_reply(self, replica_id: int, data: bytes) -> None:
+    def _addressed(self, replica_id: int, frames) -> list:
+        """The REPLYs and BUSY signals among ``frames`` that are this
+        replica's, for this client, about a request still in flight, each
+        with the bytes its signature is over: decode, attribute (reference
+        client/message-handling.go:161-170), filter."""
+        msgs = []
+        for data in frames:
+            try:
+                msg = unmarshal(data)
+            except Exception:
+                continue
+            if not isinstance(msg, (Reply, Busy)):
+                continue
+            if msg.replica_id != replica_id or msg.client_id != self.client_id:
+                continue
+            pending = self._pending.get(msg.seq)
+            if pending is None or pending.result.done():
+                continue
+            msgs.append((msg, authen_bytes(msg)))
+        return msgs
+
+    def _precheck(self, msgs: list) -> None:
+        """Hand the authenticator what it is about to be asked, reply by
+        reply, so that it may verify the lot at once.  Nothing is decided
+        here: each message still goes through
+        ``verify_message_authen_tag`` on its own, and whatever wraps the
+        authenticator sees it there, once, with its verdict."""
         try:
-            msg = unmarshal(data)
+            ahead = self._precheck_tags(
+                api.AuthenticationRole.REPLICA,
+                [(m.replica_id, signed, m.signature) for m, signed in msgs],
+            )
         except Exception:
+            # the checks are then made one by one, and a fault that
+            # persists shows there, against the stream
+            self._log.exception("client %d: reply pre-check failed", self.client_id)
             return
+        if ahead:
+            self._count("batches")
+            self._count("off_lock", ahead)
+
+    def _count(self, name: str, n: int = 1) -> None:
+        """One of the reply checks' counters, this client's and the process's."""
+        for stats in (self.reply_checks, replycheck.TOTAL):
+            setattr(stats, name, getattr(stats, name) + n)
+
+    async def _handle_reply(self, msg, signed: bytes) -> None:
         if isinstance(msg, Busy):
-            await self._handle_busy(replica_id, msg)
+            await self._handle_busy(msg, signed)
             return
-        if not isinstance(msg, Reply):
-            return
-        # Authenticate and attribute (reference client/message-handling.go:161-170).
-        if msg.replica_id != replica_id or msg.client_id != self.client_id:
-            return
+        # Re-fetch: an earlier message of the frame may have resolved it.
         pending = self._pending.get(msg.seq)
         if pending is None or pending.result.done():
             return
+        self._count("checked")
         try:
             await self._auth.verify_message_authen_tag(
                 api.AuthenticationRole.REPLICA,
                 msg.replica_id,
-                authen_bytes(msg),
+                signed,
                 msg.signature,
             )
         except api.AuthenticationError:
@@ -356,34 +424,33 @@ class Client:
         pending = self._pending.get(msg.seq)
         if pending is not None:
             tr = self._trace
-            if tr is None:
-                pending.add_reply(msg)
-                return
             first = not pending.replies_by_replica
             was_done = pending.result.done()
             pending.add_reply(msg)
-            if first and pending.replies_by_replica:
-                tr.note(obs_trace.C_FIRST_REPLY, self.client_id, msg.seq)
             if not was_done and pending.result.done():
-                tr.note(obs_trace.C_QUORUM, self.client_id, msg.seq)
+                self._count("acked")
+            if tr is not None:
+                if first and pending.replies_by_replica:
+                    tr.note(obs_trace.C_FIRST_REPLY, self.client_id, msg.seq)
+                if not was_done and pending.result.done():
+                    tr.note(obs_trace.C_QUORUM, self.client_id, msg.seq)
 
-    async def _handle_busy(self, replica_id: int, msg: Busy) -> None:
+    async def _handle_busy(self, msg: Busy, signed: bytes) -> None:
         """A replica shed our REQUEST at its admission boundary: verify the
         signal (a forged BUSY must not be able to starve this client) and
         suppress retransmission of that request for ``retry_after_ms``.
         The pending request stays live — replies from less-loaded replicas
         (or this one, post-recovery) still resolve it; only the re-send
         pressure backs off."""
-        if msg.replica_id != replica_id or msg.client_id != self.client_id:
-            return
         pending = self._pending.get(msg.seq)
         if pending is None or pending.result.done():
             return
+        self._count("checked")
         try:
             await self._auth.verify_message_authen_tag(
                 api.AuthenticationRole.REPLICA,
                 msg.replica_id,
-                authen_bytes(msg),
+                signed,
                 msg.signature,
             )
         except api.AuthenticationError:

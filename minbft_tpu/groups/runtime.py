@@ -129,6 +129,11 @@ class GroupAuthenticator(api.Authenticator):
             role, peer_id, self._msg(msg), tag
         )
 
+    def precheck_message_authen_tags(self, role: api.AuthenticationRole, items) -> int:
+        return self._base.precheck_message_authen_tags(
+            role, [(p, self._msg(m), t) for p, m, t in items]
+        )
+
     @property
     def supports_batch_verify(self) -> bool:
         return self._base.supports_batch_verify

@@ -112,6 +112,16 @@ int EVP_PKEY_fromdata(EVP_PKEY_CTX *ctx, EVP_PKEY **ppkey, int selection,
 /* selection constant: public key portions */
 #define EVP_PKEY_PUBLIC_KEY 0x86
 
+/* One-shot signature verification over a message (Ed25519 takes no
+ * digest: type NULL). */
+typedef struct evp_md_ctx_st EVP_MD_CTX;
+EVP_MD_CTX *EVP_MD_CTX_new(void);
+void EVP_MD_CTX_free(EVP_MD_CTX *ctx);
+int EVP_DigestVerifyInit(EVP_MD_CTX *ctx, EVP_PKEY_CTX **pctx,
+                         const EVP_MD *type, ENGINE *e, EVP_PKEY *pkey);
+int EVP_DigestVerify(EVP_MD_CTX *ctx, const unsigned char *sigret,
+                     size_t siglen, const unsigned char *tbs, size_t tbslen);
+
 #ifdef __cplusplus
 }
 #endif

@@ -90,6 +90,40 @@ int usig_verify_ui(const uint8_t pub[64], uint64_t epoch_be,
                    const uint8_t digest[32], uint64_t counter,
                    const uint8_t sig[64]);
 
+/* Batch signature verification for the host path (the clients' reply
+ * checks): one call a batch, so that a caller which lets go of its
+ * interpreter lock around a foreign call lets go of it once a batch, and
+ * the batch's items are verified side by side on the helper threads of
+ * sigv_pool_start, where there are any, and on the caller's own.  Keys
+ * are parsed once (sigv_key_new) and may be used by any number of
+ * concurrent sigv_verify_many calls; verdicts are OpenSSL's. */
+enum {
+  SIGV_ECDSA_P256 = 1, /* key x||y (64B); message = the digest signed */
+  SIGV_ED25519 = 2,    /* key 32B; message = the bytes signed */
+};
+
+/* NULL when the bytes are no public key of the scheme (wrong length, a
+ * point off the curve). */
+void *sigv_key_new(int scheme, const uint8_t *pub, size_t pub_len);
+void sigv_key_free(void *key);
+
+/* Item i: key keys[i] (NULL reads as invalid), message
+ * msgs[msg_off[i] .. msg_off[i+1]), signature sigs[64*i .. 64*i+64)
+ * (r||s big-endian for ECDSA, R||S for Ed25519).  Writes valid[i] = 1 or
+ * 0.  Returns USIG_OK, or USIG_ERR_ARG and writes nothing. */
+int sigv_verify_many(int scheme, size_t n, void *const *keys,
+                     const uint8_t *msgs, const uint32_t *msg_off,
+                     const uint8_t *sigs, uint8_t *valid);
+
+/* Helper threads for sigv_verify_many, shared by the process: each start
+ * takes a place (the first makes the threads, later ones find them), each
+ * stop gives one back, and the last stop ends and joins the threads.  A
+ * call in flight holds a place of its own.  Without helpers a batch is
+ * verified on the caller's thread alone. */
+int sigv_pool_start(int threads);
+void sigv_pool_stop(void);
+int sigv_pool_threads(void);
+
 /* Library build id, for the capability probe. */
 const char *usig_native_version(void);
 

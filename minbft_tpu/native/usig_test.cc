@@ -20,7 +20,118 @@
     }                                                                   \
   } while (0)
 
+namespace {
+
+std::vector<uint8_t> unhex(const char *hex) {
+  std::vector<uint8_t> out;
+  for (size_t i = 0; hex[i] != 0 && hex[i + 1] != 0; i += 2) {
+    unsigned v = 0;
+    std::sscanf(hex + i, "%2x", &v);
+    out.push_back(static_cast<uint8_t>(v));
+  }
+  return out;
+}
+
+/* sigv_verify_many: known-answer vectors (RFC 6979 A.2.5's P-256 key over
+ * SHA-256("sample"), RFC 8032 7.1 test 2), each beside its broken
+ * neighbours in ONE batch, then the same batch from four threads over the
+ * same parsed keys (the reply checker keeps two batches out at once). */
+int test_verify_many() {
+  const std::vector<uint8_t> pub = unhex(
+      "60fed4ba255a9d31c961eb74c6356d68c049b8923b61fa6ce669622e60f29fb6"
+      "7903fe1008b8bc99a41ae9e95628bc64f2f1b20c2d7e9f5177a3c294d4462299");
+  const std::vector<uint8_t> digest = unhex(
+      "af2bdbe1aa9b6ec1e2ade1d694f41fc71a831d0268e9891562113d8a62add1bf");
+  const std::vector<uint8_t> sig = unhex(
+      "efd48b2aacb6a8fd1140dd9cd45e81d69d2c877b56aaf991c34d0ea84eaf3716"
+      "f7cb1c942d657c41d436c7a1b6e29f65f3e900dbb9aff4064dc4ab2f843acda8");
+  void *key = sigv_key_new(SIGV_ECDSA_P256, pub.data(), pub.size());
+  CHECK(key != nullptr);
+  std::vector<uint8_t> off_curve = pub;
+  off_curve[63] ^= 1;
+  CHECK(sigv_key_new(SIGV_ECDSA_P256, off_curve.data(), 64) == nullptr);
+  CHECK(sigv_key_new(SIGV_ECDSA_P256, pub.data(), 63) == nullptr);
+  CHECK(sigv_key_new(7, pub.data(), 64) == nullptr);
+
+  /* items: valid, digest bit, signature bit, r = 0, no key, valid */
+  const size_t n = 6;
+  void *keys[n] = {key, key, key, key, nullptr, key};
+  std::vector<uint8_t> msgs, sigs;
+  uint32_t offs[n + 1] = {0};
+  for (size_t i = 0; i < n; ++i) {
+    msgs.insert(msgs.end(), digest.begin(), digest.end());
+    sigs.insert(sigs.end(), sig.begin(), sig.end());
+    offs[i + 1] = static_cast<uint32_t>(msgs.size());
+  }
+  msgs[32 * 1 + 5] ^= 0x10;
+  sigs[64 * 2 + 40] ^= 0x01;
+  std::memset(&sigs[64 * 3], 0, 32);
+  const uint8_t want[n] = {1, 0, 0, 0, 0, 1};
+  uint8_t got[n];
+  std::memset(got, 9, sizeof got);
+  CHECK(sigv_verify_many(SIGV_ECDSA_P256, n, keys, msgs.data(), offs,
+                         sigs.data(), got) == USIG_OK);
+  CHECK(std::memcmp(got, want, n) == 0);
+  CHECK(sigv_verify_many(SIGV_ECDSA_P256, 0, nullptr, nullptr, nullptr,
+                         nullptr, nullptr) == USIG_OK);
+  CHECK(sigv_verify_many(7, n, keys, msgs.data(), offs, sigs.data(), got) ==
+        USIG_ERR_ARG);
+
+  std::vector<std::thread> workers;
+  std::vector<int> bad(4, 0);
+  for (int t = 0; t < 4; ++t) {
+    workers.emplace_back([&, t] {
+      for (int round = 0; round < 16; ++round) {
+        uint8_t mine[n];
+        if (sigv_verify_many(SIGV_ECDSA_P256, n, keys, msgs.data(), offs,
+                             sigs.data(), mine) != USIG_OK ||
+            std::memcmp(mine, want, n) != 0)
+          ++bad[t];
+      }
+    });
+  }
+  for (auto &w : workers) w.join();
+  for (int t = 0; t < 4; ++t) CHECK(bad[t] == 0);
+  sigv_key_free(key);
+
+  const std::vector<uint8_t> epub = unhex(
+      "3d4017c3e843895a92b70aa74d1b7ebc9c982ccf2ec4968cc0cd55f12af4660c");
+  const std::vector<uint8_t> esig = unhex(
+      "92a009a9f0d4cab8720e820b5f642540a2b27b5416503f8fb3762223ebdb69da"
+      "085ac1e43e15996e458f3613d0f11d8c387b2eaeb4302aeeb00d291612bb0c00");
+  void *ekey = sigv_key_new(SIGV_ED25519, epub.data(), epub.size());
+  CHECK(ekey != nullptr);
+  CHECK(sigv_key_new(SIGV_ED25519, epub.data(), 31) == nullptr);
+  /* messages of different lengths in one batch: 0x72 (valid), the empty
+   * message and two bytes under 0x72's signature, and 0x72 again under
+   * S + L, the same scalar by a second name, which a strict verifier
+   * refuses */
+  void *ekeys[4] = {ekey, ekey, ekey, ekey};
+  const uint8_t emsgs[4] = {0x72, 0x72, 0x00, 0x72};
+  const uint32_t eoffs[5] = {0, 1, 1, 3, 4};
+  std::vector<uint8_t> esigs;
+  for (int i = 0; i < 4; ++i) esigs.insert(esigs.end(), esig.begin(), esig.end());
+  const std::vector<uint8_t> order = unhex(
+      "edd3f55c1a631258d69cf7a2def9de1400000000000000000000000000000010");
+  unsigned carry = 0;
+  for (int i = 0; i < 32; ++i) {
+    unsigned v = esig[32 + i] + order[i] + carry;
+    esigs[64 * 3 + 32 + i] = static_cast<uint8_t>(v);
+    carry = v >> 8;
+  }
+  uint8_t egot[4] = {9, 9, 9, 9};
+  CHECK(sigv_verify_many(SIGV_ED25519, 4, ekeys, emsgs, eoffs, esigs.data(),
+                         egot) == USIG_OK);
+  const uint8_t ewant[4] = {1, 0, 0, 0};
+  CHECK(std::memcmp(egot, ewant, 4) == 0);
+  sigv_key_free(ekey);
+  return 0;
+}
+
+}  // namespace
+
 int main() {
+  if (test_verify_many() != 0) return 1;
   usig_t *u = nullptr;
   CHECK(usig_init(&u, nullptr, 0) == USIG_OK);
 

@@ -460,8 +460,13 @@ def timeline() -> dict:
     - ``jax``: rows ``(event, t_end, duration_ns)``, every event of
       :data:`JAX_EVENTS` that took 1 ms or more;
     - ``client``: rows ``(client_id, seq, "start", t)``, one a request;
-    - ``loops``: obs/looplag.py's idle clocks, one per live loop.
+    - ``loops``: obs/looplag.py's idle clocks, one per live loop;
+    - ``reply_checks``: the clients' reply checks summed over the process
+      (utils/replycheck.py ``ReplyCheckStats``): native batches, checks,
+      how many of them off the interpreter lock and how many inline,
+      quorums formed, checks a write.
     """
+    from ..utils import replycheck
     from . import looplag
 
     dispatch = []
@@ -489,6 +494,7 @@ def timeline() -> dict:
             "dropped": client_dropped,
         },
         "loops": looplag.idle_clocks(),
+        "reply_checks": replycheck.TOTAL.to_dict(),
     }
 
 

@@ -73,8 +73,9 @@ def load(auto_build: bool = False) -> Optional[ctypes.CDLL]:
         lib = ctypes.CDLL(_LIB_PATH)
     except OSError:
         return None
-    if not hasattr(lib, "usig_init2") and auto_build:
-        # Stale build predating encrypted sealing (v3): rebuild + reload.
+    if not hasattr(lib, "sigv_pool_start") and auto_build:
+        # Stale build predating batch verification (or encrypted sealing,
+        # v3, before it): rebuild + reload.
         # The Makefile links to a temp name and renames, so the rebuilt
         # file is a fresh inode and dlopen yields a new handle.
         if build():
@@ -82,8 +83,9 @@ def load(auto_build: bool = False) -> Optional[ctypes.CDLL]:
                 lib = ctypes.CDLL(_LIB_PATH)
             except OSError:
                 return None
-    # A stale-but-functional pre-v3 library (no compiler to rebuild with)
-    # still serves everything except encrypted sealing — bind what exists.
+    # A stale-but-functional library (no compiler to rebuild with) still
+    # serves everything except encrypted sealing (pre-v3) and batch
+    # verification — bind what exists.
     _bind(lib)
     _lib = lib
     return _lib
@@ -126,6 +128,28 @@ def _bind(lib) -> None:
         ctypes.c_char_p,
     ]
     lib.usig_native_version.restype = ctypes.c_char_p
+    if hasattr(lib, "sigv_pool_start"):
+        lib.sigv_key_new.argtypes = [
+            ctypes.c_int,
+            ctypes.c_char_p,
+            ctypes.c_size_t,
+        ]
+        lib.sigv_key_new.restype = ctypes.c_void_p
+        lib.sigv_key_free.argtypes = [ctypes.c_void_p]
+        lib.sigv_key_free.restype = None
+        lib.sigv_verify_many.argtypes = [
+            ctypes.c_int,
+            ctypes.c_size_t,
+            ctypes.POINTER(ctypes.c_void_p),
+            ctypes.c_char_p,
+            ctypes.POINTER(ctypes.c_uint32),
+            ctypes.c_char_p,
+            ctypes.c_char_p,
+        ]
+        lib.sigv_pool_start.argtypes = [ctypes.c_int]
+        lib.sigv_pool_stop.argtypes = []
+        lib.sigv_pool_stop.restype = None
+        lib.sigv_pool_threads.argtypes = []
     if hasattr(lib, "usig_init2"):
         lib.usig_init2.argtypes = [
             ctypes.POINTER(ctypes.c_void_p),

@@ -35,6 +35,7 @@ Two tiers:
 
 from __future__ import annotations
 
+import ctypes
 import functools
 import hashlib
 import hmac
@@ -476,3 +477,101 @@ def ed25519_verify(pub: bytes, msg: bytes, sig: bytes) -> bool:
         except Exception:
             return False
     return ed25519_verify_py(pub, msg, sig)
+
+
+# ---------------------------------------------------------------------------
+# Batch verification through the native module (minbft_tpu/native): one
+# foreign call a batch.  ``cryptography``'s ``verify`` keeps the
+# interpreter lock for its whole length; ctypes lets go of the lock around
+# a foreign call, and this one goes through the whole batch inside, on the
+# module's helper threads (``sigv_pool_start``) beside the caller's own.
+# Same library (libcrypto.so.3), same verdicts as ``ecdsa_verify`` /
+# ``ed25519_verify`` item for item.
+
+NATIVE_SCHEMES = {"ecdsa-p256": 1, "ed25519": 2}
+# (scheme, public key) -> the parsed key's address in the native
+# module, None for bytes that are no key.  Entries live as long as the
+# process (a batch on another thread may be using them); past the cap a
+# key is parsed for its call alone.
+_NATIVE_KEYS: dict = {}
+_NATIVE_KEYS_MAX = 4096
+
+
+def native_verifier():
+    """The native module if it loads (building it on first use) and has
+    the batch call, else None: the callers keep their inline path."""
+    from ..usig import native  # usig.software imports this module
+
+    lib = native.load(auto_build=True)
+    return lib if lib is not None and hasattr(lib, "sigv_pool_start") else None
+
+
+def _native_key(lib, scheme: str, pub):
+    """-> (address or None, whether the caller must free it)."""
+    ident = (scheme, pub)
+    try:
+        return _NATIVE_KEYS[ident], False
+    except KeyError:
+        pass
+    if scheme == "ecdsa-p256":
+        x, y = pub
+        raw = (
+            x.to_bytes(32, "big") + y.to_bytes(32, "big")
+            if 0 <= x < 1 << 256 and 0 <= y < 1 << 256
+            else b""
+        )
+    else:
+        # the same gate as ed25519_verify: OpenSSL takes some
+        # non-canonical encodings that the other verifiers refuse
+        raw = pub if ed_decompress_cached(pub) is not None else b""
+    key = lib.sigv_key_new(NATIVE_SCHEMES[scheme], raw, len(raw)) if raw else None
+    if len(_NATIVE_KEYS) < _NATIVE_KEYS_MAX:
+        _NATIVE_KEYS[ident] = key
+        return key, False
+    return key, key is not None
+
+
+def verify_many(scheme: str, items, lib=None) -> list:
+    """``[(public key, message, signature), ...] -> [bool, ...]`` in one
+    call into native code.  ``ecdsa-p256``: key ``(x, y)``, message the
+    SHA-256 digest signed, signature ``r || s`` (64 bytes, big-endian).
+    ``ed25519``: key 32 bytes, message the bytes signed, signature
+    ``R || S``.  A signature of another length, or a digest of another
+    length than 32, reads False.  Holds the interpreter lock to pack the
+    batch and for none of its verifications; safe from any thread.
+    Raises RuntimeError where the native module is not to be had (ask
+    :func:`native_verifier` first)."""
+    lib = lib if lib is not None else native_verifier()
+    if lib is None:
+        raise RuntimeError("native batch verification is not available")
+    digest_only = scheme == "ecdsa-p256"
+    n = len(items)
+    if n == 0:
+        return []
+    keys = (ctypes.c_void_p * n)()
+    offsets = (ctypes.c_uint32 * (n + 1))()
+    msgs, sigs, owned = [], [], []
+    end = 0
+    for i, (pub, msg, sig) in enumerate(items):
+        if len(sig) == 64 and (len(msg) == 32 or not digest_only):
+            key, mine = _native_key(lib, scheme, pub)
+            if mine:
+                owned.append(key)
+            keys[i] = key
+            msgs.append(msg)
+            sigs.append(sig)
+            end += len(msg)
+        else:  # keys[i] stays NULL: invalid, whatever the rest reads
+            sigs.append(bytes(64))
+        offsets[i + 1] = end
+    valid = ctypes.create_string_buffer(n)
+    try:
+        rc = lib.sigv_verify_many(
+            NATIVE_SCHEMES[scheme], n, keys, b"".join(msgs), offsets, b"".join(sigs), valid
+        )
+    finally:
+        for key in owned:
+            lib.sigv_key_free(key)
+    if rc != 0:
+        raise RuntimeError(f"sigv_verify_many failed (rc={rc})")
+    return [v != 0 for v in valid.raw]
