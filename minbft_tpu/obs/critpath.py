@@ -168,9 +168,11 @@ def engine_queue_doc(engine, ident: int = 0) -> dict:
                 "hits": st.key_table_hits,
                 "builds": st.key_table_builds,
                 "build_s": st.key_table_build_s,
+                "first_uses": st.key_table_first_uses,
+                "first_use_s": st.key_table_first_use_s,
             }
             for name, st in engine.stats.items()
-            if st.key_table_hits or st.key_table_builds
+            if st.key_table_hits or st.key_table_builds or st.key_table_first_uses
         },
     }
 

@@ -734,9 +734,11 @@ def _collect_engine(engine, base: Dict[str, str]) -> List[Family]:
             "dispatch_timeouts": [],
             "key_table_hits": [],
             "key_table_builds": [],
+            "key_table_first_uses": [],
         }
         seconds: Dict[str, List] = {
             "device": [], "host_prep": [], "key_table_build": [],
+            "key_table_first_use": [],
         }
         flushes: List = []
         occupancy: List = []
@@ -752,6 +754,9 @@ def _collect_engine(engine, base: Dict[str, str]) -> List[Family]:
             seconds["host_prep"].append((lb, st.host_prep_time_s))
             seconds["key_table_build"].append(
                 (lb, getattr(st, "key_table_build_s", 0.0))
+            )
+            seconds["key_table_first_use"].append(
+                (lb, getattr(st, "key_table_first_use_s", 0.0))
             )
             qw = getattr(st, "queue_wait", None)
             if qw is not None and (qw.count or qw.negatives):
@@ -805,6 +810,13 @@ def _collect_engine(engine, base: Dict[str, str]) -> List[Family]:
             fams.append((f"{p}_key_table_build_seconds_total", "counter",
                          "seconds of those builds (part of host prep)",
                          seconds["key_table_build"]))
+            fams.append((f"{p}_key_table_first_uses_total", "counter",
+                         "items served by one host scalar multiplication "
+                         "(a key's first use, no table yet)",
+                         counters["key_table_first_uses"]))
+            fams.append((f"{p}_key_table_first_use_seconds_total", "counter",
+                         "seconds of those multiplications (part of host prep)",
+                         seconds["key_table_first_use"]))
         fams.append((f"{p}_flushes_total", "counter",
                      "queue flushes by reason (full/idle/timer/completion)",
                      flushes))

@@ -15,7 +15,7 @@ Division of labor (TPU-first):
   by key in a bounded LRU (:class:`_KeyTables`).  A deployment's keys are
   few and stable — the clients, replicas and USIG identities of one key
   store — and :func:`prime_key_tables` builds them before a replica serves;
-  any other key is served once by a host scalar multiplication (~1.5 ms)
+  any other key is served once by a host scalar multiplication (~0.3 ms)
   and gets its table when it comes back.
 - **Host, per batch**: hashes variable-length bytes to the fixed 32-byte
   digest ``z`` (:func:`minbft_tpu.messages.authen_digest`), computes
@@ -469,19 +469,14 @@ def comb_table(point: Tuple[int, int]) -> np.ndarray:
 
 def _scalar_mult_row(k: int, point: Tuple[int, int]) -> np.ndarray:
     """``k * point`` (0 < k < n, point ON the curve) as one table row, [32]
-    u16: a 4-bit window ladder in Python integers, ~1.5 ms.  For the first
-    use of a key that has no comb table (see :class:`_KeyTables`).  The
-    accumulator 16*m*Q never meets its addend v*Q: 16*m + v <= k < n."""
-    small = _small_multiples_host((point[0], point[1], 1))
-    acc = None
-    for shift in range(252, -1, -4):
-        if acc is not None:
-            for _ in range(4):
-                acc = _jac_dbl_host(acc)
-        v = (k >> shift) & 0xF
-        if v:
-            acc = small[v - 1] if acc is None else _jac_add_host(acc, small[v - 1])
-    return limbs.to_limbs_batch(_affine_mont_host([acc])).reshape(_COMB_ROW).astype(np.uint16)
+    u16, Montgomery domain: one host scalar multiplication
+    (:func:`hostcrypto.point_mult`, ~0.3 ms through OpenSSL).  For the first
+    use of a key that has no comb table (see :class:`_KeyTables`)."""
+    from ..utils import hostcrypto as hc
+
+    x, y = hc.point_mult(k, point)
+    r_mont = (1 << 256) % P
+    return limbs.to_limbs_batch([x * r_mont % P, y * r_mont % P]).reshape(_COMB_ROW).astype(np.uint16)
 
 
 # G's comb table as the kernels close over it: [64, 16, 2, NLIMBS] u32.
@@ -497,11 +492,14 @@ class KeyTableTally:
     """What one or more :func:`prepare_packed` calls did with the key
     tables: ``hits`` items whose key's table was cached, ``builds`` tables
     built (by priming, or inside the call that met a key for the second
-    time) and the seconds they took."""
+    time) and the seconds they took, ``first_uses`` items served by one
+    host scalar multiplication (a key's first use) and their seconds."""
 
     hits: int = 0
     builds: int = 0
     build_s: float = 0.0
+    first_uses: int = 0
+    first_use_s: float = 0.0
 
 
 class _KeyTables:
@@ -514,7 +512,7 @@ class _KeyTables:
 
     A key gets its table when it is primed (:meth:`ensure`) or on its
     SECOND use; its first use is served by one scalar multiplication on
-    the host (:func:`prepare_packed`), ~1.5 ms against a build's ~10 ms.
+    the host (:func:`prepare_packed`), ~0.3 ms against a build's ~10 ms.
     So a key that is used once (an engine's warm-up item, a calibration
     dispatch, a probe) costs no build and no slot, and a table is paid for
     only by a key that comes back.
@@ -651,9 +649,8 @@ def prepare_packed(
         nib = ((u2[idx][:, :, None] >> _NIBBLE_SHIFTS) & 0xF).reshape(
             len(idx), _COMB_WINDOWS
         )
-        got, have = _KEY_TABLES.rows(
-            keys, nib, tally if tally is not None else KeyTableTally()
-        )
+        tally = tally if tally is not None else KeyTableTally()
+        got, have = _KEY_TABLES.rows(keys, nib, tally)
         for k in np.flatnonzero(~have):  # a key without a table
             lane = idx[k]
             if not is_on_curve(*keys[k]):
@@ -661,9 +658,12 @@ def prepare_packed(
                 continue
             # First use of the key: its one row is u2*Q itself, in window
             # 0, and the scalar the kernel reads the windows from is 1.
+            t0 = time.perf_counter()
             got[k, 0] = _scalar_mult_row(limbs.from_limbs_batch(u2[lane : lane + 1])[0], keys[k])
             u2[lane] = 0
             u2[lane, 0] = 1
+            tally.first_uses += 1
+            tally.first_use_s += time.perf_counter() - t0
         out[idx, :_Q_COLS] = got.reshape(len(idx), _Q_COLS)
     c = _Q_COLS
     out[:n, c : c + L] = u1

@@ -177,10 +177,14 @@ class VerifyStats:
     # The ECDSA queue's per-key comb tables (ops/p256.py): items whose
     # key's table was cached, tables built inside a dispatch's prep (the
     # second use of a key the key store's priming did not name, or of one
-    # evicted), and the seconds the builds took (part of host_prep_time_s).
+    # evicted), and the seconds the builds took (part of host_prep_time_s);
+    # items served by one host scalar multiplication instead (a key's first
+    # use: a warm-up item, a calibration dispatch, a probe) and their seconds.
     key_table_hits: int = 0
     key_table_builds: int = 0
     key_table_build_s: float = 0.0
+    key_table_first_uses: int = 0
+    key_table_first_use_s: float = 0.0
     memo_hits: int = 0
     dispatch_timeouts: int = 0  # hung device dispatches rescued on host
     # Flight-recorder gauges (event-loop-side updates only): why each
@@ -1207,6 +1211,8 @@ class BatchVerifier:
                 st.key_table_hits += tables.hits
                 st.key_table_builds += tables.builds
                 st.key_table_build_s += tables.build_s
+                st.key_table_first_uses += tables.first_uses
+                st.key_table_first_use_s += tables.first_use_s
 
     def _note_sign_prep(self, name: str, pad: int, prep_s: float) -> None:
         """Sign-queue sibling of :meth:`_note_prep` (worker thread):

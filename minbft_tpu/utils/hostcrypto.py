@@ -132,6 +132,34 @@ if _HAVE_OSSL:
     def _ossl_pub(x: int, y: int):
         return _ossl_ec.EllipticCurvePublicNumbers(x, y, _OSSL_CURVE).public_key()
 
+    def _ecdh_x(k: int, pub) -> int:
+        # not through _ossl_priv's cache: k is a one-off scalar, not a key
+        shared = _ossl_ec.derive_private_key(k, _OSSL_CURVE).exchange(_ossl_ec.ECDH(), pub)
+        return int.from_bytes(shared, "big")
+
+
+def point_mult(k: int, q: PointA) -> PointA:
+    """``k * q`` for 0 < k < N and ``q`` a finite point of the curve (of
+    prime order N, so the product is finite too).
+
+    Through OpenSSL's ECDH where it is there: ~0.3 ms against the
+    double-and-add's ~12 ms.  An exchange gives ``x(k*q)`` alone; a second,
+    ``x3 = x((k+1)*q)``, fixes y without a square root: the chord through
+    ``k*q`` and ``q`` has slope ``l = (y - yq) / (x - xq)`` with ``l^2 = x3
+    + x + xq``, and ``y^2 = x^3 - 3x + B``, so ``2 y yq = y^2 + yq^2 - (x3
+    + x + xq) (x - xq)^2``.  ``x == xq`` only for ``k*q = +-q``, that is k
+    = 1 or N - 1, so k + 1 < N wherever the second exchange is made."""
+    if not _HAVE_OSSL:
+        return scalar_mult(k, q)
+    xq, yq = q
+    pub = _ossl_pub(xq, yq)
+    x = _ecdh_x(k, pub)
+    if x == xq:
+        return (xq, yq) if k == 1 else (xq, P - yq)
+    chord = (_ecdh_x(k + 1, pub) + x + xq) * (x - xq) * (x - xq)
+    y = ((x * x + A) * x + B + yq * yq - chord) * _inv(2 * yq, P) % P
+    return x, y
+
 
 def keygen(rng=None) -> Tuple[int, PointA]:
     """-> (private scalar d, public point Q = d*G)."""

@@ -325,6 +325,15 @@ def test_key_table_hits_and_builds_are_counted_and_exported():
     doc = critpath.engine_queue_doc(eng)
     assert doc["key_tables"]["ecdsa_p256"]["hits"] == 3
     assert doc["key_tables"]["ecdsa_p256"]["builds"] == 1
+    # the one item before the build was the key's first use: one host
+    # scalar multiplication, counted and timed beside the builds
+    st = eng.stats["ecdsa_p256"]
+    assert st.key_table_first_uses == 1
+    assert 0.0 < st.key_table_first_use_s <= st.host_prep_time_s
+    assert 'minbft_verify_queue_key_table_first_uses_total{queue="ecdsa_p256",replica="0"} 1' in text
+    assert "minbft_verify_queue_key_table_first_use_seconds_total" in text
+    assert doc["key_tables"]["ecdsa_p256"]["first_uses"] == 1
+    assert doc["key_tables"]["ecdsa_p256"]["first_use_s"] == st.key_table_first_use_s
 
 
 def test_padded_lane_accounting_is_thread_safe():

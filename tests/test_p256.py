@@ -381,3 +381,50 @@ def test_what_the_benchmark_kernel_file_relies_on(keys, monkeypatch):
 
     assert asyncio.run(run()) is True  # every lane "valid": the patch was read
     assert seen == [(BUCKET, p256.PACKED_COLS)]
+
+
+# -- a key's first use: one host scalar multiplication ------------------------
+
+
+@pytest.mark.parametrize("k", [
+    1, 2, 3, 15, 16, hc.N - 1, hc.N - 2, hc.N - 3, (hc.N - 1) // 2, (hc.N + 1) // 2,
+    0x8000000000000000000000000000000000000000000000000000000000000000,
+    0x0A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A5A,
+])
+def test_point_mult_through_ecdh_equals_double_and_add(k):
+    """hostcrypto.point_mult takes x(k*Q) and x((k+1)*Q) from OpenSSL's ECDH
+    and solves for y: against the affine double-and-add, at both ends of
+    the scalar's range (k*Q = +-Q included) and under two keys."""
+    for d in (1, 0x1F2E3D4C5B6A79881726354453627180F0E1D2C3B4A5968778695A4B3C2D1E0F):
+        q = hc.scalar_mult(d, (hc.GX, hc.GY))
+        assert hc.point_mult(k, q) == hc.scalar_mult(k, q)
+
+
+def test_point_mult_without_openssl_is_the_double_and_add(monkeypatch):
+    q = hc.scalar_mult(7, (hc.GX, hc.GY))
+    want = hc.point_mult(0xDEADBEEF, q)
+    monkeypatch.setattr(hc, "_HAVE_OSSL", False)
+    assert hc.point_mult(0xDEADBEEF, q) == want == hc.scalar_mult(0xDEADBEEF, q)
+
+
+def test_a_first_use_row_is_the_point_in_the_montgomery_domain_and_is_counted(keys):
+    d, q = keys[0]
+    k = 0x1234567890ABCDEF1234567890ABCDEF1234567890ABCDEF1234567890ABCDEF
+    row = p256._scalar_mult_row(k, q)
+    assert row.shape == (32,) and row.dtype == np.uint16
+    r_inv = pow(1 << 256, -1, hc.P)
+    x, y = (v * r_inv % hc.P for v in limbs.from_limbs_batch(row.reshape(2, 16)))
+    assert (x, y) == hc.scalar_mult(k, q)
+    # in a batch: a key without a table is a first use, once a lane; a
+    # primed key's lane is a hit; a key off the curve is neither
+    p256._KEY_TABLES.clear()
+    d2, q2 = keys[1]
+    p256.prime_key_tables([q2])
+    digest = hashlib.sha256(b"first use").digest()
+    items = [(q, digest, hc.ecdsa_sign(d, digest)), (q2, digest, hc.ecdsa_sign(d2, digest)),
+             ((q[0] ^ 1, q[1]), digest, hc.ecdsa_sign(d, digest))]
+    tally = p256.KeyTableTally()
+    p256.prepare_packed(items, BUCKET, tally=tally)
+    assert (tally.first_uses, tally.hits, tally.builds) == (1, 1, 0)
+    assert 0.0 < tally.first_use_s < 1.0
+    assert _verdicts(items)[:3] == [True, True, False]
