@@ -244,8 +244,9 @@ def _seeded_inputs(seed: int, bucket: int):
 class _CompileClock:
     """JAX's own monitoring events, summed between ``take()`` calls: how a
     first call's seconds split into tracing (Python), lowering, and the
-    backend compile — which, on a persistent-cache hit, is the retrieval.
-    Tracing and lowering are paid by every process, whatever the cache."""
+    backend compile — which, on a persistent-cache hit, is the retrieval;
+    and the executables that came from the kernel store instead, which
+    traced, lowered and compiled nothing."""
 
     _PARTS = {
         "/jax/core/compile/jaxpr_trace_duration": "trace_s",
@@ -268,8 +269,11 @@ class _CompileClock:
         monitoring.unregister_event_listener(self._event)
 
     def _reset(self):
+        from minbft_tpu.utils import kernelstore
+
         self._secs = dict.fromkeys(self._PARTS.values(), 0.0)
         self._hits = 0
+        self._store = kernelstore.totals()
 
     def _duration(self, event, secs, **_kw):
         part = self._PARTS.get(event)
@@ -281,8 +285,15 @@ class _CompileClock:
             self._hits += 1
 
     def take(self) -> dict:
+        from minbft_tpu.utils import kernelstore
+
         out = {k: round(v, 2) for k, v in self._secs.items()}
         out["cache_hits"] = self._hits
+        # executables that came from the kernel store since the last take
+        # (utils/kernelstore.py): those calls traced and compiled nothing
+        now = kernelstore.totals()
+        out["store_loads"] = now["loads"] - self._store["loads"]
+        out["store_load_s"] = round(now["load_s"] - self._store["load_s"], 2)
         self._reset()
         return out
 
@@ -303,6 +314,11 @@ def _timed(fn, clock: _CompileClock, warm_runs: int = 5):
 
 
 def _first_call(first: dict) -> str:
+    if first["store_loads"] and first["trace_s"] < 1:
+        return (
+            f"first call {first['first_call_s']:7.2f} s = loaded from the "
+            f"kernel store in {first['store_load_s']:.1f} s"
+        )
     return (
         f"first call {first['first_call_s']:7.2f} s = trace {first['trace_s']:.1f}"
         f" + lower {first['lower_s']:.1f} + compile {first['compile_s']:.1f} s"

@@ -73,6 +73,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Iterable, List, Optional, Tuple
 
+from ..utils import kernelstore
 from . import clockalign
 from .hist import Log2Histogram
 
@@ -174,6 +175,9 @@ def engine_queue_doc(engine, ident: int = 0) -> dict:
             for name, st in engine.stats.items()
             if st.key_table_hits or st.key_table_builds or st.key_table_first_uses
         },
+        # the process's, not this engine's alone: what loading or building
+        # each kernel's executable cost (utils/kernelstore.py)
+        "kernel_store": kernelstore.stats(),
     }
 
 

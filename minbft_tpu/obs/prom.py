@@ -817,6 +817,7 @@ def _collect_engine(engine, base: Dict[str, str]) -> List[Family]:
             fams.append((f"{p}_key_table_first_use_seconds_total", "counter",
                          "seconds of those multiplications (part of host prep)",
                          seconds["key_table_first_use"]))
+            fams.extend(_collect_kernel_store(base))
         fams.append((f"{p}_flushes_total", "counter",
                      "queue flushes by reason (full/idle/timer/completion)",
                      flushes))
@@ -838,6 +839,35 @@ def _collect_engine(engine, base: Dict[str, str]) -> List[Family]:
                      "scrape (peak backlog the point-in-time gauge misses)",
                      peak_samples))
     return fams
+
+
+def _collect_kernel_store(base: Dict[str, str]) -> List[Family]:
+    """What loading or building each kernel's executable cost this process
+    (utils/kernelstore.py): a replica's start, so gauges that stand still
+    once it serves."""
+    from ..utils import kernelstore
+
+    rows = [
+        ({**base, "kernel": name}, row)
+        for name, row in kernelstore.stats().items()
+    ]
+    return [
+        ("minbft_kernel_store_"
+         + (field[:-2] + "_seconds" if field.endswith("_s") else field),
+         "gauge", text, [(lb, row[field]) for lb, row in rows])
+        for field, text in (
+            ("loads", "executables loaded from the kernel store"),
+            ("builds", "executables traced, lowered and compiled (a miss)"),
+            ("load_failures", "loads that fell back to building"),
+            ("save_failures", "built executables that could not be written"),
+            ("load_s", "seconds on the load path, failed loads included"),
+            ("read_s", "of those, reading the entries' files"),
+            ("deserialize_s", "of those, deserializing and loading"),
+            ("digest_s", "of those, making the key (the sources hashed once)"),
+            ("build_s", "seconds tracing, lowering and compiling"),
+            ("bytes", "bytes of entries read and written"),
+        )
+    ]
 
 
 class MetricsServer:

@@ -458,7 +458,11 @@ def timeline() -> dict:
       generation, which no pass walks) and ``thresholds``
       (placement.settle_collector sets both once warm-up ends);
     - ``jax``: rows ``(event, t_end, duration_ns)``, every event of
-      :data:`JAX_EVENTS` that took 1 ms or more;
+      :data:`JAX_EVENTS` that took 1 ms or more; beside them
+      ``kernel_store``, per kernel what its executable cost this process
+      (utils/kernelstore.py ``KernelStoreStats``): ``loads``, ``builds``,
+      ``load_failures``, ``load_s`` and inside it ``read_s``,
+      ``deserialize_s`` and ``digest_s``, ``build_s``, ``bytes``;
     - ``client``: rows ``(client_id, seq, "start", t)``, one a request;
     - ``loops``: obs/looplag.py's idle clocks, one per live loop;
     - ``reply_checks``: the clients' reply checks summed over the process
@@ -466,7 +470,7 @@ def timeline() -> dict:
       how many of them off the interpreter lock and how many inline,
       quorums formed, checks a write.
     """
-    from ..utils import replycheck
+    from ..utils import kernelstore, replycheck
     from . import looplag
 
     dispatch = []
@@ -488,6 +492,7 @@ def timeline() -> dict:
         "jax": {
             "rows": [(JAX_EVENTS[i], t, d) for i, t, d in jax_rows],
             "dropped": jax_dropped,
+            "kernel_store": kernelstore.stats(),
         },
         "client": {
             "rows": [(c, s, CLIENT_STAGES[st], t) for c, s, st, t in client_rows],

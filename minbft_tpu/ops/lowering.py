@@ -11,6 +11,8 @@ equivalence tests.
 
 from __future__ import annotations
 
+from ..utils import kernelstore
+
 _FORCE_MODE = None  # None = auto by backend | "unrolled" | "loop" | "block"
 
 
@@ -46,25 +48,110 @@ def use_unrolled() -> bool:
     return mode() == "unrolled"
 
 
-def per_mode_jit(fn):
-    """``jax.jit`` keyed by the active lowering mode.
+_UNSTORED = object()  # a key whose calls go through the plain jit
+
+
+def _default_device():
+    """Where a computation over uncommitted arguments runs."""
+    import jax
+
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.devices()[0]
+    if isinstance(dev, str):
+        return jax.devices(dev)[0]
+    return dev
+
+
+def _signature(args):
+    """-> ``(avals, device)`` of a call, read off its arguments without
+    tracing, or None where the call is not one the kernel store serves: a
+    tracer (the kernel called under another transformation), a Python
+    scalar, an argument spread over several devices."""
+    import jax
+    import numpy as np
+
+    avals = []
+    device = None
+    for a in args:
+        if type(a) is not np.ndarray:
+            if isinstance(a, jax.core.Tracer) or not isinstance(a, jax.Array):
+                return None
+            devices = a.devices()
+            if len(devices) != 1:
+                return None
+            if a.committed and device is None:
+                (device,) = devices
+        avals.append((a.shape, a.dtype))
+    return tuple(avals), device or _default_device()
+
+
+def per_mode_jit(fn, store=None):
+    """``jax.jit`` keyed by the active lowering mode, its executables kept
+    in the kernel store (utils/kernelstore.py).
 
     The mode is read from a Python global at *trace* time, which a plain
     module-level ``jax.jit`` would bake into its first compilation and then
     silently reuse for every mode (the jit cache keys on shapes only).  One
     jitted instance per mode keeps the caches — in-process and persistent —
-    honest."""
+    honest.
+
+    Where there is a store (``store``, else the process's own: none on the
+    CPU backend), the first call for a (mode, argument shapes and dtypes,
+    device) loads the kernel's compiled executable from it and traces
+    nothing; on a miss, or when anything on the load path goes wrong, it
+    traces, lowers and compiles as ``jax.jit`` would (through the compile
+    cache), writes the entry, and serves the ``jax.stages.Compiled`` from
+    then on.  A ``Compiled`` belongs to the device it was compiled for, so
+    a pinned engine on another chip gets an entry of its own.
+    ``store=False``: never (parallel/mesh.py's kernels, whose arguments
+    arrive unplaced and are sharded by the jit itself)."""
+    import threading
+
     import jax
 
-    cache = {}
+    jits = {}  # mode -> jax.jit(fn)
+    calls = {}  # (mode, avals, device) -> Compiled | _UNSTORED
+    locks = {}  # the same key -> the lock its first call holds
+    lock = threading.Lock()
+
+    def jit_for(m):
+        jitted = jits.get(m)
+        if jitted is None:
+            with lock:
+                jitted = jits.setdefault(m, jax.jit(fn))
+        return jitted
+
+    def first_call(st, m, key, args):
+        """Load or build the executable of ``key`` and make its first call
+        -> that call's result."""
+        with lock:
+            key_lock = locks.setdefault(key, threading.Lock())
+        with key_lock:
+            call = calls.get(key)
+            if call is None:
+                call, out = st.obtain(fn, key, args)
+                calls[key] = call = call or _UNSTORED
+                if out is not None:
+                    return out
+        if call is _UNSTORED:
+            return jit_for(m)(*args)
+        return call(*args)
 
     def wrapper(*args, **kwargs):
         m = mode()
-        jitted = cache.get(m)
-        if jitted is None:
-            jitted = jax.jit(fn)
-            cache[m] = jitted
-        return jitted(*args, **kwargs)
+        st = kernelstore.default_store() if store is None else store
+        sig = None if (not st or kwargs) else _signature(args)
+        if sig is None:
+            return jit_for(m)(*args, **kwargs)
+        key = (m,) + sig
+        call = calls.get(key)
+        if call is None:
+            return first_call(st, m, key, args)
+        if call is _UNSTORED:
+            return jit_for(m)(*args)
+        return call(*args)
 
     wrapper.__name__ = getattr(fn, "__name__", "kernel")
+    wrapper.__wrapped__ = fn
     return wrapper

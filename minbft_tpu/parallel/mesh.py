@@ -117,7 +117,10 @@ def sharded_ecdsa_kernel(mesh: Mesh):
             # the field code starts its accumulators from constants, which
             # the varying-axes typing would call replicated
             check_vma=False,
-        )
+        ),
+        # sharded by the jit itself, over arguments that arrive unplaced:
+        # not an executable of one device, so not the kernel store's
+        store=False,
     )
 
 

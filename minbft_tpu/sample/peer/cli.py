@@ -172,7 +172,25 @@ def build_parser(options: dict | None = None) -> argparse.ArgumentParser:
     )
     sub = p.add_subparsers(dest="command", required=True)
 
-    r = sub.add_parser("run", help="run a replica")
+    r = sub.add_parser(
+        "run",
+        help="run a replica",
+        description=(
+            "Run a replica.  With a device engine it warms its kernels "
+            "before it listens: it loads their compiled executables from "
+            "the kernel store, <compile cache>/kernel_store (the compile "
+            "cache is JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache; "
+            "MINBFT_JAX_CACHE=0 turns both off): on one TPU v5e chip the "
+            "engine is warm about 35 s after the process starts; the "
+            "first start of a tree traces and compiles them instead "
+            "(about a minute with a warm compile cache, minutes without) "
+            "and writes the store.  The store's entries are pickles: the "
+            "directory is as trusted as the checkout, is made 0700, and "
+            "nothing is loaded from a directory or file that another user "
+            "owns or that group or others can write.  To clear it, delete "
+            "the directory."
+        ),
+    )
     r.add_argument("id", type=int, help="replica id")
     r.add_argument(
         "--listen",
@@ -640,9 +658,14 @@ async def _run_replica(args) -> int:
         prime_key_tables(store)
     await warm_engines(to_warm, warm_schemes)
     if to_warm:
+        from ...utils import kernelstore
+
+        kernels = kernelstore.totals()
         print(
             f"replica {args.id} engine warm ({', '.join(warm_schemes)}) in "
-            f"{_time.monotonic() - t_warm:.1f}s",
+            f"{_time.monotonic() - t_warm:.1f}s: {kernels['loads']} kernels "
+            f"loaded from the kernel store, {kernels['builds']} traced and "
+            f"compiled",
             file=sys.stderr,
         )
     if grouped:

@@ -35,13 +35,19 @@ def cache_dir() -> str:
     return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
 
 
+def switched_on() -> bool:
+    """MINBFT_JAX_CACHE=0 turns the compile cache off, and with it the
+    kernel store beside it (utils/kernelstore.py)."""
+    return os.environ.get("MINBFT_JAX_CACHE", "1") != "0"
+
+
 def enable_compilation_cache(min_compile_secs: float = 1.0) -> str:
     """Turn the persistent compilation cache on and return its directory.
     Call before the first kernel compile (import time is fine — this only
     sets config, it never initializes a backend).  Disable entirely with
     MINBFT_JAX_CACHE=0 (returns "")."""
     record_jax_events()
-    if os.environ.get("MINBFT_JAX_CACHE", "1") == "0":
+    if not switched_on():
         return ""
     import jax
 
@@ -83,4 +89,5 @@ def entry_count(cache_dir: str) -> int:
         1
         for name in os.listdir(cache_dir)
         if not name.startswith(".") and not name.endswith("-atime")
+        and not os.path.isdir(os.path.join(cache_dir, name))  # the kernel store
     )
