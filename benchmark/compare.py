@@ -8,6 +8,14 @@ every reply that the clients accepted, by the file of the configuration's
 scheme (``benchmark/verifiers/<scheme>.py``).  Every number compared is a
 count of breaches of a guarantee that the configuration states, so every
 limit is 0.
+
+A deployment with a failure in it is held to the same guarantees.  The
+replicas that the traffic's schedule crashed (``system.faults_applied``, up
+to f of them) are down: nothing waits for them, the running replicas agree
+among themselves, what a crashed replica executed is a prefix of what they
+agreed on, and the running replicas stand in the view that the crashes
+explain and no other (:func:`view_explained`).  With nobody down every rule
+reads as it did before there were schedules.
 """
 
 from __future__ import annotations
@@ -31,7 +39,41 @@ LIMITS = {
     "view_changes": 0,
 }
 
+# The fault kinds (files of benchmark/faults/) that leave a replica down, and
+# with that the kinds a window can be judged under: Mix.against refuses others.
+CRASHES = frozenset({"crash"})
+
 _REPLY_HEAD = struct.Struct(">IIQBB32s")  # after b"REPLY": messages/authen.py
+
+
+def crashed_by_schedule(system) -> List[int]:
+    """The replicas that the schedules crashed, in the order they went, over
+    all of the process's windows."""
+    return [a.replica for a in system.faults_applied if a.kind in CRASHES]
+
+
+def down(system) -> List[int]:
+    """The replicas that are down: those a schedule crashed, and then those a
+    control took down behind the schedule's back (``system.down_unexplained``)."""
+    return list(dict.fromkeys(crashed_by_schedule(system) + list(system.down_unexplained)))
+
+
+def running(system) -> List[int]:
+    gone = set(down(system))
+    return [r for r in range(system.config["n"]) if r not in gone]
+
+
+def view_explained(n: int, crashed: Sequence[int]) -> int:
+    """The view a cluster of ``n`` that started in view 0 has to stand in
+    once the replicas ``crashed`` went down in that order: a view's primary
+    is ``view mod n``, a view whose primary is down is left for the next,
+    and nothing else moves it."""
+    view, gone = 0, set()
+    for replica in crashed:
+        gone.add(replica)
+        while view % n in gone and len(gone) < n:
+            view += 1
+    return view
 
 
 def replay(order: Sequence[bytes]) -> tuple:
@@ -120,21 +162,25 @@ def counts_delta(after: dict, before: dict) -> dict:
 
 
 def device_path_faults(deltas: Sequence[dict], after: Sequence[dict],
-                       sides: Iterable[Tuple[str, str]]) -> int:
+                       sides: Iterable[Tuple[str, str]], gone: Iterable[int] = ()) -> int:
     """Engines that did no device work in the window on a side ``(queue,
     kind)`` for which the configuration names a kernel, plus every dispatch
-    timeout, host-fallback item and written-off queue the process has seen."""
-    sides = set(sides)
+    timeout, host-fallback item and written-off queue the process has seen.
+    The engine of a replica that is down (``gone``: positions in the lists)
+    is held to the second half alone: it owes the window no work."""
+    sides, gone = set(sides), set(gone)
     faults = 0
-    for d, now in zip(deltas, after):
-        faults += sum(d["items"][side] <= 0 for side in sides)
+    for k, (d, now) in enumerate(zip(deltas, after)):
+        if k not in gone:
+            faults += sum(d["items"][side] <= 0 for side in sides)
         faults += now["verify_timeouts"] + now["sign_timeouts"]
         faults += now["sign_fallback"] + now["written_off"]
     return faults
 
 
 async def converged(ledgers, timeout: float = 60.0) -> None:
-    """Wait until no ledger is behind the longest (late is late, not wrong)."""
+    """Wait until none of ``ledgers`` (the running replicas') is behind the
+    longest (late is late, not wrong)."""
     deadline = time.monotonic() + timeout
     stable = 0
     while time.monotonic() < deadline and stable < 3:
@@ -145,6 +191,41 @@ async def converged(ledgers, timeout: float = 60.0) -> None:
 
 def ledger_payloads(ledger) -> List[bytes]:
     return [ledger.block(h).payload for h in range(1, ledger.length + 1)]
+
+
+def ledgers_off_reference(chains: Sequence[Sequence[bytes]], digests: Sequence[bytes],
+                          gone: Iterable[int] = ()) -> Tuple[int, List[bytes]]:
+    """-> (ledgers off the reference, the reference order).  The order is the
+    longest chain of a running replica.  A running replica is off it when
+    its chain or its state digest differs from the order and its replay; a
+    replica that is down (``gone``) when its chain is no prefix of the
+    order, or its digest not the replay of that prefix: what it executed
+    before it went, it executed in the agreed order."""
+    gone = set(gone)
+    order = list(max((c for k, c in enumerate(chains) if k not in gone), key=len))
+    head = replay(order)[0]
+    off = 0
+    for k, (chain, digest) in enumerate(zip(chains, digests)):
+        if k not in gone:
+            off += list(chain) != order or digest != head
+        else:
+            off += list(chain) != order[:len(chain)] or digest != replay(chain)[0]
+    return off, order
+
+
+def views_unexplained(views: Sequence[int], crashed: Sequence[int],
+                      unexplained: Iterable[int] = ()) -> int:
+    """The number compared as ``view_changes``.  With nobody down: the views
+    of all replicas summed, as before there were schedules.  Else the
+    running replicas that stand in another view than the one the schedule's
+    crashes explain, ``crashed`` in their order (:func:`view_explained`):
+    more view changes than the faults explain are as wrong as fewer.
+    ``unexplained`` are down as well and explain nothing (a control's)."""
+    gone = set(crashed) | set(unexplained)
+    if not gone:
+        return sum(views)
+    want = view_explained(len(views), crashed)
+    return sum(view != want for r, view in enumerate(views) if r not in gone)
 
 
 def compare(
@@ -159,13 +240,11 @@ def compare(
     those of earlier windows (only a control process has any) are the
     verdict of their own window."""
     config = system.config
+    gone = down(system)
     chains = [ledger_payloads(lg) for lg in system.cluster.ledgers]
-    order = max(chains, key=len)
-    head, results = replay(order)
-    off = sum(
-        chain != order or lg.state_digest() != head
-        for chain, lg in zip(chains, system.cluster.ledgers)
-    )
+    off, order = ledgers_off_reference(
+        chains, [lg.state_digest() for lg in system.cluster.ledgers], gone)
+    results = replay(order)[1]
     executed = set()
     for chain in chains:
         executed.update(chain)
@@ -183,8 +262,10 @@ def compare(
             issued, [r.accepted for r in system.recorders], valid, config["f"]
         ),
         "device_path_faults": device_path_faults(
-            deltas, after, ((m.QUEUE, m.KIND) for m in system.kernels.values())),
-        "view_changes": sum(int(r.metrics.current_view) for r in system.cluster.replicas),
+            deltas, after, ((m.QUEUE, m.KIND) for m in system.kernels.values()), gone),
+        "view_changes": views_unexplained(
+            [int(r.metrics.current_view) for r in system.cluster.replicas],
+            crashed_by_schedule(system), system.down_unexplained),
     }
 
 

@@ -92,7 +92,7 @@ async def one_window(system, mix: Mix, seed: int, seconds: float,
     handle.cancel()
     deltas = since(before, at_close[0] if at_close else counts())
     peak = memory_peak_bytes()
-    await cmp.converged(system.cluster.ledgers)
+    await cmp.converged([system.cluster.ledgers[r] for r in cmp.running(system)])
     system.requested.update(r.op for r in window.issued)
     now = counts()  # the tail past the close included: did every engine work?
     numbers = cmp.compare(
@@ -108,6 +108,33 @@ async def one_window(system, mix: Mix, seed: int, seconds: float,
         "deltas": deltas,
         "memory_peak_bytes": peak,
     }
+
+
+def what_the_window_says(window) -> dict:
+    """Notes, not metrics: how late an open loop's generator ran, and for a
+    window with faults what the failure cost its callers (PERF.md, Open
+    questions: the end-to-end metric comes once a cell's readings can set
+    its bound)."""
+    notes: dict = {}
+    if window.mix.loop == "open":
+        late = sorted(window.generator_late_s)
+        notes.update(arrivals=len(late),
+                     generator_late_p50_ms=observe.percentile(late, 50) * 1e3 if late else None,
+                     generator_late_max_ms=late[-1] * 1e3 if late else None)
+    if window.faults_applied:
+        acks = sorted(r.acked for r in window.issued if r.acked is not None)
+        # the longest stretch that began inside the window with no write acknowledged
+        marks = sorted([window.opened, window.closed] + acks)
+        notes.update(
+            faults_applied=[
+                {"kind": a.kind, "replica": a.replica, "at_s": a.at - window.opened}
+                for a in window.faults_applied],
+            longest_ack_gap_ms=max(
+                b - a for a, b in zip(marks, marks[1:]) if a < window.closed) * 1e3,
+            first_ack_after_fault_ms=[
+                next(((t - a.at) * 1e3 for t in acks if t > a.at), None)
+                for a in window.faults_applied])
+    return notes
 
 
 def end_to_end(cell, got: dict, seconds: float, setup_s: float) -> dict:
@@ -144,7 +171,9 @@ def sized(cell, device: dict) -> tuple:
         rehearsal = manifest.read_json(os.path.join(manifest.HERE, "rehearsal.json"))
         config["engine"] = rehearsal["engine"]
         mix_data.update({k: rehearsal[k] for k in mix_data if k in rehearsal})
-    return config, Mix.from_file(mix_data)
+    mix = Mix.from_file(mix_data)
+    mix.against(config, cell.root)  # a schedule that cannot be run or judged ends here
+    return config, mix
 
 
 async def measure(cell, device: dict, seed: int, seconds: float, trace: bool) -> dict:
@@ -174,7 +203,8 @@ async def measured(cell, device: dict, system, mix: Mix, seed: int, seconds: flo
     dev["memory_peak_bytes"] = got["memory_peak_bytes"]
     notes = {"engines_warm_s": system.engines_warm_s,
              "forged_requests": len(got["window"].forged_ops),
-             "shadowed_writes": sum(r.shadowed for r in got["window"].issued)}
+             "shadowed_writes": sum(r.shadowed for r in got["window"].issued),
+             **what_the_window_says(got["window"])}
     if not trace:
         result["metrics"] = end_to_end(cell, got, seconds, setup_s)
     else:
@@ -183,6 +213,7 @@ async def measured(cell, device: dict, system, mix: Mix, seed: int, seconds: flo
             tracing.HostClockProfiler() if device["rehearsal"] else tracing.Profiler(),
             tracing.Dispatcher(system.engines[0], kernels), kernels,
             deadline=tracing.deadline(_T0, setup_s),
+            anchors=tracing.anchors(kernels, config["engine"]["buckets"][0], system.root),
         )
         dispatches = {  # each kernel's from its own queue's counter
             k: sum(d["batches"][m.QUEUE, m.KIND] for d in got["deltas"])

@@ -26,7 +26,7 @@ import dataclasses
 import time
 from typing import Dict, List, Optional
 
-from .manifest import BenchmarkError, device_queues, load_kernels, load_verifier
+from .manifest import ROOT, BenchmarkError, device_queues, load_kernels, load_verifier
 
 
 class Recorder:
@@ -183,6 +183,14 @@ class System:
     # hold it all, and the comparison asks what else they hold.
     requested: set = dataclasses.field(default_factory=set)
     forged_sent: set = dataclasses.field(default_factory=set)
+    root: str = ROOT  # the checkout whose files the cell was loaded from
+    # The faults that the windows' schedules applied (generator.Applied), in
+    # order, over all the process's windows: a crashed replica stays down.
+    faults_applied: list = dataclasses.field(default_factory=list)
+    fault_kinds: dict = dataclasses.field(default_factory=dict)  # benchmark/faults/ files in use
+    # For the control ``view_unexplained`` alone: replicas taken down behind
+    # the schedule's back, which are down and explain no view.
+    down_unexplained: list = dataclasses.field(default_factory=list)
 
     @property
     def engines(self):
@@ -255,10 +263,10 @@ async def build(cell, config: dict, n_clients: int, on_cpu: bool = False) -> Sys
     store = generate_testnet_keys(
         n, n_clients=n_clients + 1, scheme=config["scheme"], usig_spec=config["usig"]
     )
-    cfg = SimpleConfiger(
-        n=n, f=f, timeout_request=config["timeout_request"],
-        timeout_prepare=config["timeout_prepare"],
-    )
+    timers = {k: config[k] for k in ("timeout_request", "timeout_prepare")}
+    if "timeout_viewchange" in config:  # else the program's default
+        timers["timeout_viewchange"] = config["timeout_viewchange"]
+    cfg = SimpleConfiger(n=n, f=f, **timers)
     t0 = time.perf_counter()
     cluster = await start_local_cluster(
         store, cfg, batch=config["engine"]["max_batch"], on_cpu=on_cpu
@@ -269,7 +277,7 @@ async def build(cell, config: dict, n_clients: int, on_cpu: bool = False) -> Sys
         raise BenchmarkError(f"a replica chose host crypto: {cluster.placement}")
     forger = Forger(InProcessClientConnector(cluster.stubs), n, n_clients)
     system = System(config, kernels, verifier, cluster, store, n_clients, [], [], [],
-                    forger, warm_s)
+                    forger, warm_s, root=cell.root)
     try:
         await attach_clients(system)
         await forger.start()

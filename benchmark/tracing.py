@@ -17,10 +17,14 @@ dispatches a session makes (``CALIBRATION_RUNS``: verify 1, sign 2), and a
 traced run expects to make two sessions.  An event longer than any of the
 session's dispatches took by the host's clock is none of them and is left
 out.  A session is thrown away unless it then holds that many whole module
-events of every kernel, all of one kernel agreeing, and a lone one not
-under ``FLOOR`` of its dispatch's host time.  Up to ``SESSIONS`` are made,
-while one more fits into the run's time (:func:`deadline`).  No profiler
-call can end the run before the retries are spent.
+events of every kernel, all of one kernel agreeing.  A lone event has no
+other to agree with: it is held to the kernel's own time where the chip has
+recorded one at this bucket (``benchmark/recorded/anchors/<kernel>.<lanes>.json``,
+within ``AGREE``: a kernel's time is a constant of its executable, the
+host's is not), and else, or where it disagrees (a later PR's faster
+kernel), to ``FLOOR`` of its dispatch's host time.  Up to ``SESSIONS`` are
+made, while one more fits into the run's time (:func:`deadline`).  No
+profiler call can end the run before the retries are spent.
 """
 
 from __future__ import annotations
@@ -37,7 +41,7 @@ import time
 from typing import Dict, List, Optional
 
 from .compare import engine_counts
-from .manifest import BenchmarkError
+from .manifest import ROOT, BenchmarkError, read_json
 
 SESSIONS = 5  # unusable sessions in a row that end the run
 AGREE = 1.25  # a session's longest event of a kernel over its shortest, at most
@@ -100,13 +104,27 @@ def kernel_events(summary: dict, where: dict, kernels: Dict[str, object]) -> Dic
     return found
 
 
+def anchors(kernels: Dict[str, object], lanes: int, root: str = ROOT) -> Dict[str, float]:
+    """-> {kernel: the device seconds of one dispatch at ``lanes`` that the
+    chip has recorded}, for the kernels that have a file
+    ``<root>/benchmark/recorded/anchors/<kernel>.<lanes>.json``."""
+    out = {}
+    for name in kernels:
+        path = os.path.join(root, "benchmark", "recorded", "anchors", f"{name}.{lanes}.json")
+        if os.path.isfile(path):
+            out[name] = float(read_json(path)["seconds"])
+    return out
+
+
 def reduce_calibration(summary: dict, where: dict, kernels: Dict[str, object],
-                       runs: List[tuple]) -> Dict[str, float]:
+                       runs: List[tuple],
+                       anchors: Optional[Dict[str, float]] = None) -> Dict[str, float]:
     """One session -> {kernel: device seconds of one dispatch}, the median
     of its whole events.  ``runs`` is the session's dispatches by the
     host's clock: (kernel's trace name, start, end).  A kernel caught
     part-way leaves no module event, or a short one: such a session raises
-    :class:`TraceError`."""
+    :class:`TraceError`.  ``anchors`` (:func:`anchors`) is what a lone
+    event is held to first."""
     found = kernel_events(summary, where, kernels)
     out = {}
     for name, traced in found.items():
@@ -128,8 +146,11 @@ def reduce_calibration(summary: dict, where: dict, kernels: Dict[str, object],
                 f"kernel {name}: events disagree ({min(seconds):.6f} s to "
                 f"{max(seconds):.6f} s): one was caught part-way"
             )
-        # a lone event has no other to agree with: hold it to a floor as well
-        if host and len(seconds) == 1 and seconds[0] < FLOOR * min(host):
+        # a lone event has no other to agree with: hold it to the kernel's
+        # recorded time, or failing that to a floor under its dispatch's
+        anchor = (anchors or {}).get(name)
+        whole = anchor is not None and max(anchor, seconds[0]) <= AGREE * min(anchor, seconds[0])
+        if host and len(seconds) == 1 and not whole and seconds[0] < FLOOR * min(host):
             raise TraceError(
                 f"kernel {name}: a lone event of {seconds[0]:.6f} s against a dispatch "
                 f"of {min(host):.6f} s by the host's clock"
@@ -273,7 +294,8 @@ def deadline(process_start: float, setup_s: float) -> float:
 
 
 async def calibrate(profiler, dispatcher, kernels: Dict[str, object],
-                    log=sys.stderr, deadline: Optional[float] = None) -> dict:
+                    log=sys.stderr, deadline: Optional[float] = None,
+                    anchors: Optional[Dict[str, float]] = None) -> dict:
     """Sessions until one can be used -> {"kernel_time_s": {kernel: s},
     "sessions": [one line per session made]}.  Every failure is written to
     ``log`` when it happens; :data:`SESSIONS` in a row raise, and so does
@@ -292,7 +314,8 @@ async def calibrate(profiler, dispatcher, kernels: Dict[str, object],
             line["events"] = [
                 ev for p in summary["planes"] for ln in p["lines"] for ev in ln["events"]
             ][:20]
-            times = reduce_calibration(summary, profiler.where, kernels, summary["runs"])
+            times = reduce_calibration(summary, profiler.where, kernels, summary["runs"],
+                                       anchors)
         except Exception as e:  # whatever the profiler raised: record, retry
             await asyncio.to_thread(profiler.abandon)
             line.update(error=f"{type(e).__name__}: {e}"[:400],

@@ -50,17 +50,28 @@ def test_manifest_has_the_contracts_keys_and_limits():
 
 
 @pytest.mark.parametrize("name", CELLS)
-def test_cell_loads_with_its_config_traffic_and_readers(name):
+def test_cell_agrees_with_its_own_files(name):
+    """(Named ``test_cell_loads_with_its_config_traffic_and_readers`` until PR
+    35: under that name ``tests/conftest.py`` expects the ``closed-1x1`` case
+    to fail, for the pin on 16 x 8 that went with the name.)"""
     cell = manifest.load_cell(name, MANIFEST)
     entry = next(c for c in MANIFEST["configs"] if c["name"] == cell.config_name)
     assert entry["file"].startswith("benchmark/configs/")
-    assert cell.config["name"] == cell.config_name and cell.chips == cell.config["chips"] == 1
+    assert cell.config["name"] == cell.config_name
+    assert cell.chips == cell.config["chips"] and cell.chips in (1, 4)
     assert set(entry["reduced"]) == set(cell.config["reduced"])
     assert {"reply_quorum", "durability", "agreement", "device_path"} <= set(cell.config["guarantees"])
+    # its traffic loads, and can be run and judged on its configuration
     mix = Mix.from_file(cell.traffic)
-    assert (mix.loop, mix.clients, mix.depth) == ("closed", 16, 8)
-    assert {m["name"] for m in cell.end_to_end} == {
-        "goodput_rps", "finality_mean_ms", "finality_p95_ms", "setup_s"}
+    mix.against(cell.config, cell.root)
+    if mix.loop == "closed":
+        assert mix.clients >= 1 and mix.depth >= 1
+    else:
+        assert mix.clients >= 1 and not isinstance(mix.rate_rps, bool) and mix.rate_rps > 0
+    # its end-to-end metrics are the manifest's entries that admit it
+    assert [m["name"] for m in cell.end_to_end] == [
+        m["name"] for m in MANIFEST["end_to_end"] if name in m.get("workloads", [name])]
+    assert "setup_s" in {m["name"] for m in cell.end_to_end} and len(cell.end_to_end) >= 2
     # its per-layer metrics are the manifest's: every entry that admits the
     # cell and moves a metric the cell reports, each with its reader's file
     reported = {m["name"] for m in cell.end_to_end}
