@@ -17,7 +17,7 @@ import asyncio
 from collections import OrderedDict
 from typing import Callable, Dict, Optional
 
-from .timer import TimerProvider, StandardTimerProvider
+from .timer import NO_TIMERS, TimerProvider, StandardTimerProvider
 
 
 class ClientState:
@@ -324,6 +324,15 @@ class ClientState:
     def stop_prepare_timer(self, seq: int) -> None:
         self._stop_timer(self._prepare_timers, seq)
 
+    def stop_timers(self) -> None:
+        """Cancel every request and prepare timer of this client and arm
+        none from now on."""
+        self._timers = NO_TIMERS
+        for timers in (self._request_timers, self._prepare_timers):
+            for t in timers.values():
+                t.cancel()
+            timers.clear()
+
 
 class ClientStates:
     """Lazily-populated per-client provider (reference client-state.go:36-55)."""
@@ -347,6 +356,13 @@ class ClientStates:
 
     def all(self):
         return self._clients.items()
+
+    def stop_timers(self) -> None:
+        """Cancel every client's request and prepare timers; from now on
+        :attr:`timers` arms nothing."""
+        self._timers = NO_TIMERS
+        for st in self._clients.values():
+            st.stop_timers()
 
     def retire_watermarks(self):
         """Deterministic snapshot of the per-client retire state — part

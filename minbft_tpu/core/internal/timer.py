@@ -37,6 +37,23 @@ class StandardTimerProvider(TimerProvider):
         return _StandardTimer(loop.call_later(delay, callback))
 
 
+class _Unarmed(Timer):
+    def cancel(self) -> None:
+        pass
+
+
+class NoTimerProvider(TimerProvider):
+    """Arms nothing: a stopped replica's provider (a message still queued
+    at the stop may finish its validation later and ask for a timer)."""
+
+    def after(self, delay: float, callback: Callable[[], None]) -> Timer:
+        return _UNARMED
+
+
+_UNARMED = _Unarmed()
+NO_TIMERS = NoTimerProvider()
+
+
 class FakeTimer(Timer):
     def __init__(self, provider: "FakeTimerProvider", delay: float, callback):
         self.provider = provider

@@ -21,6 +21,7 @@ Asyncio re-design notes:
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import logging
 import time
 from collections import OrderedDict
@@ -77,6 +78,14 @@ from .internal.messagelog import MessageLog
 from .internal.peerstate import PeerStates
 from .internal.requestlist import RequestList
 from .internal.viewstate import ViewState
+
+# The certificate checks of one VIEW-CHANGE or NEW-VIEW validation that
+# reach the authenticator (verify_ui's memo misses), counted into the
+# one-item list this holds while the validation runs; the process
+# timeline's ``viewchange`` section gets one row a validation.
+_VIEWCHANGE_CHECKS: contextvars.ContextVar = contextvars.ContextVar(
+    "viewchange_checks", default=None
+)
 
 
 class _PrepareBatcher:
@@ -293,6 +302,9 @@ class Handlers:
             key = ("ui", msg.replica_id, authen_bytes(msg), ui.counter, ui.cert)
             if _verified_hit(key):
                 return ui
+            checks = _VIEWCHANGE_CHECKS.get()
+            if checks is not None:
+                checks[0] += 1
             ui = await base_verify_ui(msg)
             _verified_put(key)
             return ui
@@ -664,14 +676,32 @@ class Handlers:
         self.validate_commit = commit_mod.make_commit_validator(
             n, self.validate_prepare, self.verify_ui
         )
+        def counting_checks(validate):
+            async def validate_counted(msg) -> None:
+                checks = [0]
+                token = _VIEWCHANGE_CHECKS.set(checks)
+                try:
+                    await validate(msg)
+                finally:
+                    _VIEWCHANGE_CHECKS.reset(token)
+                    obs_trace.note_viewchange_items(
+                        replica_id, msg.new_view, checks[0]
+                    )
+
+            return validate_counted
+
         self.validate_view_change = _cached_validator(
-            viewchange_mod.make_view_change_validator(
-                verify_ui, self.validate_checkpoint_cert
+            counting_checks(
+                viewchange_mod.make_view_change_validator(
+                    verify_ui, self.validate_checkpoint_cert
+                )
             )
         )
         self.validate_new_view = _cached_validator(
-            viewchange_mod.make_new_view_validator(
-                n, f, verify_ui, self.validate_view_change
+            counting_checks(
+                viewchange_mod.make_new_view_validator(
+                    n, f, verify_ui, self.validate_view_change
+                )
             )
         )
 
@@ -1759,6 +1789,19 @@ class Handlers:
             await self.view_state.advance_expected_view(state.view)
             await self.view_state.advance_current_view(state.view)
 
+    def stop_timers(self) -> None:
+        """Cancel every timer this replica has armed (the clients' request
+        and prepare timers, the view-change and the state-transfer timer)
+        and arm none again: a message still in the engine's queues when
+        the replica stopped may finish its validation later.  A stopped
+        replica then demands no view and forwards nothing."""
+        self.client_states.stop_timers()
+        for timer in (self._viewchange_timer, self._snapshot_timer):
+            if timer is not None:
+                timer.cancel()
+        self._viewchange_timer = self._snapshot_timer = None
+        self._timer_provider = self.client_states.timers  # arms nothing now
+
     def _spawn_bg(self, coro) -> "asyncio.Task":
         """``create_task`` under the ``_bg_tasks`` retention contract
         (TL601): the loop holds only a weak reference to running tasks,
@@ -1834,6 +1877,7 @@ class Handlers:
         vcs.sent_view_change.add(new_view)
         await self.view_state.advance_expected_view(new_view)
         self.metrics.inc("view_changes_started")
+        obs_trace.note_viewchange(self.replica_id, new_view, obs_trace.VC_STARTED)
 
         # If the new primary is faulty too, its NEW-VIEW never arrives:
         # demand the next view after the view-change timeout.
@@ -1905,6 +1949,9 @@ class Handlers:
             and vc.new_view not in vcs.sent_new_view
         ):
             vcs.sent_new_view.add(vc.new_view)
+            obs_trace.note_viewchange(
+                self.replica_id, vc.new_view, obs_trace.VC_NEW_VIEW_SENT
+            )
             nv = NewView(
                 replica_id=self.replica_id,
                 new_view=vc.new_view,
@@ -1966,6 +2013,9 @@ class Handlers:
             self.view_change_state.prune_through(nv.new_view)
             self.commitment_collector.prune_view_bases(nv.new_view)
             self.metrics.inc("view_changes_completed")
+            obs_trace.note_viewchange(
+                self.replica_id, nv.new_view, obs_trace.VC_ENTERED
+            )
             # Health surface (ISSUE 14): the scrape-side minbft_health_view
             # gauge reads this stamp instead of suspending on view_state.
             self.metrics.note_view(nv.new_view)

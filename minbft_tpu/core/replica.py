@@ -196,6 +196,7 @@ class _Replica(api.Replica):
             t.cancel()
         await asyncio.gather(*self._tasks, return_exceptions=True)
         self._tasks.clear()
+        self.handlers.stop_timers()
         # JSON trace dump on shutdown (no-op unless MINBFT_TRACE_DUMP is
         # set): one file per replica (obs/trace.py::load_dumps reads
         # them back).  A crash dump may already exist — this overwrites

@@ -14,6 +14,7 @@ from __future__ import annotations
 from typing import Awaitable, Callable
 
 from ..messages import ReqViewChange
+from ..obs import trace as obs_trace
 
 
 def make_view_change_requestor(
@@ -24,7 +25,7 @@ def make_view_change_requestor(
 ) -> Callable[[int], Awaitable[None]]:
     """Demand a view change (reference makeViewChangeRequestor,
     core/timeout.go:45-72): dedup via expectedView, emit signed
-    REQ-VIEW-CHANGE."""
+    REQ-VIEW-CHANGE, a ``demand`` row on the process timeline."""
 
     async def request_view_change(new_view: int) -> None:
         if not await view_state.advance_expected_view(new_view):
@@ -32,6 +33,7 @@ def make_view_change_requestor(
         msg = ReqViewChange(replica_id=replica_id, new_view=new_view)
         sign_message(msg)
         broadcast(msg)
+        obs_trace.note_viewchange(replica_id, new_view, obs_trace.VC_DEMAND)
 
     return request_view_change
 

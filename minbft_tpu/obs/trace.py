@@ -402,6 +402,26 @@ _JAX_ROWS = MTStageRing(1 << 12, width=3)
 _GC_ROWS = StageRing(1 << 12, width=3)
 _gc_start = [0]
 
+# The view change, step by step (core/timeout.py and
+# core/message_handling.py write them, on the view-change path alone: a
+# window without a view change writes none).
+VIEWCHANGE_STAGES: Tuple[str, ...] = (
+    "demand",  # the replica sent its own REQ-VIEW-CHANGE for the view
+    "started",  # f+1 demands gathered: it emits its VIEW-CHANGE
+    "new_view_sent",  # the view's primary holds n-f VIEW-CHANGEs: NEW-VIEW
+    "entered",  # the NEW-VIEW applied: the replica stands in the view
+)
+VC_DEMAND = 0
+VC_STARTED = 1
+VC_NEW_VIEW_SENT = 2
+VC_ENTERED = 3
+# (replica, new_view, stage, t): a few rows a replica and view change.
+# Locked: the replicas of one process may run on several loops.
+_VIEWCHANGE_ROWS = MTStageRing(1 << 10)
+# (replica, new_view, items, t): one row a validation of a VIEW-CHANGE or
+# NEW-VIEW, items = the certificate checks it handed to the authenticator.
+_VIEWCHANGE_ITEMS = MTStageRing(1 << 12)
+
 
 def register_engine(engine) -> int:
     """Enter ``engine`` (anything with ``dispatch_rows()``) into the
@@ -445,6 +465,18 @@ def note_client_start(client_id: int, seq: int) -> None:
     _CLIENT_ROWS.push(client_id, seq, C_START, time.monotonic_ns())
 
 
+def note_viewchange(replica_id: int, new_view: int, stage: int) -> None:
+    """Replica ``replica_id`` reached ``stage`` (:data:`VIEWCHANGE_STAGES`)
+    of its change to ``new_view``."""
+    _VIEWCHANGE_ROWS.push(replica_id, new_view, stage, time.monotonic_ns())
+
+
+def note_viewchange_items(replica_id: int, new_view: int, items: int) -> None:
+    """A validator of replica ``replica_id`` handed ``items`` certificate
+    checks of the change to ``new_view`` to the authenticator."""
+    _VIEWCHANGE_ITEMS.push(replica_id, new_view, items, time.monotonic_ns())
+
+
 def timeline() -> dict:
     """Everything this process has on its timeline, as plain lists of
     rows whose instants are ``time.monotonic_ns()``, each with its ring's
@@ -468,7 +500,15 @@ def timeline() -> dict:
     - ``reply_checks``: the clients' reply checks summed over the process
       (utils/replycheck.py ``ReplyCheckStats``): native batches, checks,
       how many of them off the interpreter lock and how many inline,
-      quorums formed, checks a write.
+      quorums formed, checks a write;
+    - ``viewchange``: rows ``(replica, new_view, stage, t)``, one a step
+      of :data:`VIEWCHANGE_STAGES` (none in a process that changed no
+      view); beside them ``verify_items``, rows ``(replica, new_view,
+      items, t)``, one a validation of the change's VIEW-CHANGEs and
+      NEW-VIEW by that replica (``items``: the certificate checks it
+      handed to the authenticator, that is to the verification engine
+      where there is one; a certificate already checked is not checked
+      again), and ``verify_dropped``.
     """
     from ..utils import kernelstore, replycheck
     from . import looplag
@@ -480,6 +520,8 @@ def timeline() -> dict:
     gc_rows, gc_dropped = _GC_ROWS.read()
     jax_rows, jax_dropped = _JAX_ROWS.read()
     client_rows, client_dropped = _CLIENT_ROWS.read()
+    vc_rows, vc_dropped = _VIEWCHANGE_ROWS.read()
+    item_rows, items_dropped = _VIEWCHANGE_ITEMS.read()
     return {
         "dispatch_columns": list(DISPATCH_COLUMNS),
         "dispatch": dispatch,
@@ -500,6 +542,12 @@ def timeline() -> dict:
         },
         "loops": looplag.idle_clocks(),
         "reply_checks": replycheck.TOTAL.to_dict(),
+        "viewchange": {
+            "rows": [(r, v, VIEWCHANGE_STAGES[st], t) for r, v, st, t in vc_rows],
+            "dropped": vc_dropped,
+            "verify_items": item_rows,
+            "verify_dropped": items_dropped,
+        },
     }
 
 

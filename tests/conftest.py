@@ -55,26 +55,6 @@ def _collector_as_found():
         gc.set_threshold(*_COLLECTOR_AS_FOUND)
 
 
-# tests/benchmark/test_bench_manifest.py asks every cell for 16 callers x 8
-# writes, which was every cell until PR 33 added `n7f3-ecdsa.closed-1x1`.
-# That file is the benchmark's and only a `benchmark` PR may edit it
-# (ROADMAP.md Queue 3); until one relaxes the pin this one case is expected
-# to fail, strictly: once the pin goes, the mark has to go with it.  What else
-# the case checks is checked for this cell in
-# tests/benchmark/test_bench_n13f6_ecdsa_hmacusig.py.
-_PINNED_TO_16X8 = (
-    "test_bench_manifest.py::test_cell_loads_with_its_config_traffic_and_readers"
-    "[n7f3-ecdsa.closed-1x1]"
-)
-
-
-def pytest_collection_modifyitems(items):
-    for item in items:
-        if item.nodeid.endswith(_PINNED_TO_16X8):
-            item.add_marker(pytest.mark.xfail(
-                strict=True, reason="the benchmark's test pins closed-16x8 for every cell"))
-
-
 async def make_cluster(
     n=4, f=1, n_clients=1, usig_kind="hmac", cfg=None, wrap_conn=None,
     **auth_kw

@@ -117,6 +117,34 @@ def test_prepare_timeout_forwards_request_to_primary():
     asyncio.run(run())
 
 
+def test_a_stopped_replica_leaves_no_timer_armed():
+    """stop() cancels the request and prepare timers the replica armed, and
+    arms none for a message that is still on its way through: a replica
+    that was taken down counts no timeout after it went, forwards nothing
+    and demands no view."""
+
+    async def run():
+        r, timers, _, client_auths = _make_backup()
+        h = r.handlers
+        await h.handle_peer_message(_signed_request(client_auths[0]))
+        assert sum(not t.cancelled for t in timers.timers) == 2  # request + prepare
+        counted = dict(h.metrics.counters)
+        await r.stop()
+        assert all(t.cancelled for t in timers.timers)
+        timers.fire_all()
+        await asyncio.sleep(0)
+        await asyncio.sleep(0)
+        assert h.metrics.counters.get("timeouts_request", 0) == counted.get("timeouts_request", 0)
+        assert h.metrics.counters.get("timeouts_prepare", 0) == counted.get("timeouts_prepare", 0)
+        assert not any(isinstance(m, ReqViewChange) for m in h.message_log.snapshot())
+        assert not list(h.unicast_logs[0].snapshot())
+        # a request whose validation ends after the stop arms nothing either
+        await h.handle_peer_message(_signed_request(client_auths[0], seq=2))
+        assert all(t.cancelled for t in timers.timers)
+
+    asyncio.run(run())
+
+
 def test_timers_stop_on_commit():
     """Committing a request cancels its client's request+prepare timers: a
     later fire_all must not emit a view-change demand."""

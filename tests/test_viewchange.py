@@ -5,6 +5,8 @@ cluster survives a crashed primary and keeps committing requests through
 the new view."""
 
 import asyncio
+import contextvars
+import time
 
 import pytest
 
@@ -335,6 +337,136 @@ def test_cluster_survives_primary_crash():
         return True
 
     assert asyncio.run(scenario())
+
+
+def test_the_view_change_leaves_its_steps_on_the_timeline(monkeypatch):
+    """The same crash of the view-0 primary, read from the process
+    timeline: for view 1 a demand or more, a ``started`` and an
+    ``entered`` row from each survivor, one ``new_view_sent`` from the new
+    primary (replica 1), each in protocol order; and per survivor the
+    certificate checks its two validators handed to the authenticator, as
+    a counting stand-in around the replica's UI verifier saw them while a
+    VIEW-CHANGE or NEW-VIEW was being validated."""
+    from minbft_tpu.core import usig_ui
+    from minbft_tpu.obs import trace
+
+    validating = contextvars.ContextVar("validating", default=False)
+    seen = []  # [checks] per Handlers, in the order the replicas are built
+    build_verifier = usig_ui.make_ui_verifier
+    build_vc = vc_mod.make_view_change_validator
+    build_nv = vc_mod.make_new_view_validator
+
+    def verifier(authenticator):
+        verify_ui, checks = build_verifier(authenticator), [0]
+        seen.append(checks)
+
+        async def verify(msg):
+            checks[0] += validating.get()
+            return await verify_ui(msg)
+
+        return verify
+
+    def marked(validate):
+        async def validate_marked(msg):
+            token = validating.set(True)
+            try:
+                await validate(msg)
+            finally:
+                validating.reset(token)
+
+        return validate_marked
+
+    monkeypatch.setattr(usig_ui, "make_ui_verifier", verifier)
+    monkeypatch.setattr(vc_mod, "make_view_change_validator", lambda *a: marked(build_vc(*a)))
+    monkeypatch.setattr(vc_mod, "make_new_view_validator", lambda *a: marked(build_nv(*a)))
+
+    def items_by_replica(view, since):
+        out = {}
+        for r, v, n, t in trace.timeline()["viewchange"]["verify_items"]:
+            if v == view and t >= since:
+                out[r] = out.get(r, 0) + n
+        return out
+
+    async def scenario():
+        from minbft_tpu.client import new_client
+        from minbft_tpu.sample.config import SimpleConfiger
+        from minbft_tpu.sample.conn.inprocess import InProcessClientConnector
+
+        cfg = SimpleConfiger(
+            n=4, f=1,
+            timeout_request=0.8, timeout_prepare=0.4, timeout_viewchange=3.0,
+        )
+        replicas, c_auths, stubs, _ledgers = await make_cluster(n=4, f=1, cfg=cfg)
+        client = new_client(0, 4, 1, c_auths[0], InProcessClientConnector(stubs))
+        await client.start()
+        try:
+            assert await asyncio.wait_for(client.request(b"before-crash"), 30)
+            t0 = time.monotonic_ns()  # earlier cases of this process changed views too
+            stubs[0].crash()
+            await replicas[0].stop()
+            assert await asyncio.wait_for(client.request(b"after-crash"), 30)
+            await all_entered(replicas[1:], 1)
+            items = items_by_replica(1, t0)
+        finally:
+            await client.stop()
+            for r in replicas[1:]:
+                await r.stop()
+        rows = [row for row in trace.timeline()["viewchange"]["rows"] if row[3] >= t0]
+        return rows, {r: items.get(r, 0) for r in range(4)}
+
+    rows, items = asyncio.run(scenario())
+    assert {view for _r, view, _s, _t in rows} == {1}
+    at = {}
+    for replica, _view, stage, t in rows:
+        at.setdefault((replica, stage), []).append(t)
+    survivors = (1, 2, 3)
+    demands = sorted(t for (r, stage), ts in at.items() if stage == "demand" for t in ts)
+    assert demands and all(r in survivors for r, stage in at if stage == "demand")
+    for r in survivors:
+        (started,), (entered,) = at[r, "started"], at[r, "entered"]
+        assert demands[0] <= started < entered
+    (sent,) = at[1, "new_view_sent"]
+    assert [r for r, stage in at if stage == "new_view_sent"] == [1]
+    assert at[1, "started"][0] <= sent <= at[1, "entered"][0]
+    assert not any(r == 0 for r, _stage in at)  # the crashed primary wrote nothing
+    assert [items[r] for r in survivors] == [seen[r][0] for r in survivors]
+    assert all(items[r] > 0 for r in survivors) and items[0] == 0
+
+
+def test_a_window_without_a_view_change_writes_no_view_change_row():
+    """The view-change rings are written on the view-change path alone: a
+    cluster that commits without one leaves them as they were."""
+    from minbft_tpu.obs import trace
+
+    async def scenario():
+        from minbft_tpu.client import new_client
+        from minbft_tpu.sample.conn.inprocess import InProcessClientConnector
+
+        t0 = time.monotonic_ns()
+        replicas, c_auths, stubs, _ledgers = await make_cluster(n=4, f=1)
+        client = new_client(0, 4, 1, c_auths[0], InProcessClientConnector(stubs))
+        await client.start()
+        try:
+            for k in range(3):
+                assert await asyncio.wait_for(client.request(b"w%d" % k), 30)
+        finally:
+            await client.stop()
+            for r in replicas:
+                await r.stop()
+        section = trace.timeline()["viewchange"]
+        return [row for row in section["rows"] + section["verify_items"] if row[3] >= t0]
+
+    assert asyncio.run(scenario()) == []
+
+
+async def all_entered(replicas, view, timeout=10.0):
+    """Wait until every one of ``replicas`` stands in ``view``: a request is
+    answered by f+1, and the others may enter the view a moment later."""
+    loop = asyncio.get_running_loop()
+    deadline = loop.time() + timeout
+    while not all(r.metrics.current_view >= view for r in replicas):
+        assert loop.time() < deadline, [r.metrics.current_view for r in replicas]
+        await asyncio.sleep(0.02)
 
 
 def test_view_change_escalates_past_faulty_new_primary():
