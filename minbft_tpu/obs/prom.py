@@ -858,6 +858,8 @@ def _collect_kernel_store(base: Dict[str, str]) -> List[Family]:
         for field, text in (
             ("loads", "executables loaded from the kernel store"),
             ("builds", "executables traced, lowered and compiled (a miss)"),
+            ("off_main_loads", "of the loads, made off the process's main thread"),
+            ("off_main_builds", "of the builds, made off the process's main thread"),
             ("load_failures", "loads that fell back to building"),
             ("save_failures", "built executables that could not be written"),
             ("load_s", "seconds on the load path, failed loads included"),

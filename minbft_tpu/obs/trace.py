@@ -493,7 +493,8 @@ def timeline() -> dict:
       :data:`JAX_EVENTS` that took 1 ms or more; beside them
       ``kernel_store``, per kernel what its executable cost this process
       (utils/kernelstore.py ``KernelStoreStats``): ``loads``, ``builds``,
-      ``load_failures``, ``load_s`` and inside it ``read_s``,
+      of them ``off_main_loads`` / ``off_main_builds`` (made off the
+      main thread), ``load_failures``, ``load_s`` and inside it ``read_s``,
       ``deserialize_s`` and ``digest_s``, ``build_s``, ``bytes``;
     - ``client``: rows ``(client_id, seq, "start", t)``, one a request;
     - ``loops``: obs/looplag.py's idle clocks, one per live loop;

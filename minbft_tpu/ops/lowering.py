@@ -104,6 +104,8 @@ def per_mode_jit(fn, store=None):
     cache), writes the entry, and serves the ``jax.stages.Compiled`` from
     then on.  A ``Compiled`` belongs to the device it was compiled for, so
     a pinned engine on another chip gets an entry of its own.
+    ``wrapper.resolve(*avals)`` makes that first load on the calling
+    thread, ahead of the first call.
     ``store=False``: never (parallel/mesh.py's kernels, whose arguments
     arrive unplaced and are sharded by the jit itself)."""
     import threading
@@ -122,21 +124,52 @@ def per_mode_jit(fn, store=None):
                 jitted = jits.setdefault(m, jax.jit(fn))
         return jitted
 
-    def first_call(st, m, key, args):
-        """Load or build the executable of ``key`` and make its first call
-        -> that call's result."""
+    def obtain(st, key, args, build=True):
+        """The executable of ``key``, loaded or built by the first caller
+        (the others wait on the key's lock) -> ``(it or _UNSTORED, the
+        result of the first call over args made on the way, or None)``;
+        ``(None, None)`` and the key left unresolved where ``build`` is
+        False and the store has no entry for it."""
         with lock:
             key_lock = locks.setdefault(key, threading.Lock())
         with key_lock:
             call = calls.get(key)
-            if call is None:
-                call, out = st.obtain(fn, key, args)
-                calls[key] = call = call or _UNSTORED
-                if out is not None:
-                    return out
+            if call is not None:
+                return call, None
+            call, out = st.obtain(fn, key, args, build)
+            if call is None and not build:
+                return None, None
+            calls[key] = call = call or _UNSTORED
+            return call, out
+
+    def first_call(st, m, key, args):
+        """Load or build the executable of ``key`` and make its first call
+        -> that call's result."""
+        call, out = obtain(st, key, args)
+        if out is not None:
+            return out
         if call is _UNSTORED:
             return jit_for(m)(*args)
         return call(*args)
+
+    def resolve(*avals):
+        """Load on the CALLING thread the executable for arguments of
+        ``avals`` (``(shape, dtype)`` each) on the default device (a
+        pinned engine's scope), as a first call over zeros would; every
+        later call of that key, from any thread, is served by it
+        (warm-up's ``parallel/engine.py::BatchVerifier.load_kernels``).
+        Nothing where there is no store, no entry (the key's first call
+        builds it, as before) or the key is resolved already."""
+        st = kernelstore.default_store() if store is None else store
+        if not st:
+            return
+        import jax.numpy as jnp
+        import numpy as np
+
+        args = [jnp.asarray(np.zeros(shape, dtype)) for shape, dtype in avals]
+        key = (mode(),) + _signature(args)
+        if key not in calls:
+            obtain(st, key, args, build=False)
 
     def wrapper(*args, **kwargs):
         m = mode()
@@ -154,4 +187,5 @@ def per_mode_jit(fn, store=None):
 
     wrapper.__name__ = getattr(fn, "__name__", "kernel")
     wrapper.__wrapped__ = fn
+    wrapper.resolve = resolve
     return wrapper

@@ -1189,6 +1189,50 @@ class BatchVerifier:
         q = self._sign_queue("ed25519", self._dispatch_sign_ed25519)
         return await q.submit((seed, msg))
 
+    # -- warm-up ------------------------------------------------------------
+
+    def load_kernels(self, schemes) -> None:
+        """Load on the calling thread the executable of every device
+        kernel that one item of ``schemes`` reaches through this engine
+        (verify, and sign where signing is on the device), at the bucket
+        one item lands in, on this engine's chip:
+        ``ops/lowering.py::per_mode_jit`` loads it from the kernel store
+        and serves every later call of that key with it, so that a
+        dispatcher's first call, on its worker thread, loads nothing.  The
+        runtime loads an executable 5-6 times slower on a thread that is
+        not the process's main one (glibc's arena a thread: PERF.md
+        section 6).  Blocks the caller for the loads.  Nothing is loaded
+        where there is no store (the CPU backend), no entry (the first
+        dispatch builds it, as before: a build on this thread measured
+        slower, PERF.md section 6) or a key resolved already, and a
+        mesh-routed engine's kernels keep the plain jit."""
+        if self.mesh is not None:
+            return
+        sign = self._sign_device_enabled()
+        lanes = _bucket_for(1, self.buckets)
+        with self._device_scope():
+            if "ecdsa_p256" in schemes:
+                from ..ops import p256
+
+                p256.ecdsa_verify_kernel_packed.resolve(
+                    ((lanes, p256.PACKED_COLS), np.uint16)
+                )
+                if sign:
+                    p256.kg_comb_kernel().resolve(((lanes, p256.SIGN_COLS), np.uint16))
+            if "ed25519" in schemes:
+                from ..ops import ed25519 as ed
+
+                ed.ed25519_verify_kernel_packed.resolve(
+                    ((lanes, ed.PACKED_COLS), np.uint16)
+                )
+                if sign:
+                    ed.rb_comb_kernel().resolve(((lanes, ed.SIGN_COLS), np.uint16))
+            if "hmac_sha256" in schemes:
+                from ..ops.hmac_sha256 import hmac_verify_kernel_packed
+
+                # key | msg | mac, eight big-endian words each (_dispatch_hmac)
+                hmac_verify_kernel_packed.resolve(((lanes, 24), np.uint32))
+
     # -- dispatchers (worker thread; jax work happens here) -----------------
     #
     # Shape: acquire a recycled staging buffer, prep/pack the batch into

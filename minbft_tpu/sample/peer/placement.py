@@ -142,19 +142,26 @@ def prime_key_tables(store) -> None:
 
 async def warm_engine(engine, schemes=("ecdsa_p256",)) -> None:
     """One item signed and verified through each of ``engine``'s queues
-    in ``schemes``: the kernels trace, compile (or load from the
-    persistent cache) and run once at the engine's bucket BEFORE any
-    protocol timer runs.  Even with a warm cache the first call of the
-    ECDSA kernels costs tens of seconds on the TPU (tracing and loading
-    the executable) — inside a first request that is a prepare timeout
-    and a view change against a replica that is merely starting up.  The
-    first dispatch of each queue carries a cold compile inside the
-    liveness net's first-dispatch allowance (parallel/engine.py)."""
+    in ``schemes``, at the engine's bucket, BEFORE any protocol timer
+    runs: inside a first request a kernel's load would be a prepare
+    timeout and a view change against a replica that is merely starting
+    up.  First the kernels' executables are loaded from the kernel store
+    on the calling thread (:meth:`BatchVerifier.load_kernels`), where the
+    runtime loads them 5-6 times faster than on the dispatchers' worker
+    threads: ~4 s for the two ECDSA kernels on the TPU, during which the
+    event loop is blocked, before ``start()``, before the listener binds
+    and before any timer is armed.  Then the items go through the queues
+    as in service, so the liveness net, the counters and the dispatch
+    ring see each queue's first dispatch.  A kernel the store does not
+    hold (a tree's first process; the CPU backend, which has no store)
+    is traced and compiled by its queue's first dispatch, inside the
+    net's first-dispatch allowance (parallel/engine.py), as before."""
     import hashlib
     import hmac
 
     from ...utils import hostcrypto as hc
 
+    engine.load_kernels(schemes)
     msg = b"minbft-tpu engine warm-up"
     digest = hashlib.sha256(msg).digest()
     ok = True
@@ -177,9 +184,9 @@ async def warm_engine(engine, schemes=("ecdsa_p256",)) -> None:
 async def warm_engines(engines, schemes=("ecdsa_p256",), replicas: int = 1) -> None:
     """:func:`warm_engine` over several engines.  An executable belongs
     to its device: engines that share one warm one after the other (the
-    first compiles, the rest reuse its executable), engines on distinct
-    devices side by side (each compiles its own — a four-chip pool warms
-    in the time of one chip, not four).  Warm-up ends with
+    first obtains it, the rest reuse it), engines on distinct devices
+    side by side, but for their loads, which take the calling thread one
+    device after another.  Warm-up ends with
     :func:`settle_collector` for the ``replicas`` this process carries,
     with engines or with none."""
     import asyncio

@@ -56,10 +56,16 @@ class KernelStoreStats:
     the whole of the load path (failed loads too); ``digest_s`` (making
     the key: the sources are hashed once a process, for the kernel that
     asks first), ``read_s`` (the file, decompressed) and ``deserialize_s``
-    lie inside it; ``bytes`` are the files', read and written."""
+    lie inside it; ``bytes`` are the files', read and written.
+    ``off_main_loads`` / ``off_main_builds``: of ``loads`` / ``builds``,
+    those made on a thread that is not the process's main one, where the
+    runtime loads an executable 5-6 times slower (warm-up loads its
+    kernels on the main thread: ``ops/lowering.py::per_mode_jit``)."""
 
     loads: int = 0
     builds: int = 0
+    off_main_loads: int = 0
+    off_main_builds: int = 0
     load_failures: int = 0
     save_failures: int = 0
     load_s: float = 0.0
@@ -263,11 +269,13 @@ class KernelStore:
             raise
         return len(blob)
 
-    def obtain(self, fn, key, args):
+    def obtain(self, fn, key, args, build: bool = True):
         """The executable of kernel ``fn`` for ``key`` = (mode, avals,
         device), loaded, or built and written -> ``(Compiled, the result
         of its first call over args or None if not made yet)``;
-        ``(None, None)`` when the store is switched off."""
+        ``(None, None)`` when the store is switched off, or holds no
+        entry for ``key`` and ``build`` is False (the key's first call
+        builds it then)."""
         import jax
 
         if not jaxcache.switched_on():  # nothing read, nothing written
@@ -275,6 +283,7 @@ class KernelStore:
         mode, avals, device = key
         name = getattr(fn, "__name__", "kernel")
         st = stats_for(name)
+        off_main = threading.current_thread() is not threading.main_thread()
         store_key = None
         t0 = time.perf_counter()
         try:
@@ -291,14 +300,19 @@ class KernelStore:
                 if out_avals(out) != want:
                     raise Refused(f"{name} returned {out_avals(out)}")
                 st.loads += 1
+                st.off_main_loads += off_main
                 st.load_s += time.perf_counter() - t0
                 return compiled, out
+            if not build:
+                st.load_s += time.perf_counter() - t0
+                return None, None
         except Exception:  # noqa: BLE001 - whatever it was: build, rewrite
             st.load_failures += 1
         st.load_s += time.perf_counter() - t0
         t0 = time.perf_counter()
         compiled = jax.jit(fn).lower(*args).compile()
         st.builds += 1
+        st.off_main_builds += off_main
         st.build_s += time.perf_counter() - t0
         placed = {
             d for s in jax.tree.leaves(compiled.input_shardings)

@@ -425,8 +425,9 @@ def test_counter_fields_in_timeline_engine_dump_and_prometheus(store):
     per_mode_jit(fn, store=store)(X)
     row = trace.timeline()["jax"]["kernel_store"]["toy_counted"]
     assert set(row) == {
-        "loads", "builds", "load_failures", "save_failures", "load_s", "read_s",
-        "deserialize_s", "digest_s", "build_s", "bytes",
+        "loads", "builds", "off_main_loads", "off_main_builds", "load_failures",
+        "save_failures", "load_s", "read_s", "deserialize_s", "digest_s",
+        "build_s", "bytes",
     }
     assert (row["loads"], row["builds"], row["load_failures"]) == (1, 1, 0)
     assert row["bytes"] > 0 and row["build_s"] > 0
@@ -440,8 +441,177 @@ def test_counter_fields_in_timeline_engine_dump_and_prometheus(store):
     doc, text = asyncio.run(run())
     assert doc["kernel_store"]["toy_counted"] == row
     samples = prom.parse_exposition(text)
-    for family in ("loads", "builds", "load_failures", "load_seconds",
+    for family in ("loads", "builds", "off_main_loads", "off_main_builds",
+                   "load_failures", "load_seconds",
                    "read_seconds", "deserialize_seconds", "digest_seconds",
                    "build_seconds", "bytes"):
         assert f"minbft_kernel_store_{family}" in samples, family
     assert 'minbft_kernel_store_loads{kernel="toy_counted",replica="0"} 1' in text
+
+
+# -- warm-up obtains on the calling thread; dispatchers find it there -------
+
+
+class _Recorded:
+    """An executable that notes the threads that call it."""
+
+    def __init__(self, compiled):
+        self.compiled = compiled
+        self.threads = []
+
+    def __call__(self, *args):
+        self.threads.append(threading.current_thread())
+        return self.compiled(*args)
+
+
+class _RecordingStore:
+    """A store that holds every key it is asked for (it compiles it there
+    and then) and notes the thread that asked (the process's real store
+    is None on this backend)."""
+
+    def __init__(self):
+        self.obtained = []  # (kernel name, key, thread)
+        self.executables = []
+
+    def obtain(self, fn, key, args, build=True):
+        self.obtained.append((fn.__name__, key, threading.current_thread()))
+        run = _Recorded(jax.jit(fn).lower(*args).compile())
+        self.executables.append(run)
+        return run, None
+
+
+def test_warm_up_obtains_each_kernel_on_the_calling_thread(monkeypatch):
+    from minbft_tpu.sample.peer import placement
+
+    stub = _RecordingStore()
+    # the module-level entries the dispatchers call, over the stub
+    monkeypatch.setattr(p256, "ecdsa_verify_kernel_packed",
+                        per_mode_jit(p256._verify_one_packed, store=stub))
+    monkeypatch.setattr(p256, "per_mode_jit",
+                        functools.partial(per_mode_jit, store=stub))
+    monkeypatch.setattr(p256, "_kg_comb_batch", None)
+
+    async def warm():
+        engine, _line = placement.replica_engine(BUCKET, on_cpu=True)
+        await placement.warm_engine(engine)
+        return engine
+
+    engine = asyncio.run(warm())
+    here = threading.current_thread()
+    assert sorted((name, key[1][0], thread) for name, key, thread in stub.obtained) == [
+        ("_kg_comb_widen", ((BUCKET, p256.SIGN_COLS), np.dtype(np.uint16)), here),
+        ("_verify_one_packed", ((BUCKET, p256.PACKED_COLS), np.dtype(np.uint16)), here),
+    ]
+    # each executable served its queue's one dispatch, on the dispatcher's
+    # worker thread, and nothing was obtained there
+    for run in stub.executables:
+        assert len(run.threads) == 1 and run.threads[0] is not here
+    verify, sign = engine.stats["ecdsa_p256"], engine.sign_stats["ecdsa_p256"]
+    assert (verify.items, verify.batches, verify.dispatch_timeouts) == (1, 1, 0)
+    assert (sign.items, sign.batches, sign.host_fallback_items) == (1, 1, 0)
+    rows = engine.drain_obs_events()
+    assert sorted(r[3] for r in rows) == ["sign", "verify"]
+    assert all(r[7] == 0 for r in rows)  # no fallback, timeout or hostside row
+
+
+def test_a_resolved_key_serves_worker_threads_without_a_second_obtain():
+    stub = _RecordingStore()
+    fn = toy()
+    kernel = per_mode_jit(fn, store=stub)
+    on_2 = jax.devices()[2]
+    kernel.resolve(((BUCKET, 4), np.uint16))
+    kernel.resolve(((BUCKET, 4), np.uint16))  # resolved already: nothing
+    with jax.default_device(on_2):  # a pinned engine's scope: its own key
+        kernel.resolve(((BUCKET, 4), np.uint16))
+    assert [t for _n, _k, t in stub.obtained] == [threading.current_thread()] * 2
+    assert [k[2] for _n, k, _t in stub.obtained] == [jax.devices()[0], on_2]
+
+    def on_2_scope():
+        with jax.default_device(on_2):
+            return kernel(jnp.asarray(X))
+
+    async def from_workers():
+        return (await asyncio.to_thread(kernel, jnp.asarray(X)),
+                await asyncio.to_thread(on_2_scope))
+
+    got, got_2 = asyncio.run(from_workers())
+    assert (np.asarray(got) == WANT).all() and (np.asarray(got_2) == WANT).all()
+    assert got_2.devices() == {on_2}
+    assert len(stub.obtained) == 2
+    for run in stub.executables:
+        assert len(run.threads) == 1
+        assert run.threads[0] is not threading.current_thread()
+    # no store: nothing obtained, nothing called
+    per_mode_jit(fn, store=False).resolve(((BUCKET, 4), np.uint16))
+    assert len(stub.obtained) == 2
+
+
+def test_off_main_counters_count_what_a_worker_thread_loads_and_builds(
+    store, fresh_compiles
+):
+    fn = toy()
+
+    def on_a_worker(kernel, x):
+        out = []
+        worker = threading.Thread(target=lambda: out.append(np.asarray(kernel(x))))
+        worker.start()
+        worker.join(timeout=120)
+        assert not worker.is_alive()
+        return out[0]
+
+    def split():
+        row = counters(fn)
+        return (row["loads"], row["off_main_loads"],
+                row["builds"], row["off_main_builds"])
+
+    # no entry yet: resolve leaves the key to its first call, which builds
+    kernel = per_mode_jit(fn, store=store)
+    kernel.resolve(((BUCKET, 4), np.uint16))
+    assert split() == (0, 0, 0, 0) and not os.path.exists(store.directory)
+    assert (on_a_worker(kernel, X) == WANT).all()
+    assert split() == (0, 0, 1, 1)
+    # a second process: loaded on the main thread, then called from a worker
+    kernel = per_mode_jit(fn, store=store)
+    kernel.resolve(((BUCKET, 4), np.uint16))
+    assert split() == (1, 0, 1, 1)
+    assert (on_a_worker(kernel, X) == WANT).all()
+    assert split() == (1, 0, 1, 1)
+    # a third, whose first call comes from a worker with nothing resolved
+    assert (on_a_worker(per_mode_jit(fn, store=store), X) == WANT).all()
+    assert split() == (2, 1, 1, 1)
+    assert (np.asarray(per_mode_jit(fn, store=store)(X[:2])) == WANT[:2]).all()
+    assert split() == (2, 1, 2, 1)  # a new key, built here
+
+
+def test_without_a_store_warm_up_dispatches_as_it_did(monkeypatch):
+    from minbft_tpu.sample.peer import placement
+
+    assert kernelstore.default_store() is None
+    store_rows = kernelstore.stats()
+
+    def counts(engine):
+        verify, sign = engine.stats["ecdsa_p256"], engine.sign_stats["ecdsa_p256"]
+        return (
+            [getattr(verify, f) for f in (
+                "items", "batches", "max_batch_seen", "padded_lanes", "memo_hits",
+                "dispatch_timeouts", "key_table_hits", "key_table_builds",
+                "key_table_first_uses", "flush_reasons", "occupancy")],
+            [getattr(sign, f) for f in (
+                "items", "batches", "max_batch_seen", "padded_lanes",
+                "dispatch_timeouts", "host_fallback_items", "flush_reasons",
+                "occupancy")],
+            sorted((r[2], r[3], r[4], r[5], r[6], r[7])
+                   for r in engine.drain_obs_events()),
+        )
+
+    async def warm(load):
+        engine, _line = placement.replica_engine(BUCKET, on_cpu=True)
+        if not load:  # the warm-up as it was: no kernel obtained ahead
+            monkeypatch.setattr(engine, "load_kernels", lambda schemes: None)
+        await placement.warm_engine(engine)
+        return counts(engine)
+
+    now, before = asyncio.run(warm(True)), asyncio.run(warm(False))
+    assert now == before
+    assert now[0][:2] == [1, 1] and now[1][:2] == [1, 1]
+    assert kernelstore.stats() == store_rows  # nothing met a store
