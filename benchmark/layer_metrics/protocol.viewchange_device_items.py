@@ -8,7 +8,7 @@ that the replica had checked before."""
 from benchmark import viewchanges
 
 DECLARATION = {"unit": "items", "better": "lower", "source": "program_counter",
-               "layer": "protocol", "moves": "goodput_rps"}
+               "layer": "protocol", "moves": "outage_ms"}
 
 
 def read(obs):

@@ -6,7 +6,7 @@ request timer that ran out before the first demand is not in it."""
 from benchmark import viewchanges
 
 DECLARATION = {"unit": "ms", "better": "lower", "source": "program_span",
-               "layer": "protocol", "moves": "goodput_rps"}
+               "layer": "protocol", "moves": "outage_ms"}
 
 
 def read(obs):

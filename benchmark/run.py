@@ -27,13 +27,13 @@ import sys  # noqa: E402
 from typing import Optional  # noqa: E402
 
 from . import compare as cmp  # noqa: E402
-from . import manifest, observe, tracing  # noqa: E402
+from . import manifest, observe, spans, tracing  # noqa: E402
 from .manifest import BenchmarkError  # noqa: E402
 from .generator import Mix, Window  # noqa: E402
 
 UNATTRIBUTED = (
-    "all idle time of the window together: single gaps are not traced under "
-    "load, and the program carries no TraceAnnotation to attribute them by"
+    "all idle time of the window together: the program's timeline does not "
+    "hold the whole window (a ring lost rows), so it is not split by class"
 )
 
 
@@ -110,11 +110,23 @@ async def one_window(system, mix: Mix, seed: int, seconds: float,
     }
 
 
+def longest_ack_gap_ms(window) -> Optional[float]:
+    """The longest stretch that began inside the window with no write
+    acknowledged, in a window in which a scheduled fault was applied (None
+    in any other): what a failure cost the callers, a view change and the
+    timers that ran out before it included."""
+    if not window.faults_applied:
+        return None
+    acks = [r.acked for r in window.issued if r.acked is not None]
+    marks = sorted([window.opened, window.closed] + acks)
+    return max(b - a for a, b in zip(marks, marks[1:]) if a < window.closed) * 1e3
+
+
 def what_the_window_says(window) -> dict:
     """Notes, not metrics: how late an open loop's generator ran, and for a
-    window with faults what the failure cost its callers (PERF.md, Open
-    questions: the end-to-end metric comes once a cell's readings can set
-    its bound)."""
+    window with faults which were applied and how long their callers waited
+    (``longest_ack_gap_ms`` is the end-to-end metric ``outage_ms`` of a cell
+    that lists it)."""
     notes: dict = {}
     if window.mix.loop == "open":
         late = sorted(window.generator_late_s)
@@ -123,14 +135,11 @@ def what_the_window_says(window) -> dict:
                      generator_late_max_ms=late[-1] * 1e3 if late else None)
     if window.faults_applied:
         acks = sorted(r.acked for r in window.issued if r.acked is not None)
-        # the longest stretch that began inside the window with no write acknowledged
-        marks = sorted([window.opened, window.closed] + acks)
         notes.update(
             faults_applied=[
                 {"kind": a.kind, "replica": a.replica, "at_s": a.at - window.opened}
                 for a in window.faults_applied],
-            longest_ack_gap_ms=max(
-                b - a for a, b in zip(marks, marks[1:]) if a < window.closed) * 1e3,
+            longest_ack_gap_ms=longest_ack_gap_ms(window),
             first_ack_after_fault_ms=[
                 next(((t - a.at) * 1e3 for t in acks if t > a.at), None)
                 for a in window.faults_applied])
@@ -144,6 +153,7 @@ def end_to_end(cell, got: dict, seconds: float, setup_s: float) -> dict:
         "finality_mean_ms": sum(lat) / len(lat) if lat else None,
         "finality_p95_ms": observe.percentile(lat, 95) if lat else None,
         "setup_s": setup_s,
+        "outage_ms": longest_ack_gap_ms(got["window"]),
     }
     out = {}
     for m in cell.end_to_end:
@@ -161,6 +171,19 @@ def per_layer(cell, obs: observe.Observations) -> dict:
         if value is not None:
             out[m.name] = {"value": value, "unit": m.unit}
     return out
+
+
+def idle_gaps(obs: observe.Observations) -> list:
+    """The window's idle device time by what the host was doing
+    (benchmark/spans.py's classes, the same as the ``device.idle_<class>_share``
+    metrics'), ``[["idle_<class>", seconds], ...]`` largest first; they sum
+    to the window less the modelled kernels' time.  One unattributed row,
+    the window less ``busy_s``, where the timeline cannot be split."""
+    a = spans.analysis(obs)
+    if a is None or a.classes is None:
+        return [[UNATTRIBUTED, max(obs.window_s - obs.busy_s, 0.0)]]
+    return sorted(([f"idle_{c}", a.classes[c] / 1e9] for c in spans.CLASSES),
+                  key=lambda row: -row[1])
 
 
 def sized(cell, device: dict) -> tuple:
@@ -236,7 +259,7 @@ async def measured(cell, device: dict, system, mix: Mix, seed: int, seconds: flo
                 ([kernels[k].TRACE_NAME, s] for k, s in busy.items()),
                 key=lambda kv: -kv[1],
             )[:10],
-            "idle_gaps": [[UNATTRIBUTED, max(seconds - obs.busy_s, 0.0)]],
+            "idle_gaps": idle_gaps(obs),
         }
         notes.update(trace_sessions=cal["sessions"], kernel_dispatches=dispatches,
                      end_to_end_of_this_traced_window=end_to_end(

@@ -49,6 +49,39 @@ def test_manifest_has_the_contracts_keys_and_limits():
     assert len(json.dumps(MANIFEST)) < 64 * 1024
 
 
+VIEW_CHANGE = {"protocol.viewchange_ms", "protocol.viewchange_device_items"}
+
+
+def per_layer_of(manifest_data: dict, name: str) -> list:
+    """The rule a cell's per-layer metrics follow, and the one place the
+    tests state it: the manifest's entries that list the cell (or list no
+    cells), in the manifest's order, whose ``moves`` is an end-to-end metric
+    the cell reports."""
+    def admits(entry):
+        return name in entry.get("workloads", [name])
+
+    reported = {m["name"] for m in manifest_data["end_to_end"] if admits(m)}
+    return [m["name"] for m in manifest_data["per_layer"] if admits(m) and m["moves"] in reported]
+
+
+def hold_the_schemes(cell: manifest.Cell) -> None:
+    """What a cell reports follows from its own files: the roofline of each
+    kernel its configuration runs and of no other (so no ECDSA metric at
+    n=31, no HMAC metric in an ECDSA-only cell), the USIG checks a commit
+    where the certificates are HMACs checked on the device, and the
+    view-change readers (with ``outage_ms``) where its traffic has a fault
+    schedule and nowhere else."""
+    names = {m.name for m in cell.per_layer}
+    rooflines = {n[len("kernel."):-len("_roofline")] for n in names
+                 if n.startswith("kernel.") and n.endswith("_roofline")}
+    assert rooflines == set(cell.config["kernels"]), cell.name
+    usig_on_device = cell.config["usig"] == "HMAC_SHA256" and "hmac_verify" in cell.config["kernels"]
+    assert ("protocol.usig_verifies_per_commit" in names) == usig_on_device, cell.name
+    faults = bool(cell.traffic.get("faults"))
+    assert names & VIEW_CHANGE == (VIEW_CHANGE if faults else set()), cell.name
+    assert ("outage_ms" in {m["name"] for m in cell.end_to_end}) == faults, cell.name
+
+
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_agrees_with_its_own_files(name):
     """(Named ``test_cell_loads_with_its_config_traffic_and_readers`` until PR
@@ -72,22 +105,48 @@ def test_cell_agrees_with_its_own_files(name):
     assert [m["name"] for m in cell.end_to_end] == [
         m["name"] for m in MANIFEST["end_to_end"] if name in m.get("workloads", [name])]
     assert "setup_s" in {m["name"] for m in cell.end_to_end} and len(cell.end_to_end) >= 2
-    # its per-layer metrics are the manifest's: every entry that admits the
-    # cell and moves a metric the cell reports, each with its reader's file
-    reported = {m["name"] for m in cell.end_to_end}
-    entries = {m["name"]: m for m in MANIFEST["per_layer"]
-               if name in m.get("workloads", [name]) and m["moves"] in reported}
-    assert entries and [m.name for m in cell.per_layer] == list(entries)
+    # its per-layer metrics are the manifest's, by the one rule, each with its reader's file
+    entries = {m["name"]: m for m in MANIFEST["per_layer"]}
+    assert [m.name for m in cell.per_layer] == per_layer_of(MANIFEST, name) != []
     for m in cell.per_layer:
         declared = manifest.by_name(REPO, "layer_metrics", m.name, "reader").DECLARATION
         assert declared == {k: entries[m.name][k] for k in ("unit", "better", "source", "layer", "moves")}
         assert callable(m.read)
+    hold_the_schemes(cell)
     kernels = manifest.load_kernels(cell)
     assert list(kernels) == cell.config["kernels"]
     assert manifest.device_queues(kernels) == list(dict.fromkeys(k.QUEUE for k in kernels.values()))
     assert all(k.KIND in ("verify", "sign") and (k.KIND == "sign" or callable(k.skip))
                for k in kernels.values())
     assert callable(manifest.load_verifier(cell).make)
+
+
+def test_a_per_layer_entry_appended_for_every_cell_keeps_every_rule(tmp_path):
+    """What a change to the program's tracing does: one more reader's file
+    and one more entry at the end of ``per_layer`` that lists every cell.
+    Every cell then reports it last, after what it reported before, and every
+    rule above still holds; no test names a count or a position."""
+    shutil.copytree(manifest.HERE, tmp_path / "benchmark", ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp_path / "benchmark" / "layer_metrics" / "runtime.loop_turn_p50_ms.py").write_text(
+        "DECLARATION = {'unit': 'ms', 'better': 'lower', 'source': 'program_span',\n"
+        "               'layer': 'host runtime', 'moves': 'finality_mean_ms'}\n\n\n"
+        "def read(obs):\n    return None\n")
+    later = json.loads(json.dumps(MANIFEST))
+    later["per_layer"].append({"name": "runtime.loop_turn_p50_ms", "unit": "ms", "better": "lower",
+                               "source": "program_span", "layer": "host runtime",
+                               "moves": "finality_mean_ms", "workloads": list(CELLS)})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(later))
+    for name in CELLS:
+        before = [m.name for m in manifest.load_cell(name, MANIFEST).per_layer]
+        cell = manifest.load_cell(name, root=str(tmp_path))
+        assert [m.name for m in cell.per_layer] == per_layer_of(later, name) == before + [
+            "runtime.loop_turn_p50_ms"]
+        assert cell.per_layer[-1].read(None) is None
+        hold_the_schemes(cell)
+        declared = manifest.by_name(str(tmp_path), "layer_metrics", "runtime.loop_turn_p50_ms",
+                                    "reader").DECLARATION
+        assert declared == {k: later["per_layer"][-1][k]
+                            for k in ("unit", "better", "source", "layer", "moves")}
 
 
 @pytest.mark.parametrize("entry", MANIFEST["per_layer"], ids=lambda m: m["name"])

@@ -28,6 +28,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from benchmark import manifest, observe, roofline, run, spans, tracing  # noqa: E402
 from benchmark.generator import Mix  # noqa: E402
 from test_bench_deployments import CPU, drive  # noqa: E402  (this directory)
+from test_bench_manifest import hold_the_schemes, per_layer_of  # noqa: E402  (this directory)
 from test_bench_spans import T0, dispatch, observations, timeline  # noqa: E402
 
 CELL = "n13f6-ecdsa-hmacusig.closed-16x8"
@@ -82,30 +83,40 @@ def test_the_serial_cell_is_n7f3_unedited_under_one_caller_with_one_write_in_fli
     # the rest of what test_bench_manifest.py asks of every cell (its 16 x 8 aside)
     assert {m["name"] for m in cell.end_to_end} == {
         "goodput_rps", "finality_mean_ms", "finality_p95_ms", "setup_s"}
-    entries = [m["name"] for m in MANIFEST["per_layer"] if SERIAL in m["workloads"]]
-    assert [m.name for m in cell.per_layer] == entries and all(callable(m.read) for m in cell.per_layer)
+    assert [m.name for m in cell.per_layer] == per_layer_of(MANIFEST, SERIAL)
+    assert all(callable(m.read) for m in cell.per_layer)
     assert manifest.device_queues(manifest.load_kernels(cell)) == ["ecdsa_p256"]
 
 
-@pytest.mark.parametrize("name,count", [(CELL, 22), (SERIAL, 20)])
-def test_each_new_cell_reports_every_entry_that_applies_and_no_other(name, count):
-    names = {m.name for m in manifest.load_cell(name).per_layer}
-    assert len(names) == count
+@pytest.mark.parametrize("name", [CELL, SERIAL])
+def test_each_new_cell_reports_every_entry_that_applies_and_no_other(name):
+    """Every entry that applies by the manifest's one rule, whatever their
+    number; the mixed cell the HMAC kernel's roofline and the USIG checks a
+    commit, the ECDSA-only serial cell neither, and no Ed25519 metric."""
+    cell = manifest.load_cell(name)
+    names = [m.name for m in cell.per_layer]
+    assert names == per_layer_of(MANIFEST, name)
+    hold_the_schemes(cell)
     assert {"engine.full_flush_share", "kernel.ecdsa_verify_roofline", "kernel.ecdsa_sign_roofline",
-            "protocol.device_items_per_commit", "device.idle_share"} <= names
+            "protocol.device_items_per_commit", "device.idle_share"} <= set(names)
     mixed = {"kernel.hmac_verify_roofline", "protocol.usig_verifies_per_commit"}
-    assert mixed & names == (mixed if name == CELL else set())
+    assert mixed & set(names) == (mixed if name == CELL else set())
     assert not any("ed25519" in n for n in names)
 
 
 def test_the_ecdsa_cells_gain_the_one_new_metric_and_n31f15_stays_as_it_was():
-    """``test_bench_n31f15_ed25519.py`` pins that cell's twenty-one metrics and
-    is the benchmark's to edit, so the new metric lists the other four cells."""
-    counts = {name: len(manifest.load_cell(name).per_layer) for name in ACCEPTED}
-    assert counts == dict(zip(ACCEPTED, (20, 20, 21)))
-    entry = MANIFEST["per_layer"][-1]
-    assert entry["name"] == "engine.full_flush_share"
-    assert entry["workloads"] == ACCEPTED[:2] + [CELL, SERIAL]
+    """``engine.full_flush_share`` (of the dispatches resolved in the window,
+    those a whole bucket sent) reads a number in every cell, since every
+    engine writes the dispatch ring, so it lists every cell, n31f15 among
+    them; and n31f15 still reports no ECDSA metric."""
+    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == "engine.full_flush_share")
+    seven = ACCEPTED + [CELL, SERIAL, "n7f3-ecdsa-2s1s.closed-16x8-primary-crash", "n7f3-ecdsa.open-0.8knee"]
+    assert set(seven) <= set(entry["workloads"])
+    for name in seven:
+        assert "engine.full_flush_share" in per_layer_of(MANIFEST, name)
+    n31f15 = manifest.load_cell(ACCEPTED[2])
+    assert not any("ecdsa" in m.name for m in n31f15.per_layer)
+    assert "ecdsa_p256" not in manifest.device_queues(manifest.load_kernels(n31f15))
 
 
 # -- the kernels' work at 128 lanes -------------------------------------------------

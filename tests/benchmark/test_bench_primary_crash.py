@@ -16,9 +16,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from benchmark import manifest, spans  # noqa: E402
+from benchmark import manifest, run, spans  # noqa: E402
 from benchmark.generator import Fault, Mix  # noqa: E402
 from test_bench_faults import a_run, drive  # noqa: E402  (this directory)
+from test_bench_manifest import hold_the_schemes, per_layer_of  # noqa: E402  (this directory)
 
 MANIFEST = manifest.load_manifest()
 CRASH_CELL = "n7f3-ecdsa-2s1s.closed-16x8-primary-crash"
@@ -69,13 +70,39 @@ def test_the_open_loop_file_is_named_for_its_rate():
 
 @pytest.mark.parametrize("name", [CRASH_CELL, OPEN_CELL])
 def test_each_new_cell_reports_the_flagships_metrics(name):
-    """Every metric of the flagship's cell that reads a number in these
-    cells (``engine.full_flush_share`` once its list may take them), and
-    the two view-change readers in the crash cell alone, once entered."""
-    flagship = {m.name for m in manifest.load_cell("n7f3-ecdsa.closed-16x8").per_layer}
-    names = {m.name for m in manifest.load_cell(name).per_layer}
-    assert names - set(READERS) == flagship - ({"engine.full_flush_share"} - names)
-    assert name == CRASH_CELL or not names & set(READERS)
+    """Every entry that applies by the manifest's one rule, whatever their
+    number: the flagship's metrics that read a number in these cells
+    (``engine.full_flush_share`` among them), and the view-change readers
+    and ``outage_ms`` in the cell with a fault schedule alone."""
+    cell = manifest.load_cell(name)
+    names = [m.name for m in cell.per_layer]
+    assert names == per_layer_of(MANIFEST, name)
+    hold_the_schemes(cell)
+    assert {"engine.full_flush_share", "device.idle_share", "kernel.ecdsa_verify_roofline",
+            "client.finality_p50_ms", "setup.jax_trace_s"} <= set(names)
+    assert (set(READERS) <= set(names)) == (name == CRASH_CELL)
+
+
+def test_the_outage_is_the_longest_stretch_without_an_ack_and_absent_without_a_fault():
+    """``outage_ms``: the longest stretch that began inside the window with no
+    write acknowledged (the note ``longest_ack_gap_ms``), in a window in
+    which a fault was applied; in one without, not reported, and not 0."""
+    cell = manifest.load_cell(CRASH_CELL)
+    acks = [100.0 + 0.5 * k for k in range(21)] + [113.9 + 0.5 * k for k in range(32)] + [131.0]
+    window = types.SimpleNamespace(
+        opened=100.0, closed=130.0, faults_applied=[object()],
+        issued=[types.SimpleNamespace(acked=t) for t in acks] + [types.SimpleNamespace(acked=None)])
+    got = {"latencies_ms": [1.0, 2.0, 3.0], "commits": 52, "window": window}
+    metrics = run.end_to_end(cell, got, 30.0, 15.0)
+    assert list(metrics) == [m["name"] for m in cell.end_to_end]
+    assert metrics["outage_ms"] == {"value": pytest.approx(3900.0), "unit": "ms"}
+    assert run.longest_ack_gap_ms(window) == metrics["outage_ms"]["value"]
+    window.faults_applied = []
+    assert set(run.end_to_end(cell, got, 30.0, 15.0)) == {
+        m["name"] for m in cell.end_to_end} - {"outage_ms"}
+    # the other cells do not take it
+    assert all("outage_ms" not in {m["name"] for m in manifest.load_cell(w["name"]).end_to_end}
+               for w in MANIFEST["workloads"] if w["name"] != CRASH_CELL)
 
 
 # -- the readers, over timelines made by hand ---------------------------------
@@ -168,12 +195,17 @@ def test_a_program_without_the_section_or_a_ring_that_lost_rows_reads_none(monke
 
 
 def test_the_readers_declare_what_a_later_entry_of_the_benchmark_will_say():
+    """What their entries say: they move the outage, the crash cell's own
+    end-to-end metric, and are read in that cell alone."""
     assert reader(READERS[0]).DECLARATION == {
         "unit": "ms", "better": "lower", "source": "program_span",
-        "layer": "protocol", "moves": "goodput_rps"}
+        "layer": "protocol", "moves": "outage_ms"}
     assert reader(READERS[1]).DECLARATION == {
         "unit": "items", "better": "lower", "source": "program_counter",
-        "layer": "protocol", "moves": "goodput_rps"}
+        "layer": "protocol", "moves": "outage_ms"}
+    for name in READERS:
+        entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
+        assert CRASH_CELL in entry["workloads"] and OPEN_CELL not in entry["workloads"]
 
 
 # -- a rehearsal of the crash cell on the CPU backend --------------------------
@@ -202,4 +234,7 @@ def test_the_crash_cell_rehearsed_ends_correct_in_view_1():
     # the outage holds the view change, and the request timers that ran out
     # before it (each armed when its write reached a backup, before the crash)
     assert 0 < read_out["protocol.viewchange_ms"] < notes["longest_ack_gap_ms"] < 60e3
+    # the end-to-end metric is that note, in this cell alone
+    assert result["metrics"]["outage_ms"] == {"value": notes["longest_ack_gap_ms"], "unit": "ms"}
+    assert set(result["metrics"]) == {m["name"] for m in cell.end_to_end}
     assert read_out["protocol.viewchange_device_items"] > 0

@@ -16,7 +16,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from benchmark import compare, controls, manifest, run  # noqa: E402
+from benchmark import compare, controls, manifest, run, spans  # noqa: E402
 from benchmark import system as sut  # noqa: E402
 from bench_timeline import timeline_from_here  # noqa: E402  (this directory)
 
@@ -89,6 +89,18 @@ def test_traced_run_reports_the_per_layer_metrics_and_the_device_times(rehearsed
     assert traced["device"]["busy_s"] > 0 and traced["device"]["window_s"] == 1.0
     assert len(traced["breakdown"]["device_ops"]) == len(cell.config["kernels"])
     assert traced["notes"]["trace_sessions"][-1]["kernel_time_s"]["ecdsa_verify"] > 0
+
+
+def test_traced_run_breaks_the_idle_time_down_by_what_the_host_was_doing(rehearsed):
+    """``breakdown.idle_gaps``: the five classes of the ``device.idle_*_share``
+    metrics, in seconds, largest first, where the timeline was whole."""
+    traced, _ = rehearsed
+    gaps = traced["breakdown"]["idle_gaps"]
+    assert sorted(name for name, _s in gaps) == sorted(f"idle_{c}" for c in spans.CLASSES)
+    assert [s for _n, s in gaps] == sorted((s for _n, s in gaps), reverse=True)
+    for name, seconds in gaps:
+        share = traced["metrics"][f"device.{name}_share"]["value"]
+        assert seconds == pytest.approx(share * traced["device"]["window_s"], abs=1e-9)
 
 
 @pytest.mark.parametrize("seed", [SEED + 2, SEED + 9], ids=["first", "after_the_controls"])

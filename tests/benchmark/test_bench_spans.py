@@ -248,8 +248,9 @@ def test_latest_placement_ends_each_kernel_at_its_result_or_the_next_ones_start(
         (1, 0, 10), (2, 10, 20), (3, 42, 43)]
 
 
-@pytest.mark.parametrize("seed", range(4))
-def test_the_five_shares_sum_to_one_minus_busy_over_window(seed):
+def a_busy_timeline(seed: int) -> tuple:
+    """-> (dispatch rows, timeline): 40 dispatches of both kinds over three
+    engines, four collector passes and a loop idle by turns, from ``seed``."""
     import random
 
     rng = random.Random(seed)
@@ -263,7 +264,13 @@ def test_the_five_shares_sum_to_one_minus_busy_over_window(seed):
         t = enq
     gc = [(a, a + rng.uniform(1, 12)) for a in (rng.uniform(-5, 100) for _ in range(4))]
     idle = {s: rng.uniform(0, 10) for s in range(-2, 12)}
-    a = spans.analyse(observations(verify_ms=7.3, sign_ms=0.9), timeline(rows=rows, gc=gc, idle=idle))
+    return rows, timeline(rows=rows, gc=gc, idle=idle)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_the_five_shares_sum_to_one_minus_busy_over_window(seed):
+    rows, tl = a_busy_timeline(seed)
+    a = spans.analyse(observations(verify_ms=7.3, sign_ms=0.9), tl)
     assert sum(a.classes.values()) == a.window_ns  # every instant is in one class
     shares = sum(a.classes[c] for c in spans.CLASSES) / a.window_ns
     assert abs(shares - (1 - a.classes["busy"] / a.window_ns)) < 1e-9
@@ -275,6 +282,49 @@ def test_the_five_shares_sum_to_one_minus_busy_over_window(seed):
     inside = [spans.union_ns(spans._clipped(((s, e) for _r, s, e in t), a.opened, a.closed))
               for t in (by_result, by_launch)]
     assert inside[0] == inside[1] == a.classes["busy"]
+
+
+def test_the_breakdowns_idle_rows_are_the_five_classes_to_the_nanosecond(monkeypatch):
+    """``breakdown.idle_gaps`` of a traced run: a row a class, named as its
+    ``device.idle_<class>_share`` metric, largest first, summing to the window
+    less the modelled kernels' time; one unattributed row where a ring lost
+    rows inside the window."""
+    _rows, tl = a_busy_timeline(1)
+    monkeypatch.setattr(spans, "timeline", lambda: tl)
+    obs = observations(verify_ms=1.0, sign_ms=0.3)
+    obs.busy_s = 0.05
+    gaps = run.idle_gaps(obs)
+    a = spans.analysis(obs)
+    assert [name for name, _s in gaps] == sorted(
+        (f"idle_{c}" for c in spans.CLASSES), key=lambda n: -a.classes[n[len("idle_"):]])
+    assert [s for _n, s in gaps] == sorted((s for _n, s in gaps), reverse=True)
+    assert all(round(s * 1e9) == a.classes[n[len("idle_"):]] for n, s in gaps)
+    assert sum(round(s * 1e9) for _n, s in gaps) == a.window_ns - a.classes["busy"]
+    assert 0 < a.classes["busy"] < a.window_ns and min(s for _n, s in gaps) > 0
+    # the shares the metrics report are the same rows over the window
+    for name, seconds in gaps:
+        assert spans.idle_share(obs, name[len("idle_"):]) == pytest.approx(seconds / obs.window_s, abs=1e-12)
+    lost = timeline(rows=[dispatch(1, 20, 20, 20, 31)], gc=[(40, 50)], dropped={"gc": 3})
+    monkeypatch.setattr(spans, "timeline", lambda: lost)
+    obs = observations()
+    obs.busy_s = 0.01
+    assert run.idle_gaps(obs) == [[run.UNATTRIBUTED, pytest.approx(0.09)]]
+
+
+def test_the_kernel_stores_load_time_is_summed_over_its_kernels(monkeypatch):
+    """``setup.kernel_load_s``: ``load_s`` summed over the store's rows; 0
+    where the process met no store (the CPU backend), None where the program
+    keeps no such counter."""
+    read = manifest.by_name(REPO, "layer_metrics", "setup.kernel_load_s", "reader").read
+    tl = timeline()
+    row = {"loads": 1, "builds": 0, "off_main_loads": 0, "load_s": 3.5, "deserialize_s": 3.25}
+    tl["jax"]["kernel_store"] = {"_verify_one_packed": row, "_kg_comb_widen": {**row, "load_s": 0.25}}
+    monkeypatch.setattr(spans, "timeline", lambda: tl)
+    assert read(observations()) == 3.75
+    tl["jax"]["kernel_store"] = {}
+    assert read(observations()) == 0
+    del tl["jax"]["kernel_store"]
+    assert read(observations()) is None
 
 
 def test_a_negative_residual_is_reported_not_clipped():
